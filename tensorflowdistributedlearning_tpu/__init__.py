@@ -20,11 +20,7 @@ provides the same capabilities designed TPU-first:
 
 import importlib.util as _ilu
 
-from tensorflowdistributedlearning_tpu.utils import jaxcompat as _jaxcompat
-
-_jaxcompat.install()
-
-from tensorflowdistributedlearning_tpu.config import ModelConfig, TrainConfig  # noqa: E402
+from tensorflowdistributedlearning_tpu.config import ModelConfig, TrainConfig
 
 __version__ = "0.1.0"
 

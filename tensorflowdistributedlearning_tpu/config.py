@@ -335,11 +335,10 @@ class TrainConfig:
     telemetry: bool = True
     # persistent XLA compile cache directory (utils/compile_cache.py): point
     # repeated runs at the same dir and a second same-shape run loads its
-    # executables instead of recompiling (keys hash the StableHLO module +
-    # jaxlib version + XLA flags + device kinds — NOT process topology, so
-    # the elastic AOT standby and serve replicas share entries). None (the
-    # default) leaves the cache off; an unwritable dir degrades to a warning
-    # and an uncached run. CLI: --compile-cache-dir on train/fit/serve.
+    # executables instead of recompiling. JAX_COMPILATION_CACHE_DIR, when
+    # set, wins over this field; with neither, an accelerator run uses the
+    # checkout's fixed .jax_cache_tpu and a CPU-pinned run does not cache.
+    # An unwritable directory is an error. CLI: --compile-cache-dir.
     compile_cache_dir: Optional[str] = None
     # memory snapshot cadence, counted in LOG WINDOWS (every N-th window event
     # also records per-device HBM + host RSS); the trainers additionally

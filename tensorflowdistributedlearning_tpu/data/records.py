@@ -143,7 +143,7 @@ def read_records(path: str, verify: bool = True) -> Iterator[bytes]:
 def _records_lib() -> Optional[ctypes.CDLL]:
     lib = native_loader.load_extra_library(
         "records.cc",
-        "libtfdl_records.so",
+        "libtfdl_records",
         link_png=False,
     )
     if lib is None:

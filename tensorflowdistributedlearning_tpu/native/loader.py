@@ -1,16 +1,20 @@
 """ctypes binding + on-demand build for the native IO library (io.cc).
 
 Build strategy: compile ``io.cc`` with the system ``g++`` into
-``{package}/native/_build/libtfdl_io.so`` the first time it is needed, guarded by an
-mtime check. Concurrent processes may each compile, but each writes to a
-pid-unique temp file and installs with an atomic ``os.replace``, so the installed
-library is never torn. Falls back to PIL decoding when no compiler or libpng is
+``{package}/native/_build/libtfdl_io-{hash of the source}.so`` the first time it is
+needed. The name carries the content of what was compiled, so a library left in
+``_build/`` by other source — a copied tree does not promise mtimes — can never
+pass for fresh: it is simply not the file looked for. Concurrent processes may
+each compile, but each writes to a pid-unique temp file and installs with an
+atomic ``os.replace``, so the installed library is never torn. Falls back to
+PIL decoding when no compiler or libpng is
 available — same results, just slower and GIL-bound.
 """
 
 from __future__ import annotations
 
 import ctypes
+import hashlib
 import logging
 import os
 import subprocess
@@ -24,7 +28,15 @@ logger = logging.getLogger(__name__)
 _HERE = os.path.dirname(os.path.abspath(__file__))
 _SRC = os.path.join(_HERE, "io.cc")
 _BUILD_DIR = os.path.join(_HERE, "_build")
-_LIB = os.path.join(_BUILD_DIR, "libtfdl_io.so")
+
+
+def _library_path(src: str, stem: str) -> str:
+    """``_build/{stem}-{sha256 of src, 16 hex}.so``: where the library built
+    from exactly this source lives."""
+    with open(src, "rb") as f:
+        digest = hashlib.sha256(f.read()).hexdigest()[:16]
+    return os.path.join(_BUILD_DIR, f"{stem}-{digest}.so")
+
 
 _lock = threading.Lock()
 _lib: Optional[ctypes.CDLL] = None
@@ -63,13 +75,13 @@ def _build_library(
     return None
 
 
-def _build() -> bool:
+def _build(target: str) -> bool:
     # Prefer full PNG+JPEG support; on hosts without libjpeg fall back to a
     # PNG-only build (TFDL_NO_JPEG) so the native PNG fast path survives —
     # decode_image_batch then PIL-decodes JPEG files one at a time.
     return (
         _build_library(
-            _SRC, _LIB, [["-lpng", "-ljpeg"], ["-DTFDL_NO_JPEG", "-lpng"]]
+            _SRC, target, [["-lpng", "-ljpeg"], ["-DTFDL_NO_JPEG", "-lpng"]]
         )
         is not None
     )
@@ -81,13 +93,11 @@ def _load() -> Optional[ctypes.CDLL]:
         if _tried:
             return _lib
         _tried = True
-        fresh = os.path.exists(_LIB) and os.path.getmtime(_LIB) >= os.path.getmtime(
-            _SRC
-        )
-        if not fresh and not _build():
+        target = _library_path(_SRC, "libtfdl_io")
+        if not os.path.exists(target) and not _build(target):
             return None
         try:
-            lib = ctypes.CDLL(_LIB)
+            lib = ctypes.CDLL(target)
         except OSError as e:
             logger.warning("native IO load failed (%s); using PIL fallback", e)
             return None
@@ -320,22 +330,19 @@ _extra_libs: dict = {}
 
 
 def load_extra_library(
-    src_name: str, lib_name: str, *, link_png: bool = False
+    src_name: str, lib_stem: str, *, link_png: bool = False
 ) -> Optional[ctypes.CDLL]:
     """Build-and-load another single-source native library from this package
-    directory via the shared build core (mtime-checked, atomic install); None
-    when no toolchain is available."""
+    directory via the shared build core (source-hash-named, atomic install);
+    None when no toolchain is available."""
     with _extra_lock:
         if src_name in _extra_libs:
             return _extra_libs[src_name]
         src = os.path.join(_HERE, src_name)
-        target = os.path.join(_BUILD_DIR, lib_name)
+        target = _library_path(src, lib_stem)
         lib = None
         try:
-            fresh = os.path.exists(target) and os.path.getmtime(
-                target
-            ) >= os.path.getmtime(src)
-            if fresh or _build_library(
+            if os.path.exists(target) or _build_library(
                 src, target, [["-lpng"] if link_png else []]
             ):
                 lib = ctypes.CDLL(target)

@@ -25,9 +25,8 @@ on a number that isn't continuously measured. This module closes that gap:
 
 MFU here is the standard analytic-FLOPs convention: the planner's
 ``6 * param_count * global_batch`` per-step FLOP model priced against
-measured wall time and the peak bf16 FLOP/s table
-(``parallel/planner.PEAK_FLOPS_BY_KIND``). On backends without a known peak
-(CPU hosts) MFU is ABSENT — never a fabricated 0/0; set ``TFDL_PEAK_FLOPS``
+measured wall time and the published bf16 peak of the chip
+(``utils/peaks.PEAKS``). On a CPU host MFU is ABSENT — never a fabricated 0/0; set ``TFDL_PEAK_FLOPS``
 to price against an explicit peak (the CI drill does).
 
 Failure stance matches the rest of obs/: a profiler hiccup (unsupported
@@ -65,13 +64,14 @@ _COMPUTE_BUCKETS = ("conv", "matmul")
 _COLLECTIVE_BUCKETS = ("collectives",)
 
 
-def resolve_peak_flops(device_kind: Optional[str] = None) -> Optional[float]:
-    """Peak bf16 FLOP/s per chip for MFU accounting, or ``None`` when the
-    device kind is unknown (CPU hosts) — the caller must then OMIT MFU, not
-    price against a made-up peak. ``TFDL_PEAK_FLOPS`` overrides (lets CI
-    drill the MFU path on CPU, and lets operators price exotic SKUs).
+def resolve_peak_flops() -> Optional[float]:
+    """Peak bf16 FLOP/s per chip of this process's device for MFU accounting
+    (the one peaks table, utils/peaks.py): ``None`` on a CPU host — the
+    caller must then OMIT MFU, not price against a made-up peak — and an
+    error on a TPU the table does not know. ``TFDL_PEAK_FLOPS`` overrides
+    (lets CI drill the MFU path on CPU).
 
-    Deliberately NOT ``Topology.peak_flops()``: the planner's fallback
+    Deliberately NOT ``Topology.peak_flops()``: the planner's CPU what-if
     constant is fine for relative candidate ordering but would turn CPU MFU
     into a meaningless absolute number."""
     env = os.environ.get("TFDL_PEAK_FLOPS")
@@ -80,22 +80,13 @@ def resolve_peak_flops(device_kind: Optional[str] = None) -> Optional[float]:
             return float(env)
         except ValueError:
             logger.warning("ignoring unparseable TFDL_PEAK_FLOPS=%r", env)
-    if device_kind is None:
-        try:
-            import jax
+    import jax
 
-            device_kind = getattr(jax.devices()[0], "device_kind", "")
-        except Exception:  # noqa: BLE001 — backend probe best-effort
-            return None
-    from tensorflowdistributedlearning_tpu.parallel.planner import (
-        PEAK_FLOPS_BY_KIND,
-    )
+    from tensorflowdistributedlearning_tpu.utils import peaks as peaks_lib
 
-    kind = (device_kind or "").lower()
-    for needle, flops in PEAK_FLOPS_BY_KIND.items():
-        if needle in kind:
-            return flops
-    return None
+    device = jax.devices()[0]
+    peaks = peaks_lib.device_peaks(device.device_kind, device.platform)
+    return peaks.bf16_flops if peaks else None
 
 
 def build_roofline(

@@ -251,7 +251,7 @@ def _serve_fleet_section(events: List[Dict]) -> Optional[Dict]:
         section["replicas"] = dict(lifecycle)
         # spawn -> readiness-line wall time per replica_ready event: the
         # cold-start metric (interpreter boot + artifact load + ladder
-        # warmup) the shipped compile cache exists to shrink
+        # warmup) the persistent compile cache exists to shrink
         ttrs = [
             float(e["time_to_ready_s"])
             for e in events

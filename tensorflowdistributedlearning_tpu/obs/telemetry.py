@@ -114,7 +114,14 @@ class Telemetry:
         process_index: Optional[int] = None,
         process_count: Optional[int] = None,
         capacity_sampling: bool = True,
+        controller: bool = False,
     ):
+        """``controller=True`` is for a process that SPAWNS the chip users
+        (the serve-fleet controller, the flywheel): a chip belongs to one
+        process, and a parent that initializes a jax backend takes it from
+        its children. Such a telemetry asks jax nothing — its run header
+        says ``controller`` where a chip owner's carries the device
+        fingerprint, which the children's own ledgers record."""
         self.enabled = enabled and workdir is not None
         # the run's workdir (None when disabled) — the continuous profiler
         # (obs/profiler.py) roots its capture dirs under it
@@ -164,17 +171,14 @@ class Telemetry:
             # jax process — serve replicas sharing one workdir pass their
             # replica id so each writes its own telemetry-{i}.jsonl.
             process_index, process_count = 0, 1
-            if is_main is None:
-                try:
-                    from tensorflowdistributedlearning_tpu.parallel import (
-                        multihost,
-                    )
+            if is_main is None and not controller:
+                from tensorflowdistributedlearning_tpu.parallel import (
+                    multihost,
+                )
 
-                    info = multihost.process_info()
-                    process_index = info["process_index"]
-                    process_count = info["process_count"]
-                except Exception:  # noqa: BLE001 — backend probe best-effort
-                    pass
+                info = multihost.process_info()
+                process_index = info["process_index"]
+                process_count = info["process_count"]
         process_index = int(process_index)
         if is_main is None:
             is_main = process_index == 0
@@ -209,10 +213,10 @@ class Telemetry:
                 # obs/report tell a supervised session's relaunches apart
                 # from later standalone runs in the same workdir
                 header["supervised"] = True
-            try:
+            if controller:
+                header["controller"] = True
+            else:
                 header["fingerprint"] = run_fingerprint()
-            except Exception as e:  # noqa: BLE001 — backend probe is best-effort
-                header["fingerprint"] = {"error": str(e)[:200]}
             if run_info:
                 header.update(run_info)
             self.ledger.event("run_header", **header)

@@ -173,13 +173,17 @@ def flash_attention(
     the [block_q, T] score tile) would not fit the VMEM budget."""
     b, t, h, d = q.shape
     block_q = min(_BLOCK_Q, t)
+    from tensorflowdistributedlearning_tpu.ops.pallas_kernels import (
+        note_reference_fallback,
+        pallas_platform_ok,
+    )
+
     if _vmem_estimate_bytes(t, d, block_q) > _VMEM_KV_LIMIT_BYTES:
+        note_reference_fallback(
+            "flash_attention", f"K/V block [{t}, {d}] over the VMEM budget"
+        )
         return attention_reference(q, k, v, causal=causal)
     if interpret is None:
-        from tensorflowdistributedlearning_tpu.ops.pallas_kernels import (
-            pallas_platform_ok,
-        )
-
         interpret = not pallas_platform_ok()
     if interpret and (vma_of(q) | vma_of(k) | vma_of(v)):
         # the Pallas interpreter's block slicing trips shard_map's varying-axes

@@ -18,7 +18,8 @@ dense/conv layers through these kernels, which
    home shared with the fused elementwise kernels.
 
 Dispatch policy (same shape as the other Pallas ops): compiled kernels on
-TPU behind :func:`pallas_platform_ok`; off-TPU the public wrappers take the
+TPU behind :func:`pallas_kernels.pallas_platform_ok` (looked up on the
+module at call time: the one switch for every wrapper); off-TPU the public wrappers take the
 **exact dequantize-f32 XLA fallback** — the same dynamic activation
 quantization followed by f32 dequantize-and-matmul. That fallback is also
 the parity oracle (`*_reference`): integer accumulation is exact, so the
@@ -54,10 +55,11 @@ from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from tensorflowdistributedlearning_tpu.ops import pallas_kernels
 from tensorflowdistributedlearning_tpu.ops.pallas_kernels import (
     _VMEM_BLOCK_LIMIT_BYTES,
     bias_act_epilogue,
-    pallas_platform_ok,
+    note_reference_fallback,
 )
 from tensorflowdistributedlearning_tpu.parallel.collectives import vma_of
 
@@ -158,6 +160,13 @@ def _qmm_kernel(x_ref, w_ref, s_ref, b_ref, o_ref, *, act: str):
     o_ref[...] = y.astype(o_ref.dtype)
 
 
+# Rows of the activation block one grid step holds: a multiple of the int8
+# sublane tile (32). The row count of a served batch is the symbolic batch of
+# the exported artifact, so the block — and with it the VMEM budget — must
+# not depend on it; the grid covers the rows with a last partial block.
+_QMM_ROW_TILE = 256
+
+
 def _n_tile(n: int, fixed_bytes: int, per_n_bytes: int, limit: int) -> int:
     """Largest divisor-of-n output-feature tile whose block set fits VMEM.
     Features are independent columns, so tiling N is free."""
@@ -183,14 +192,16 @@ def int8_matmul(
     fused scale+bias+act epilogue.
 
     ``x``: [..., K] float (leading dims flattened for the kernel and
-    restored); ``wq``: [K, N] int8; ``w_scale``: [N] f32; ``bias``: [N] or
-    ``None``; output [..., N] in ``out_dtype`` (default ``x.dtype``).
+    restored; they may be symbolic — the exported batch); ``wq``: [K, N]
+    int8; ``w_scale``: [N] f32; ``bias``: [N] or ``None``; output [..., N] in
+    ``out_dtype`` (default ``x.dtype``).
 
-    Dispatch: compiled Pallas on TPU (N-tiled when a whole-array block
-    overflows the VMEM budget, whole-K always resident); the exact
-    dequantize-f32 XLA reference off-TPU, on VMEM overflow, and under
-    shard_map's interpreter restriction. ``interpret=True`` runs the real
-    integer kernel body interpreted (tests only — slow)."""
+    Dispatch: compiled Pallas on TPU (rows in blocks of ``_QMM_ROW_TILE``,
+    N-tiled when the block set overflows the VMEM budget, whole-K always
+    resident); the exact dequantize-f32 XLA reference off-TPU, on VMEM
+    overflow, and under shard_map's interpreter restriction.
+    ``interpret=True`` runs the real integer kernel body interpreted (tests
+    only — slow)."""
     if wq.dtype != jnp.int8:
         raise ValueError(f"wq must be int8, got {wq.dtype}")
     k, n = wq.shape
@@ -202,7 +213,7 @@ def int8_matmul(
         raise ValueError(f"bias must be [{n}], got {bias.shape}")
     out_dtype = x.dtype if out_dtype is None else out_dtype
     if interpret is None:
-        interpret = not pallas_platform_ok()
+        interpret = not pallas_kernels.pallas_platform_ok()
         if interpret:
             return int8_matmul_reference(
                 x, wq, w_scale, bias=bias, act=act, out_dtype=out_dtype
@@ -211,18 +222,22 @@ def int8_matmul(
         return int8_matmul_reference(
             x, wq, w_scale, bias=bias, act=act, out_dtype=out_dtype
         )
-    lead = x.shape[:-1]
-    m = 1
-    for d in lead:
-        m *= d
-    # block budget: xq [m,k]i8 + wq [k,nt]i8 + acc/out [m,nt]f32 + vectors
-    fixed = m * k
-    per_n = k + m * 4 + 8
+    tm = _QMM_ROW_TILE
+    # block budget: xq [tm,k]i8 + wq [k,nt]i8 + acc/out [tm,nt]f32 + vectors
+    fixed = tm * k
+    per_n = k + tm * 4 + 8
     nt = _n_tile(n, fixed, per_n, vmem_limit_bytes)
     if fixed + nt * per_n > vmem_limit_bytes:
+        note_reference_fallback(
+            "int8_matmul", f"[{tm}, {k}] x [{k}, {nt}] over the VMEM budget"
+        )
         return int8_matmul_reference(
             x, wq, w_scale, bias=bias, act=act, out_dtype=out_dtype
         )
+    lead = x.shape[:-1]
+    m = 1
+    for d in lead:
+        m = m * d
     wq = jnp.asarray(wq)
     xq, xs = quantize_activations(x)
     xq2 = xq.reshape(m, k)
@@ -240,14 +255,16 @@ def int8_matmul(
     )
     out = pl.pallas_call(
         functools.partial(_qmm_kernel, act=act),
-        grid=(n // nt,),
+        grid=(pl.cdiv(m, tm), n // nt),
         in_specs=[
-            pl.BlockSpec((m, k), lambda j: (0, 0), memory_space=pltpu.VMEM),
-            pl.BlockSpec((k, nt), lambda j: (0, j), memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, nt), lambda j: (0, j), memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, nt), lambda j: (0, j), memory_space=pltpu.VMEM),
+            pl.BlockSpec((tm, k), lambda i, j: (i, 0), memory_space=pltpu.VMEM),
+            pl.BlockSpec((k, nt), lambda i, j: (0, j), memory_space=pltpu.VMEM),
+            pl.BlockSpec((1, nt), lambda i, j: (0, j), memory_space=pltpu.VMEM),
+            pl.BlockSpec((1, nt), lambda i, j: (0, j), memory_space=pltpu.VMEM),
         ],
-        out_specs=pl.BlockSpec((m, nt), lambda j: (0, j), memory_space=pltpu.VMEM),
+        out_specs=pl.BlockSpec(
+            (tm, nt), lambda i, j: (i, j), memory_space=pltpu.VMEM
+        ),
         out_shape=out_shape,
         interpret=interpret,
     )(xq2, wq, scale_vec, b32)
@@ -384,7 +401,7 @@ def int8_conv2d(
         raise ValueError(f"unsupported padding spec {padding!r}")
     out_dtype = x.dtype if out_dtype is None else out_dtype
     if interpret is None:
-        interpret = not pallas_platform_ok()
+        interpret = not pallas_kernels.pallas_platform_ok()
         if interpret:
             return int8_conv2d_reference(
                 x, wq, w_scale, padding=pads, bias=bias, act=act,
@@ -400,6 +417,20 @@ def int8_conv2d(
     ho = h + pt + pb - (kh - 1)
     wo = wd + pl_ + pr - (kw - 1)
     if ho <= 0 or wo <= 0:
+        note_reference_fallback("int8_conv2d", f"empty output for {x.shape}")
+        return int8_conv2d_reference(
+            x, wq, w_scale, padding=pads, bias=bias, act=act,
+            out_dtype=out_dtype,
+        )
+    if not interpret and cin % 128 and wo % 8:
+        # the kernel flattens each [ho, wo, cin] int8 tap to [ho*wo, cin] for
+        # the MXU; Mosaic (libtpu 0.0.34, compiled for v5e) refuses that
+        # shape cast unless the lanes are full or the rows tile evenly
+        note_reference_fallback(
+            "int8_conv2d",
+            f"Mosaic cannot flatten a [{ho}, {wo}, {cin}] int8 tile "
+            "(needs cin % 128 == 0 or width % 8 == 0)",
+        )
         return int8_conv2d_reference(
             x, wq, w_scale, padding=pads, bias=bias, act=act,
             out_dtype=out_dtype,
@@ -410,6 +441,11 @@ def int8_conv2d(
         hp * wp * cin + kh * kw * cin * cout + ho * wo * cout * 8
     )
     if block_bytes > vmem_limit_bytes:
+        note_reference_fallback(
+            "int8_conv2d",
+            f"image block {(hp, wp, cin)} -> {(ho, wo, cout)} over the VMEM "
+            "budget",
+        )
         return int8_conv2d_reference(
             x, wq, w_scale, padding=pads, bias=bias, act=act,
             out_dtype=out_dtype,

@@ -18,12 +18,8 @@ from tensorflowdistributedlearning_tpu.parallel import mesh as mesh_lib
 
 def vma_of(x: Any) -> frozenset:
     """The varying-manual-axes set of a traced value inside ``shard_map`` —
-    empty when the value is replicated or outside shard_map. Single place to
-    follow jax's aval API (``jax.typeof``; older versions only had
-    ``jax.core.get_aval``)."""
-    typeof = getattr(jax, "typeof", None)
-    aval = typeof(x) if typeof is not None else jax.core.get_aval(x)
-    return getattr(aval, "vma", None) or frozenset()
+    empty when the value is replicated or outside shard_map."""
+    return getattr(jax.typeof(x), "vma", None) or frozenset()
 
 
 def psum_tree(tree: Any, axis_name: str = mesh_lib.BATCH_AXIS) -> Any:

@@ -7,8 +7,8 @@ SURVEY §2.3 "Cross-host DP: NO"). The TPU-native build scales past that by desi
 mesh spans them all, and XLA routes collectives over ICI within a slice and DCN
 across slices. The only host-side code multi-host adds is here:
 
-- ``initialize``: one call per process before any jax op (TPU pods auto-discover;
-  explicit coordinator args supported for CPU/GPU clusters);
+- ``initialize``: one call per process of an explicit multi-process world,
+  before any jax op (a single-host run never calls it);
 - ``global_shard_batch``: each process contributes ONLY its local shard of every
   global batch (``jax.make_array_from_process_local_data``), the per-host
   generalization of the reference's per-tower ``batch/n_gpus`` input_fn contract
@@ -70,54 +70,44 @@ def barrier_probe():
 
 
 def initialize(
-    coordinator_address: Optional[str] = None,
-    num_processes: Optional[int] = None,
-    process_id: Optional[int] = None,
+    coordinator_address: str,
+    num_processes: int,
+    process_id: int,
 ) -> None:
-    """Join this process to the jax.distributed cluster (no-op if already
-    initialized). On TPU pods all arguments auto-discover from the TPU metadata;
-    pass them explicitly for multi-host CPU/GPU runs.
+    """Join this process to the jax.distributed cluster at
+    ``coordinator_address`` (no-op if already initialized).
+
+    Only ever called with an explicit world: a run that names no coordinator
+    is one process driving every chip of its host and calls
+    ``jax.distributed.initialize()`` not at all. jax's auto-discovery is no
+    quiet no-op there — on a machine that shows TPU chips it asks the cloud
+    metadata server which cluster it belongs to, which a machine without one
+    answers with a stall or a connection error (timed on the chip in
+    CHANGES.md, PR 21). A pod names its coordinator (``--coordinator-address
+    --num-processes --process-id``).
 
     MUST run before any jax call that initializes the XLA backend (even
     ``jax.devices()``/``jax.process_count()``) — jax refuses to form a cluster
-    afterwards. With explicit coordinator arguments a failure to join RAISES
-    (silently degrading to per-host single-process training would be wrong
-    training at pod scale); with auto-discovery a quiet single-process fallback
-    is the correct behavior for laptop/CI runs.
+    afterwards. A failure to join RAISES: silently degrading to per-host
+    single-process training would be wrong training at pod scale.
     """
-    explicit = (
-        coordinator_address is not None
-        or num_processes is not None
-        or process_id is not None
-    )
     # already-initialized check WITHOUT touching the XLA backend
-    is_initialized = getattr(jax.distributed, "is_initialized", None)
-    if is_initialized is not None and is_initialized():
+    if jax.distributed.is_initialized():
         return
-    if explicit and not _platform_known_non_cpu():
-        # explicit multi-process on the CPU backend (elastic drills, the gloo
+    if not _platform_known_non_cpu():
+        # multi-process on the CPU backend (elastic drills, the gloo
         # integration tests, laptop pods): cross-process collectives need the
         # gloo implementation selected BEFORE the backend initializes — the
         # default CPU collectives are single-process only. Applied whenever
         # the configured platform is cpu OR unset (a CPU-only machine with no
         # JAX_PLATFORMS still lands on the cpu backend); the knob only
         # affects the CPU backend, so it is inert on TPU/GPU pods.
-        # Best-effort: a jax build without it surfaces its real error below.
-        try:
-            jax.config.update("jax_cpu_collectives_implementation", "gloo")
-        except Exception:  # noqa: BLE001 — older jax: no such config
-            pass
-    try:
-        jax.distributed.initialize(
-            coordinator_address=coordinator_address,
-            num_processes=num_processes,
-            process_id=process_id,
-        )
-    except (ValueError, RuntimeError):
-        if explicit:
-            raise
-        # auto-discovery found no cluster: single-process run (the reference's
-        # only mode)
+        jax.config.update("jax_cpu_collectives_implementation", "gloo")
+    jax.distributed.initialize(
+        coordinator_address=coordinator_address,
+        num_processes=num_processes,
+        process_id=process_id,
+    )
 
 
 def _platform_known_non_cpu() -> bool:

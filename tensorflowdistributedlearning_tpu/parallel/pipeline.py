@@ -97,10 +97,7 @@ def pipeline_apply_aux(
         return buf_next, (y, aux)
 
     zero = jnp.zeros_like(x_microbatches[0])
-    if hasattr(lax, "pcast"):
-        buf0 = lax.pcast(zero, axis_name, to="varying")
-    else:  # pragma: no cover - older jax
-        buf0 = lax.pvary(zero, (axis_name,))
+    buf0 = lax.pcast(zero, axis_name, to="varying")
     _, (ys, auxs) = lax.scan(tick, buf0, inject[:ticks])
 
     tail = lax.dynamic_slice_in_dim(ys, k_stages - 1, m_micro, axis=0)

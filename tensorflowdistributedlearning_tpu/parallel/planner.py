@@ -53,6 +53,8 @@ from typing import Any, Dict, List, Optional, Tuple
 
 import numpy as np
 
+from tensorflowdistributedlearning_tpu.utils import peaks as peaks_lib
+
 __all__ = [
     "PlanError",
     "Layout",
@@ -80,21 +82,12 @@ class PlanError(ValueError):
 
 # -- cost-model constants ----------------------------------------------------
 
-# peak bf16 matmul FLOP/s per chip by device_kind substring (public figures;
-# the same table bench.py prices MFU with). Unknown kinds (CPU hosts) fall
-# back to DEFAULT_PEAK_FLOPS — on a homogeneous mesh only the compute/comms
-# RATIO matters for candidate ordering, not the absolute scale.
-PEAK_FLOPS_BY_KIND = {
-    "v6e": 918e12,
-    "v6": 918e12,
-    "v5e": 197e12,
-    "v5p": 459e12,
-    "v5": 197e12,
-    "v4": 275e12,
-    "v3": 123e12,
-    "v2": 45e12,
-}
-DEFAULT_PEAK_FLOPS = 100e12
+# what-if planning for a CPU host (the forced-device test mesh, a pod layout
+# sketched from a laptop) has no published peak to price compute with; on a
+# homogeneous mesh only the compute/comms RATIO matters for candidate
+# ordering, not the absolute scale. Real chips are priced from the one peaks
+# table (utils/peaks.py), where an unknown TPU is an error.
+CPU_WHATIF_PEAK_FLOPS = 100e12
 # per-chip interconnect bandwidth the comm terms divide by (order-of-magnitude
 # ICI figure; DCN-crossing layouts are already excluded by the
 # spans-processes rule, so one constant suffices)
@@ -188,15 +181,11 @@ class Topology:
     device_kind: str = "cpu"
 
     def peak_flops(self) -> float:
-        kind = self.device_kind.lower()
-        for key, flops in PEAK_FLOPS_BY_KIND.items():
-            if key in kind:
-                return flops
-        return DEFAULT_PEAK_FLOPS
+        peaks = peaks_lib.device_peaks(self.device_kind)
+        return peaks.bf16_flops if peaks else CPU_WHATIF_PEAK_FLOPS
 
     def collective_latency_s(self) -> float:
-        kind = self.device_kind.lower()
-        if any(key in kind for key in PEAK_FLOPS_BY_KIND):
+        if peaks_lib.device_peaks(self.device_kind):
             return COLLECTIVE_LATENCY_S
         return COLLECTIVE_LATENCY_CPU_S
 

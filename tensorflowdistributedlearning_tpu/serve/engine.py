@@ -23,8 +23,6 @@ from __future__ import annotations
 import bisect
 import contextlib
 import logging
-import os
-import tempfile
 import threading
 import time
 from typing import Callable, Dict, Optional, Sequence, Tuple
@@ -49,52 +47,6 @@ class RequestTooLargeError(ValueError):
     """A request carries more examples than the largest compiled bucket —
     the caller must chunk it; silently splitting here would reorder the
     batcher's fairness guarantees."""
-
-
-# manifest-adjacent cache subdir an exporter may ship beside the artifact
-# (train/serving.py attach_compile_cache); warmup LOADS these executables
-# instead of compiling them — the load-not-compile replica path
-ARTIFACT_CACHE_SUBDIR = "compile_cache"
-
-
-def consume_artifact_cache(directory: str, manifest: Optional[Dict]) -> int:
-    """Fold an artifact's shipped compile-cache subdir into this process's
-    active persistent cache so the subsequent warmup loads, not compiles.
-
-    The manifest's ``compile_cache`` section records the subdir's
-    fingerprint at export time; a mismatch (truncated copy, mixed artifact)
-    warns and skips the shipped entries — a stale cache entry is harmless
-    (keys are content-addressed) but a torn one is not worth the risk. When
-    no cache dir is configured yet, the entries land in a throwaway temp
-    cache so the artifact directory itself is never written to at runtime.
-    Returns the number of entries merged (0 = nothing shipped/usable)."""
-    from tensorflowdistributedlearning_tpu.utils import compile_cache
-
-    sub = os.path.join(directory, ARTIFACT_CACHE_SUBDIR)
-    if not os.path.isdir(sub):
-        return 0
-    recorded = (manifest or {}).get("compile_cache")
-    if recorded and recorded.get("fingerprint"):
-        fresh = compile_cache.fingerprint(sub)
-        if fresh["fingerprint"] != recorded["fingerprint"]:
-            logger.warning(
-                "artifact %s ships a compile cache whose fingerprint does "
-                "not match its manifest (%s entries on disk vs %s recorded) "
-                "— skipping the shipped cache; warmup will compile",
-                directory, fresh["entries"], recorded.get("entries"),
-            )
-            return 0
-    dst = compile_cache.active_dir()
-    if dst is None:
-        dst = tempfile.mkdtemp(prefix="tfdl-compile-cache-")
-        if not compile_cache.configure(dst):
-            return 0
-    merged = compile_cache.merge(sub, dst)
-    if merged:
-        logger.info(
-            "loaded %d shipped compile-cache entries from %s", merged, sub
-        )
-    return merged
 
 
 def _tree_map(fn, tree):
@@ -197,13 +149,6 @@ class InferenceEngine:
 
         serve = serving_lib.load_serving_artifact(directory)
         manifest = serving_lib.read_manifest(directory)
-        # shipped cache entries must be active BEFORE warmup compiles the
-        # ladder — this is what turns a replica spawn into a load, not a
-        # compile (failures degrade to a normal compiling warmup)
-        try:
-            consume_artifact_cache(directory, manifest)
-        except Exception:  # noqa: BLE001 — a bad cache must not block serving
-            logger.warning("shipped compile cache unusable", exc_info=True)
         shape = manifest["input_shape"]
         if any(d is None for d in shape[1:]):
             raise ValueError(

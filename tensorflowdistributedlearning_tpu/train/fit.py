@@ -103,17 +103,17 @@ class ClassifierTrainer:
                 "fit() trains classification models; model_config.num_classes is None "
                 "(use train.trainer.Trainer for the segmentation task)"
             )
-        multihost.initialize()
         self.model_dir = model_dir
         self.data_dir = data_dir
         self.model_config = model_config
         self.train_config = train_config or TrainConfig()
-        if self.train_config.compile_cache_dir:
-            # before anything compiles (state init, eval, the step): a second
-            # same-shape run must LOAD its executables, not rebuild them
-            from tensorflowdistributedlearning_tpu.utils import compile_cache
+        # before anything compiles (state init, eval, the step): a second
+        # same-shape run must LOAD its executables, not rebuild them. The CLI
+        # has resolved the same directory already; this is the library
+        # caller's entry point (utils/compile_cache.py decides where it goes)
+        from tensorflowdistributedlearning_tpu.utils import compile_cache
 
-            compile_cache.configure(self.train_config.compile_cache_dir)
+        compile_cache.configure(self.train_config.compile_cache_dir)
         if self.train_config.parallelism == "auto" and plan is None:
             # the mesh is built below from the config's explicit degrees, so
             # an unresolved 'auto' here would silently train explicit while
@@ -1060,7 +1060,7 @@ class ClassifierTrainer:
                     logits = forward(st, x)
             else:
                 logits = forward(st, x)
-            out = task.serve_predictions(logits)
+            out = task.predictions(logits)
             return quantize.cast_outputs_float32(out)
 
         serve.quantization = quant_section
@@ -1291,10 +1291,8 @@ def fit_preset(
     # explicit validates the hand spec — either way an indivisible preset
     # fails HERE, at parse time, with the named constraint, and the plan's
     # predicted bytes/chip ride the run header
-    from tensorflowdistributedlearning_tpu.parallel import multihost
     from tensorflowdistributedlearning_tpu.parallel import planner as planner_lib
 
-    multihost.initialize()  # topology must see the full pod, like the mesh
     global_batch = batch_size or preset.global_batch
     if train_cfg.parallelism == "auto":
         # pin only what the CALLER explicitly asked for (explicit flags win);

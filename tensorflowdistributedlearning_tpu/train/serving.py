@@ -147,99 +147,6 @@ def load_serving_artifact(directory: str) -> Callable:
     return serve
 
 
-_ATTACH_SCRIPT = """
-import json, sys
-directory, cache_dir, raw = sys.argv[1], sys.argv[2], sys.argv[3]
-from tensorflowdistributedlearning_tpu.utils import compile_cache
-if not compile_cache.configure(cache_dir):
-    sys.exit(3)
-from tensorflowdistributedlearning_tpu.serve.engine import InferenceEngine
-buckets = json.loads(raw)
-kwargs = {"buckets": tuple(buckets)} if buckets else {}
-engine = InferenceEngine.from_artifact(directory, **kwargs)
-engine.warmup()
-print(json.dumps([int(b) for b in engine.buckets]))
-"""
-
-
-def attach_compile_cache(
-    directory: str, *, buckets=None, timeout_s: float = 600.0
-) -> Dict:
-    """Populate ``{directory}/compile_cache`` with the artifact's compiled
-    bucket-ladder executables and stamp the subdir's fingerprint into the
-    manifest — the load-not-compile serving contract.
-
-    The exporter pays the ladder compile ONCE, here; every replica that
-    later loads the artifact (fleet scale-up surge, promotion flip) merges
-    the shipped entries into its own persistent cache and goes ready on
-    load. The manifest section::
-
-        "compile_cache": {"subdir": "compile_cache",
-                          "buckets": [...], "entries": N,
-                          "fingerprint": "sha256..."}
-
-    lets consumers detect a torn/mixed copy before trusting the entries
-    (serve/engine.py consume_artifact_cache).
-
-    The ladder is compiled in a SUBPROCESS pinned to the serving topology
-    (one forced host device): cache keys hash the process-local backend
-    topology, so entries compiled in the training process — typically many
-    emulated devices, maybe a distributed world — would never match what a
-    single-process serve replica looks up. A replica on different hardware
-    or topology simply misses and compiles; shipped entries are an
-    optimization, never a correctness dependency. Returns the manifest
-    section ({} when the cache could not be populated — the export is
-    already on disk and unaffected)."""
-    import subprocess
-    import sys
-
-    from tensorflowdistributedlearning_tpu.utils import compile_cache
-
-    sub = os.path.join(directory, "compile_cache")
-    env = dict(os.environ)
-    env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=1"
-    # make the package importable even when running from a source tree
-    pkg_root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    env["PYTHONPATH"] = pkg_root + os.pathsep + env.get("PYTHONPATH", "")
-    argv = [
-        sys.executable, "-c", _ATTACH_SCRIPT,
-        directory, sub,
-        json.dumps([int(b) for b in buckets] if buckets else None),
-    ]
-    try:
-        proc = subprocess.run(
-            argv, env=env, capture_output=True, text=True, timeout=timeout_s
-        )
-    except (OSError, subprocess.TimeoutExpired) as e:
-        import logging
-
-        logging.getLogger(__name__).warning(
-            "compile-cache attach subprocess failed (%s) — artifact ships "
-            "without a cache; replicas compile cold", e,
-        )
-        return {}
-    if proc.returncode != 0:
-        import logging
-
-        logging.getLogger(__name__).warning(
-            "compile-cache attach exited rc=%d — artifact ships without a "
-            "cache; replicas compile cold. stderr tail: %s",
-            proc.returncode, proc.stderr[-500:],
-        )
-        return {}
-    bucket_list = json.loads(proc.stdout.strip().splitlines()[-1])
-    section = {
-        "subdir": "compile_cache",
-        "buckets": bucket_list,
-        **compile_cache.fingerprint(sub),
-    }
-    manifest = read_manifest(directory)
-    manifest["compile_cache"] = section
-    with open(os.path.join(directory, MANIFEST_NAME), "w") as f:
-        json.dump(manifest, f, indent=2)
-    return section
-
-
 def read_manifest(directory: str) -> Dict:
     """Read + validate an artifact manifest. The ONE site that applies the
     legacy defaults (pre-input_dtype manifests mean float32; no

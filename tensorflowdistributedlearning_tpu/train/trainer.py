@@ -127,9 +127,6 @@ class Trainer:
         unknown = set(kwargs) - _MODEL_FIELDS
         if unknown:
             raise ValueError(f"Unknown model config keys: {sorted(unknown)}")
-        # join the jax.distributed cluster (auto-discovery; quiet single-process
-        # fallback) BEFORE the first device query below builds the mesh
-        multihost.initialize()
         self.model_dir = model_dir
         self.data_directory = data_directory
         self.model_config = ModelConfig(**kwargs)
@@ -145,12 +142,13 @@ class Trainer:
         self.augment_config = augment_config or augment_lib.AugmentConfig(
             crop_probability=0.0
         )
-        if self.train_config.compile_cache_dir:
-            # before the first compile (fold state init): a restarted run
-            # loads its executables from the cache instead of rebuilding
-            from tensorflowdistributedlearning_tpu.utils import compile_cache
+        # before the first compile (fold state init): a restarted run loads
+        # its executables from the cache instead of rebuilding. The CLI has
+        # resolved the same directory already; this is the library caller's
+        # entry point (utils/compile_cache.py decides where the cache goes)
+        from tensorflowdistributedlearning_tpu.utils import compile_cache
 
-            compile_cache.configure(self.train_config.compile_cache_dir)
+        compile_cache.configure(self.train_config.compile_cache_dir)
         if self.train_config.parallelism == "auto" and plan is None:
             # same contract as ClassifierTrainer: the mesh is built below
             # from the explicit degrees, so 'auto' must be resolved (and its
@@ -986,7 +984,7 @@ class Trainer:
                     logits = forward(st, x)
             else:
                 logits = forward(st, x)
-            out = task.serve_predictions(logits)
+            out = task.predictions(logits)
             out = quantize.cast_outputs_float32(out)
             if nchw:
                 out = {k: jnp.transpose(v, (0, 3, 1, 2)) for k, v in out.items()}

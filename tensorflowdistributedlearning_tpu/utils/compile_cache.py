@@ -2,29 +2,41 @@
 
 Compilation is the biggest cold-start cliff in the stack: every serve
 replica recompiles its bucket ladder, every elastic resize recompiles the
-step function at the new world size, every train restart pays full warmup.
-JAX ships a content-addressed persistent compilation cache (keyed on the
-canonicalized StableHLO module + jaxlib version + registered XLA flags +
-compile options + device kinds); this module wires it through the CLI
-surface (``--compile-cache-dir`` on train/fit/serve/serve-fleet) and turns
-its hit/miss stream into telemetry the rest of obs/ can ledger.
+step function at the new world size, every train restart pays full warmup
+(the flagship train step alone is about a minute of XLA:TPU compile). JAX
+ships a content-addressed persistent compilation cache; this module decides
+WHERE it lives, once, for every entry point, and turns its hit/miss stream
+into telemetry the rest of obs/ can ledger.
 
-Three public seams:
+Placement (:func:`resolve`, applied by :func:`configure`), first match wins:
 
-- :func:`configure` points the process at a cache directory, forcing the
-  cache-everything knobs (JAX's defaults skip sub-second compiles, which on
-  CPU smoke scale means caching *nothing*). Unwritable directory degrades
-  to a warning + uncached run — a bad ``--compile-cache-dir`` must never
-  kill a training job.
+1. ``JAX_COMPILATION_CACHE_DIR`` in the environment — the operator placed
+   the cache, and then no code sets another (``--compile-cache-dir`` is
+   ignored with a log line);
+2. the ``--compile-cache-dir`` flag / ``TrainConfig.compile_cache_dir``;
+3. one fixed directory inside the checkout, :data:`DEFAULT_DIR` — unless
+   the process is pinned to the CPU backend, where there is no default:
+   XLA:CPU executables are machine-feature-sensitive (entries written on
+   another host warn on load and can SIGILL) and resumed resilience
+   children have crashed inside their serialization, so a CPU run caches
+   only where it is told to.
+
+The path is part of what a deployment keys on — a directory that moves
+never hits — so there is no temp-dir fallback anywhere: a resolved
+directory that cannot be written is an error (:class:`CompileCacheError`),
+not an uncached run.
+
+Whichever way the directory was chosen, :func:`configure` forces the
+cache-everything knobs (JAX's defaults skip sub-second compiles and small
+entries) and registers the hit/miss listeners:
+
 - :func:`consume_pending` is called by ``obs.recompile`` exactly once per
   backend-compile event to learn whether that compile was served from the
   cache (and how much compile time the hit saved). JAX fires the cache-hit
   monitoring events synchronously on the compiling thread *before* the
   compile-duration event closes, so a thread-local carries the verdict
   across the two listener callbacks.
-- :func:`fingerprint` / :func:`merge` support shipping a cache subdir
-  beside an exported serving artifact (manifest records the fingerprint;
-  serve merges the entries into its active cache before warmup).
+- :func:`stats` gives the process-wide hit/miss counters.
 
 Cache-key caveat (documented, load-bearing): keys hash the canonicalized
 module, jaxlib version, registered XLA flags, compile options AND the
@@ -34,25 +46,47 @@ only share entries when their whole topology matches rank-for-rank
 (verified empirically: rank 0 and rank 1 of the same 2-process world
 compute *different* keys for the same module). Consequences wired through
 this codebase: (1) the elastic AOT standby is a real (world-1)-process
-mini-world, not a solo emulator; (2) ``attach_compile_cache`` compiles the
-serving ladder in a 1-device subprocess because replicas load under the
-serving topology, not the trainer's; (3) ``configure`` disables the XLA
-autotune-cache debug option, whose directory (a path inside cache_dir)
-would otherwise be hashed into every key, pinning entries to one absolute
-cache path. Keys do NOT survive jaxlib upgrades or XLA flag changes.
+mini-world, not a solo emulator; (2) a serving ladder is compiled by the
+first server that loads the artifact, under the serving topology, and
+every later start on that machine loads it — an exporter that sees other
+devices than the server could not have produced matching entries;
+(3) ``configure`` disables the XLA autotune-cache debug option, whose
+directory (a path inside cache_dir) would otherwise be hashed into every
+key, pinning entries to one absolute cache path. Keys do NOT survive jaxlib
+upgrades or XLA flag changes.
 """
 
 from __future__ import annotations
 
-import hashlib
 import logging
 import os
-import shutil
 import tempfile
 import threading
 from typing import Dict, Optional, Tuple
 
+import jax
+from jax import monitoring as _monitoring
+from jax.experimental.compilation_cache import compilation_cache as jax_cache
+
 logger = logging.getLogger(__name__)
+
+ENV_VAR = "JAX_COMPILATION_CACHE_DIR"
+
+# the one fixed directory: the checkout root (git-ignored), so every entry
+# point started from this tree — the CLI, chip_smoke.py, bench.py — finds
+# what the previous one compiled
+DEFAULT_DIR = os.path.join(
+    os.path.dirname(
+        os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    ),
+    ".jax_cache_tpu",
+)
+
+
+class CompileCacheError(RuntimeError):
+    """The resolved cache directory cannot be used. Raised, not logged: a run
+    that silently pays every compile in full looks like a slow device."""
+
 
 # jax.monitoring event names fired by jax._src.compiler.compile_or_get_cached
 # (verified against the installed jax; literal strings are the stable API)
@@ -60,14 +94,8 @@ _REQUEST_EVENT = "/jax/compilation_cache/compile_requests_use_cache"
 _HIT_EVENT = "/jax/compilation_cache/cache_hits"
 _SAVED_EVENT = "/jax/compilation_cache/compile_time_saved_sec"
 
-try:
-    from jax import monitoring as _monitoring
-except Exception:  # noqa: BLE001 — jax without the monitoring API
-    _monitoring = None
-
 _lock = threading.Lock()
 _listener_registered = False
-_active_dir: Optional[str] = None
 
 # Per-thread in-flight verdict: compile_or_get_cached fires request → (hit,
 # saved) → the backend-compile duration event, all on the compiling thread,
@@ -103,24 +131,15 @@ def _on_duration_event(event: str, duration_secs: float, **kwargs) -> None:
             _stats["saved_s"] += float(duration_secs)
 
 
-def _ensure_listeners() -> bool:
+def _ensure_listeners() -> None:
     """Register the cache-hit monitoring listeners once per process."""
     global _listener_registered
-    if _monitoring is None:
-        return False
     with _lock:
         if _listener_registered:
-            return True
-        try:
-            _monitoring.register_event_listener(_on_record_event)
-            _monitoring.register_event_duration_secs_listener(
-                _on_duration_event
-            )
-        except Exception as e:  # noqa: BLE001 — degrade, never crash
-            logger.warning("compile-cache hit telemetry unavailable: %s", e)
-            return False
+            return
+        _monitoring.register_event_listener(_on_record_event)
+        _monitoring.register_event_duration_secs_listener(_on_duration_event)
         _listener_registered = True
-    return True
 
 
 def consume_pending() -> Tuple[Optional[bool], float]:
@@ -158,128 +177,81 @@ def reset_stats() -> None:
 
 
 def active_dir() -> Optional[str]:
-    """The cache directory this process was configured with (None = off)."""
-    return _active_dir
+    """The directory jax's persistent cache writes to (None = off). Read from
+    jax's own config, so it is true however the directory was set."""
+    return jax.config.jax_compilation_cache_dir or None
 
 
-def _probe_writable(cache_dir: str) -> bool:
+def _cpu_pinned() -> bool:
+    """Whether this process is pinned to the CPU backend, decided WITHOUT
+    initializing one (controllers that spawn chip users call configure too)."""
+    platforms = jax.config.jax_platforms or os.environ.get("JAX_PLATFORMS")
+    return (platforms or "").strip().lower() == "cpu"
+
+
+def resolve(flag_dir: Optional[str] = None) -> Tuple[Optional[str], str]:
+    """Where this process's compile cache goes: ``(directory, source)`` with
+    source ``env`` | ``flag`` | ``default`` | ``off`` (directory None)."""
+    env_dir = os.environ.get(ENV_VAR)
+    if env_dir:
+        return os.path.abspath(os.path.expanduser(env_dir)), "env"
+    if flag_dir:
+        return os.path.abspath(os.path.expanduser(flag_dir)), "flag"
+    if _cpu_pinned():
+        return None, "off"
+    return DEFAULT_DIR, "default"
+
+
+def _probe_writable(cache_dir: str) -> None:
     try:
         os.makedirs(cache_dir, exist_ok=True)
         fd, probe = tempfile.mkstemp(prefix=".cache_probe_", dir=cache_dir)
         os.close(fd)
         os.unlink(probe)
-        return True
-    except OSError:
-        return False
+    except OSError as e:
+        raise CompileCacheError(
+            f"compile cache dir {cache_dir} is not writable ({e}); place the "
+            f"cache with {ENV_VAR} or --compile-cache-dir"
+        ) from e
 
 
-def configure(cache_dir: Optional[str]) -> bool:
-    """Point this process's XLA compiles at a persistent cache directory.
+def configure(flag_dir: Optional[str] = None) -> Optional[str]:
+    """Resolve the cache directory (:func:`resolve`) and point this process's
+    XLA compiles at it; returns the directory, or None when the cache is off
+    (a CPU-pinned run that named none).
 
-    Must run before the first compile to catch everything, but is safe (and
-    effective for later compiles) at any point — an already-initialized
-    cache backend is reset so the new directory takes. Forces the
-    cache-everything knobs: JAX's defaults skip compiles under 1 s and tiny
-    entries, which at CPU-smoke scale silently caches nothing.
-
-    Returns True when the cache is active. An unwritable/uncreatable
-    directory logs a warning and returns False with the process left
-    uncached — degradation, never a crash. ``cache_dir=None`` is a no-op
-    False (callers can pass the knob through unconditionally).
+    Every entry point calls this once, before its first compile. Safe at any
+    later point too, and idempotent: an already-initialized cache backend is
+    reset only when the directory changes. Raises :class:`CompileCacheError`
+    when the directory cannot be written.
     """
-    global _active_dir
-    if not cache_dir:
-        return False
-    cache_dir = os.path.abspath(os.path.expanduser(cache_dir))
-    if not _probe_writable(cache_dir):
-        logger.warning(
-            "compile cache dir %s is not writable — proceeding UNCACHED "
-            "(every compile will be paid in full)",
-            cache_dir,
+    cache_dir, source = resolve(flag_dir)
+    if cache_dir is None:
+        return None
+    if source == "env" and flag_dir:
+        logger.info(
+            "%s=%s places the compile cache; ignoring --compile-cache-dir %s",
+            ENV_VAR, cache_dir, flag_dir,
         )
-        return False
-    try:
-        import jax
-
-        jax.config.update("jax_compilation_cache_dir", cache_dir)
-        # cache EVERYTHING: the defaults (min 1.0s compile, min entry size)
-        # are tuned for real accelerators and would skip our smoke compiles
-        jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
-        jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
-        # The default enables the XLA per-fusion autotune cache, whose
-        # directory (a path INSIDE cache_dir) is baked into compile options
-        # and is NOT stripped from the cache key — so keys would depend on
-        # the cache dir's absolute path and entries shipped beside an
-        # artifact could never hit. Disable it; it's a GPU-only feature.
-        jax.config.update("jax_persistent_cache_enable_xla_caches", "none")
-    except Exception as e:  # noqa: BLE001 — old jax without the knobs
-        logger.warning("persistent compile cache unavailable: %s", e)
-        return False
-    # The cache backend latches on first compile: _cache_initialized flips
-    # True even when the dir was unset (leaving _cache None *permanently*),
-    # so a late configure() must reset unconditionally — checking _cache
-    # alone misses the initialized-while-disabled state.
-    try:
-        from jax._src import compilation_cache as _cc
-
-        _cc.reset_cache()
-    except Exception:  # noqa: BLE001 — private seam; best-effort
-        pass
+    _probe_writable(cache_dir)
+    changed = active_dir() != cache_dir or not _listener_registered
+    # env placement: jax read the variable itself at import; writing the
+    # absolute form back only normalizes a relative path
+    jax.config.update("jax_compilation_cache_dir", cache_dir)
+    # cache EVERYTHING: the defaults (min 1.0s compile, min entry size) skip
+    # the small programs around the step (init, augmentation, metric merges)
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+    jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
+    # The default enables the XLA per-fusion autotune cache, whose
+    # directory (a path INSIDE cache_dir) is baked into compile options
+    # and is NOT stripped from the cache key — so keys would depend on
+    # the cache dir's absolute path. Disable it; it's a GPU-only feature.
+    jax.config.update("jax_persistent_cache_enable_xla_caches", "none")
+    if changed:
+        # The cache backend latches on first compile — even when the dir
+        # was unset, leaving it off permanently — so a configure() that
+        # lands after one must reset it for the directory to take.
+        jax_cache.reset_cache()
+        logger.info("persistent compile cache at %s (%s)", cache_dir, source)
     _ensure_listeners()
-    _active_dir = cache_dir
-    logger.info("persistent compile cache at %s", cache_dir)
-    return True
-
-
-# -- artifact cache subdir support ------------------------------------------
-
-
-def fingerprint(cache_dir: str) -> Dict[str, object]:
-    """Content fingerprint of a cache directory for manifest stamping.
-
-    Hashes the sorted (relative path, size) list — cheap, order-stable, and
-    enough to detect a truncated/mixed copy. Entry *contents* are already
-    content-addressed by JAX's own key, so hashing bytes again buys nothing.
-    """
-    entries = []
-    if os.path.isdir(cache_dir):
-        for root, _dirs, files in os.walk(cache_dir):
-            for name in sorted(files):
-                path = os.path.join(root, name)
-                rel = os.path.relpath(path, cache_dir)
-                try:
-                    entries.append((rel, os.path.getsize(path)))
-                except OSError:
-                    continue
-    entries.sort()
-    h = hashlib.sha256()
-    for rel, size in entries:
-        h.update(f"{rel}\x00{size}\n".encode())
-    return {"entries": len(entries), "fingerprint": h.hexdigest()}
-
-
-def merge(src_dir: str, dst_dir: str) -> int:
-    """Copy cache entries from ``src_dir`` into ``dst_dir`` (skip existing).
-
-    Used by serve to fold an artifact's shipped cache subdir into the
-    replica's active cache directory so warmup loads instead of compiling.
-    Returns the number of entries copied; I/O failures skip the entry (a
-    missed merge costs one compile, not the replica).
-    """
-    copied = 0
-    if not os.path.isdir(src_dir):
-        return 0
-    for root, _dirs, files in os.walk(src_dir):
-        for name in files:
-            src = os.path.join(root, name)
-            rel = os.path.relpath(src, src_dir)
-            dst = os.path.join(dst_dir, rel)
-            if os.path.exists(dst):
-                continue
-            try:
-                os.makedirs(os.path.dirname(dst), exist_ok=True)
-                shutil.copy2(src, dst)
-                copied += 1
-            except OSError as e:
-                logger.warning("cache merge skipped %s: %s", rel, e)
-    return copied
+    return cache_dir

@@ -350,11 +350,11 @@ DEFAULT_GROUPS: Dict[str, Tuple[str, ...]] = {
     # name ("_qmm_kernel", "_qconv_kernel", ...). The int8 matmul/conv run
     # the MXU just like their XLA counterparts, so they must land in the
     # compute buckets the roofline classifier keys on; "qconv" is caught by
-    # the "conv" needle, "qmm" needs its own. The fused epilogue/mask heads
-    # are single-HBM-pass elementwise work — same class as XLA fusions.
+    # the "conv" needle, "qmm" needs its own. The fused bias+act epilogue
+    # is single-HBM-pass elementwise work — same class as XLA fusions.
     "conv": ("convolution", "conv"),
     "matmul": ("dot", "einsum", "qmm"),
-    "fusion(elementwise/bn)": ("fusion", "fused_bias_act", "sigmoid_mask"),
+    "fusion(elementwise/bn)": ("fusion", "fused_bias_act"),
     "collectives": (
         "all-reduce",
         "all-gather",

@@ -5,11 +5,12 @@ The contracts under test are the ones the cold-start work ships on:
 - the persistent compile cache survives the PROCESS — a fresh interpreter
   running the same-shape computation loads its executables (ledgered cache
   hits, zero real compiles) instead of rebuilding them;
-- an exported artifact's shipped ``compile_cache/`` subdir round-trips
-  through the real manifest seam (attach at export, fingerprint-verified
-  consume at load) and a warm replica's warmup is compile-free;
-- an unwritable cache dir degrades to an uncached run with a warning —
-  never a crash (utils/compile_cache.py configure());
+- ONE resolver places the cache (utils/compile_cache.py): the environment's
+  JAX_COMPILATION_CACHE_DIR beats the flag beats the checkout's fixed
+  directory, a CPU-pinned run has no default, nothing ever lands in a temp
+  dir, and a directory that cannot be written is an error;
+- the first server to load an artifact compiles its bucket ladder into that
+  cache and every later start loads it;
 - parallel bucket warmup preserves the warm-mark ordering and the
   ``warmed_buckets`` accounting;
 - ``replica_ready.time_to_ready_s`` and the compile-cache verdicts surface
@@ -106,8 +107,7 @@ def test_second_interpreter_loads_from_cache(cache_roundtrip):
     # run 0 populated the cache (misses), run 1 consumed it (hits, 0 misses)
     assert cold["stats"]["misses"] >= 2 and cold["stats"]["hits"] == 0
     assert warm["stats"]["hits"] >= 2 and warm["stats"]["misses"] == 0
-    entries = compile_cache.fingerprint(cache_dir)["entries"]
-    assert entries >= 2
+    assert len(os.listdir(cache_dir)) >= 2
 
 
 def test_cache_verdicts_reach_the_ledger(cache_roundtrip):
@@ -143,32 +143,96 @@ def test_report_renders_hit_ratio(cache_roundtrip):
     assert "100% served from cache" in text
 
 
-# -- degradation: unwritable cache dir ---------------------------------------
+# -- placement: one resolver, env > flag > fixed dir ---------------------------
 
 
-def test_unwritable_cache_dir_degrades_uncached(tmp_path, caplog):
-    ro = tmp_path / "ro"
-    ro.mkdir()
-    os.chmod(ro, stat.S_IRUSR | stat.S_IXUSR)
-    try:
-        if os.access(str(ro / "probe"), os.W_OK) or os.getuid() == 0:
-            pytest.skip("running as root — read-only dirs are writable")
-        before = compile_cache.active_dir()
-        with caplog.at_level("WARNING"):
-            assert compile_cache.configure(str(ro)) is False
-        assert compile_cache.active_dir() == before  # untouched, not crashed
-        assert any("UNCACHED" in r.message for r in caplog.records)
-    finally:
-        os.chmod(ro, stat.S_IRWXU)
+@pytest.fixture
+def accelerator_run(monkeypatch):
+    """A process not pinned to the CPU, with no cache placed from outside."""
+    monkeypatch.delenv(compile_cache.ENV_VAR, raising=False)
+    monkeypatch.setattr(compile_cache, "_cpu_pinned", lambda: False)
 
 
-def test_configure_none_is_a_noop():
+def test_resolver_env_beats_flag_beats_fixed_dir(
+    accelerator_run, monkeypatch, tmp_path
+):
+    assert compile_cache.resolve() == (compile_cache.DEFAULT_DIR, "default")
+    assert compile_cache.DEFAULT_DIR == os.path.join(REPO, ".jax_cache_tpu")
+    flag = str(tmp_path / "flag")
+    assert compile_cache.resolve(flag) == (flag, "flag")
+    env = str(tmp_path / "env")
+    monkeypatch.setenv(compile_cache.ENV_VAR, env)
+    assert compile_cache.resolve(flag) == (env, "env")
+    assert compile_cache.resolve() == (env, "env")
+
+
+def test_cpu_pinned_run_has_no_default_cache(monkeypatch, tmp_path):
+    """The suite itself runs CPU-pinned: no flag, no env -> no cache (XLA:CPU
+    entries are machine-feature-sensitive), and configure() is a no-op."""
+    monkeypatch.delenv(compile_cache.ENV_VAR, raising=False)
+    assert compile_cache.resolve() == (None, "off")
     before = compile_cache.active_dir()
-    assert compile_cache.configure(None) is False
+    assert compile_cache.configure(None) is None
     assert compile_cache.active_dir() == before
+    # ...but a CPU run still caches where it is told to
+    flag = str(tmp_path / "flag")
+    assert compile_cache.resolve(flag) == (flag, "flag")
 
 
-# -- artifact cache subdir: attach -> fingerprint -> consume -----------------
+def test_unwritable_named_cache_dir_is_an_error(monkeypatch, tmp_path):
+    """A directory named from outside that cannot be written raises — an
+    uncached run looks like a slow device. (A path under a regular FILE
+    cannot be created even by root.)"""
+    blocker = tmp_path / "file"
+    blocker.write_text("not a directory")
+    before = compile_cache.active_dir()
+    for placed_by_env in (False, True):
+        target = str(blocker / "cache")
+        if placed_by_env:
+            monkeypatch.setenv(compile_cache.ENV_VAR, target)
+        else:
+            monkeypatch.delenv(compile_cache.ENV_VAR, raising=False)
+        with pytest.raises(compile_cache.CompileCacheError, match="writable"):
+            compile_cache.configure(None if placed_by_env else target)
+    assert compile_cache.active_dir() == before  # untouched
+
+
+_ENV_PLACED_SCRIPT = """
+import json, os, sys
+sys.path.insert(0, {repo!r})
+from tensorflowdistributedlearning_tpu.utils import compile_cache
+placed = compile_cache.configure({flag!r})
+import jax, jax.numpy as jnp
+jax.block_until_ready(jax.jit(lambda x: jnp.tanh(x) * 3.0)(jnp.ones((4,))))
+print(json.dumps({{
+    "placed": placed,
+    "jax_dir": jax.config.jax_compilation_cache_dir,
+    "min_compile_s": jax.config.jax_persistent_cache_min_compile_time_secs,
+    "stats": compile_cache.stats(),
+}}))
+"""
+
+
+def test_env_placed_cache_gets_the_listeners_and_knobs(tmp_path):
+    """JAX_COMPILATION_CACHE_DIR set from outside: every entry lands there,
+    the flag is ignored, and the hit/miss listeners and cache-everything
+    knobs apply all the same."""
+    env_dir, flag_dir = str(tmp_path / "env"), str(tmp_path / "flag")
+    script = _ENV_PLACED_SCRIPT.format(repo=REPO, flag=flag_dir)
+    out = subprocess.run(
+        [sys.executable, "-c", script],
+        env=_env({compile_cache.ENV_VAR: env_dir}),
+        capture_output=True, text=True, timeout=240,
+    )
+    assert out.returncode == 0, out.stderr[-2000:]
+    res = json.loads(out.stdout.strip().splitlines()[-1])
+    assert res["placed"] == env_dir and res["jax_dir"] == env_dir
+    assert res["min_compile_s"] == 0.0  # a sub-second compile was cached
+    assert res["stats"]["misses"] >= 1  # the listeners saw it
+    assert os.listdir(env_dir) and not os.path.exists(flag_dir)
+
+
+# -- the first server compiles the ladder, every later start loads it ---------
 
 
 @pytest.fixture(scope="module")
@@ -190,33 +254,24 @@ def serve_fn():
 
 
 @pytest.fixture(scope="module")
-def cached_artifact(tmp_path_factory, serve_fn):
-    """An exported artifact with its compile cache attached through the
-    real seam (train/serving.py attach_compile_cache)."""
+def artifact(tmp_path_factory, serve_fn):
     from tensorflowdistributedlearning_tpu.train import serving as serving_lib
 
     directory = str(tmp_path_factory.mktemp("artifact") / "art")
     serving_lib.export_serving_artifact(serve_fn, (1, FEATURES), directory)
-    section = serving_lib.attach_compile_cache(directory, buckets=(1, 4))
-    return directory, section
+    return directory
 
 
-def test_attach_stamps_manifest_fingerprint(cached_artifact):
-    from tensorflowdistributedlearning_tpu.serve.engine import (
-        ARTIFACT_CACHE_SUBDIR,
-    )
+def test_export_ships_no_cache_of_its_own(artifact):
+    """The artifact is the module and its manifest. Where compiled ladders
+    live is the resolver's business — an exporter that sees other devices
+    than the server could not have produced entries the server would hit."""
     from tensorflowdistributedlearning_tpu.train import serving as serving_lib
 
-    directory, section = cached_artifact
-    assert section["subdir"] == ARTIFACT_CACHE_SUBDIR
-    assert section["entries"] >= 1
-    assert section["buckets"] == [1, 4]
-    sub = os.path.join(directory, ARTIFACT_CACHE_SUBDIR)
-    assert os.path.isdir(sub)
-    manifest = serving_lib.read_manifest(directory)
-    assert manifest["compile_cache"]["fingerprint"] == section["fingerprint"]
-    # the attach must NOT leave the process writing into the artifact
-    assert compile_cache.active_dir() != sub
+    assert sorted(os.listdir(artifact)) == sorted(
+        [serving_lib.ARTIFACT_NAME, serving_lib.MANIFEST_NAME]
+    )
+    assert "compile_cache" not in serving_lib.read_manifest(artifact)
 
 
 _LOAD_SCRIPT = """
@@ -247,58 +302,46 @@ def _load_replica(artifact: str, cache_dir: str) -> dict:
     return json.loads(out.stdout.strip().splitlines()[-1])
 
 
-def test_warm_artifact_load_is_compile_free(cached_artifact, tmp_path):
-    directory, _ = cached_artifact
-    res = _load_replica(directory, str(tmp_path / "replica_cache"))
-    # every warmup compile answered from the shipped entries
-    assert res["warmed"] == [1, 4]
-    assert res["stats"]["hits"] >= 2
-    assert res["stats"]["misses"] == 0
+@pytest.fixture(scope="module")
+def two_server_starts(artifact, tmp_path_factory):
+    cache_dir = str(tmp_path_factory.mktemp("replica_cache"))
+    return [_load_replica(artifact, cache_dir) for _ in range(2)]
 
 
-def test_cold_artifact_load_compiles(cached_artifact, tmp_path):
-    import shutil
-
-    from tensorflowdistributedlearning_tpu.serve.engine import (
-        ARTIFACT_CACHE_SUBDIR,
-    )
-
-    directory, _ = cached_artifact
-    bare = str(tmp_path / "bare_artifact")
-    shutil.copytree(directory, bare)
-    shutil.rmtree(os.path.join(bare, ARTIFACT_CACHE_SUBDIR))
-    res = _load_replica(bare, str(tmp_path / "replica_cache"))
-    assert res["warmed"] == [1, 4]
-    assert res["stats"]["misses"] >= 2
-    assert res["stats"]["hits"] == 0
+def test_first_server_start_compiles_the_ladder(two_server_starts):
+    first, _ = two_server_starts
+    assert first["warmed"] == [1, 4]
+    assert first["stats"]["misses"] >= 2
+    assert first["stats"]["hits"] == 0
 
 
-def test_torn_shipped_cache_is_refused(cached_artifact, tmp_path, caplog):
-    """A shipped cache whose fingerprint mismatches the manifest (truncated
-    copy, mixed artifact) is skipped — warmup compiles, serving proceeds."""
-    import shutil
+def test_second_server_start_is_compile_free(two_server_starts):
+    _, second = two_server_starts
+    # every warmup compile answered from what the first start cached
+    assert second["warmed"] == [1, 4]
+    assert second["stats"]["hits"] >= 2
+    assert second["stats"]["misses"] == 0
 
-    from tensorflowdistributedlearning_tpu.serve.engine import (
-        ARTIFACT_CACHE_SUBDIR,
-        consume_artifact_cache,
-    )
-    from tensorflowdistributedlearning_tpu.train import serving as serving_lib
 
-    directory, _ = cached_artifact
-    torn = str(tmp_path / "torn_artifact")
-    shutil.copytree(directory, torn)
-    sub = os.path.join(torn, ARTIFACT_CACHE_SUBDIR)
-    entry = next(
-        os.path.join(root, f)
-        for root, _, files in os.walk(sub)
-        for f in files
-    )
-    with open(entry, "ab") as fh:
-        fh.write(b"torn")
-    manifest = serving_lib.read_manifest(torn)
-    with caplog.at_level("WARNING"):
-        assert consume_artifact_cache(torn, manifest) == 0
-    assert any("fingerprint" in r.message for r in caplog.records)
+def test_engine_load_never_places_a_cache_in_a_temp_dir(
+    artifact, monkeypatch
+):
+    """No cache configured (this CPU-pinned suite): loading and warming an
+    artifact must neither configure one nor create a temp dir for one — a
+    directory that moves never hits."""
+    import tempfile
+
+    from tensorflowdistributedlearning_tpu.serve.engine import InferenceEngine
+
+    def no_temp_dirs(*args, **kwargs):
+        raise AssertionError("a temp dir was created on the load path")
+
+    monkeypatch.setattr(tempfile, "mkdtemp", no_temp_dirs)
+    before = compile_cache.active_dir()
+    eng = InferenceEngine.from_artifact(artifact, buckets=(1, 4))
+    eng.warmup()
+    assert eng.warmed_buckets == {1, 4}
+    assert compile_cache.active_dir() == before
 
 
 # -- parallel warmup: ordering + accounting ----------------------------------

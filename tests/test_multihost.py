@@ -13,12 +13,37 @@ from tensorflowdistributedlearning_tpu.parallel.mesh import (
 )
 
 
-def test_initialize_is_safe_single_process():
-    multihost.initialize()  # no coordinator: must not raise
+def test_a_run_that_names_no_coordinator_joins_nothing(monkeypatch, tmp_path):
+    """One process over its host's chips never calls
+    jax.distributed.initialize(): jax's auto-discovery asks a metadata server
+    a sealed single-host machine does not have. Both trainers build without
+    it; the world is the one process."""
+    import pytest
+
+    def joined(*args, **kwargs):
+        raise AssertionError("jax.distributed.initialize() was called")
+
+    monkeypatch.setattr(jax.distributed, "initialize", joined)
+    from tensorflowdistributedlearning_tpu.config import ModelConfig
+    from tensorflowdistributedlearning_tpu.train.fit import ClassifierTrainer
+    from tensorflowdistributedlearning_tpu.train.trainer import Trainer
+
+    Trainer(str(tmp_path / "seg"), "", n_blocks=(1, 1, 1), base_depth=8)
+    ClassifierTrainer(
+        str(tmp_path / "cls"),
+        None,
+        ModelConfig(
+            num_classes=10, input_shape=(32, 32), input_channels=3,
+            n_blocks=(1, 1, 1), base_depth=8,
+        ),
+    )
     info = multihost.process_info()
     assert info["process_count"] == 1
     assert info["process_index"] == 0
     assert info["global_device_count"] >= 8
+    # an explicit world is the only way in: the arguments are not optional
+    with pytest.raises(TypeError):
+        multihost.initialize()
 
 
 def test_global_shard_batch_matches_shard_batch():

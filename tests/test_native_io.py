@@ -187,3 +187,47 @@ def test_imagefolder_accepts_jpeg(tmp_path):
     assert ds.num_classes == 2
     batch = next(imagefolder.train_batches(ds, 4, seed=0, steps=1))
     assert batch["images"].shape == (4, 32, 32, 3)
+
+
+def test_library_is_named_by_a_hash_of_its_source(tmp_path):
+    """A copied tree does not promise mtimes, so freshness is not a
+    timestamp: the library built from this source lives at a path that
+    carries the source's hash, and other source means another path."""
+    from tensorflowdistributedlearning_tpu.native import loader
+
+    a, b = tmp_path / "a.cc", tmp_path / "b.cc"
+    a.write_text("int f() { return 1; }\n")
+    b.write_text("int f() { return 2; }\n")
+    path_a = loader._library_path(str(a), "libx")
+    assert path_a == loader._library_path(str(a), "libx")  # content, not time
+    assert path_a != loader._library_path(str(b), "libx")
+    assert os.path.dirname(path_a) == loader._BUILD_DIR
+    assert os.path.basename(path_a).startswith("libx-")
+
+
+def test_a_stale_library_under_the_old_name_is_never_loaded(monkeypatch):
+    """What an older checkout left in _build/ under the unhashed name — built
+    from who knows which source — is not what the loader looks for."""
+    from tensorflowdistributedlearning_tpu.native import loader
+
+    stale = os.path.join(loader._BUILD_DIR, "libtfdl_io.so")
+    os.makedirs(loader._BUILD_DIR, exist_ok=True)
+    created = not os.path.exists(stale)
+    if created:
+        with open(stale, "wb") as f:
+            f.write(b"not a shared library")
+    try:
+        loaded = []
+        real_cdll = loader.ctypes.CDLL
+        monkeypatch.setattr(
+            loader.ctypes, "CDLL",
+            lambda path, *a, **k: loaded.append(path) or real_cdll(path, *a, **k),
+        )
+        monkeypatch.setattr(loader, "_tried", False)
+        monkeypatch.setattr(loader, "_lib", None)
+        assert loader.native_available()
+        assert loaded == [loader._library_path(loader._SRC, "libtfdl_io")]
+        assert stale not in loaded
+    finally:
+        if created:
+            os.remove(stale)

@@ -306,7 +306,14 @@ def test_fused_bias_act_validation():
         fused_bias_act(jnp.zeros((2, 4)), jnp.zeros((3,)), interpret=True)
 
 
-# -- fused sigmoid + threshold mask head (segmentation serve path) ------------
+# -- the segmentation serve head: plain XLA, on purpose ------------------------
+#
+# PR 20 routed the serving closure's head through a Pallas sigmoid+threshold
+# kernel to save HBM passes. Its block shape was one Mosaic refuses for every
+# batch but 1 (and for the exported symbolic batch), so `train --export-serving`
+# died on a TPU — and XLA:TPU already emits the plain head as ONE multi-output
+# fusion that reads the logits once (compiled for a v5e, PR 21). The kernel is
+# gone; these pin what serving relies on from the plain head.
 
 
 def _mask_logits(shape=(2, 9, 9, 1), seed=22):
@@ -320,53 +327,46 @@ def _mask_logits(shape=(2, 9, 9, 1), seed=22):
 
 
 @pytest.mark.parametrize("threshold", [0.5, 0.3])
-def test_fused_sigmoid_mask_bit_identical(threshold):
-    """The contract the serve head relies on: fusing is a memory-traffic
-    change, not a numerics change — BITWISE equality with the unfused ops."""
-    from tensorflowdistributedlearning_tpu.ops.pallas_kernels import (
-        fused_sigmoid_mask,
-        fused_sigmoid_mask_reference,
-    )
-
-    logits = _mask_logits()
-    p_ref, m_ref = fused_sigmoid_mask_reference(logits, threshold)
-    for kwargs in ({"interpret": True}, {}):  # kernel body AND auto-fallback
-        probs, mask = fused_sigmoid_mask(logits, threshold, **kwargs)
-        assert probs.dtype == logits.dtype and mask.dtype == jnp.float32
-        np.testing.assert_array_equal(np.asarray(probs), np.asarray(p_ref))
-        np.testing.assert_array_equal(np.asarray(mask), np.asarray(m_ref))
-        assert set(np.unique(np.asarray(mask))) <= {0.0, 1.0}
-
-
-def test_fused_sigmoid_mask_vmem_and_rank_fallbacks():
-    from tensorflowdistributedlearning_tpu.ops.pallas_kernels import (
-        fused_sigmoid_mask,
-        fused_sigmoid_mask_reference,
-    )
-
-    logits = _mask_logits((2, 64, 64, 1), seed=23)
-    p_ref, m_ref = fused_sigmoid_mask_reference(logits, 0.5)
-    p, m = fused_sigmoid_mask(logits, 0.5, interpret=True, vmem_limit_bytes=128)
-    np.testing.assert_array_equal(np.asarray(p), np.asarray(p_ref))
-    np.testing.assert_array_equal(np.asarray(m), np.asarray(m_ref))
-    v = jnp.asarray([0.0, -1.0, 3.0], jnp.float32)  # rank-1: reference path
-    p1, m1 = fused_sigmoid_mask(v, 0.5, interpret=True)
-    pr, mr = fused_sigmoid_mask_reference(v, 0.5)
-    np.testing.assert_array_equal(np.asarray(p1), np.asarray(pr))
-    np.testing.assert_array_equal(np.asarray(m1), np.asarray(mr))
-
-
-def test_segmentation_serve_predictions_uses_fused_head():
-    """SegmentationTask.serve_predictions must agree bitwise with the
-    training-path predictions() dict — same probabilities, same mask."""
+def test_segmentation_head_mask_is_a_strict_threshold_of_its_probabilities(
+    threshold,
+):
     from tensorflowdistributedlearning_tpu.train.step import SegmentationTask
 
-    task = SegmentationTask()
-    logits = _mask_logits((2, 5, 5, 1), seed=24)
-    served = task.serve_predictions(logits)
-    trained = task.predictions(logits)
-    assert set(served) == set(trained)
-    for k in served:
-        np.testing.assert_array_equal(
-            np.asarray(served[k]), np.asarray(trained[k])
-        )
+    logits = _mask_logits()
+    out = SegmentationTask(threshold=threshold).predictions(logits)
+    probs, mask = np.asarray(out["probabilities"]), np.asarray(out["mask"])
+    assert out["probabilities"].dtype == logits.dtype
+    assert out["mask"].dtype == jnp.float32
+    assert set(np.unique(mask)) <= {0.0, 1.0}
+    np.testing.assert_array_equal(mask, (probs > threshold).astype(np.float32))
+    # sigmoid(0) == 0.5 exactly: strictly-greater keeps it out at 0.5
+    assert mask.flat[0] == (1.0 if threshold < 0.5 else 0.0)
+
+
+def test_serving_has_one_head_for_every_task():
+    """The serving closures call `predictions` — there is no second,
+    serving-only head to keep bit-identical with it."""
+    from tensorflowdistributedlearning_tpu.ops import pallas_kernels
+    from tensorflowdistributedlearning_tpu.train.step import (
+        ClassificationTask,
+        SegmentationTask,
+    )
+
+    for task in (SegmentationTask(), ClassificationTask()):
+        assert not hasattr(task, "serve_predictions")
+    assert not hasattr(pallas_kernels, "fused_sigmoid_mask")
+
+
+def test_segmentation_head_lowers_for_tpu_without_a_custom_call():
+    """One head, any batch: the plain ops lower for a TPU at the exported
+    symbolic batch with no Mosaic call for the compiler to refuse."""
+    from jax import export as jax_export
+
+    from tensorflowdistributedlearning_tpu.train.step import SegmentationTask
+
+    (b,) = jax_export.symbolic_shape("b")
+    exported = jax_export.export(
+        jax.jit(SegmentationTask().predictions), platforms=["tpu"]
+    )(jax.ShapeDtypeStruct((b, 101, 101, 1), jnp.float32))
+    assert "tpu_custom_call" not in exported.mlir_module()
+    assert exported.platforms == ("tpu",)

@@ -13,6 +13,7 @@ import numpy as np
 import pytest
 
 from tensorflowdistributedlearning_tpu.ops.quant_kernels import (
+    _QMM_ROW_TILE,
     int8_conv2d,
     int8_conv2d_reference,
     int8_intercept,
@@ -122,9 +123,12 @@ def test_matmul_n_tiling_matches_untiled():
     x = jnp.asarray(rng.normal(0, 1, (8, 32)), jnp.float32)
     wq, ws = quantize_weight(jnp.asarray(rng.normal(0, 0.5, (32, 64))))
     full = int8_matmul(x, wq, ws, interpret=True)
-    # budget fits ~a quarter of N: fixed 8*32 + nt*(32+8*4+8)
+    # budget fits ~a quarter of N: fixed tm*32 + nt*(32+tm*4+8), with tm the
+    # kernel's row block (independent of the 8 rows served)
+    tm = _QMM_ROW_TILE
     tiled = int8_matmul(
-        x, wq, ws, interpret=True, vmem_limit_bytes=8 * 32 + 16 * 72 + 1
+        x, wq, ws, interpret=True,
+        vmem_limit_bytes=tm * 32 + 16 * (32 + tm * 4 + 8) + 1,
     )
     np.testing.assert_array_equal(np.asarray(full), np.asarray(tiled))
 

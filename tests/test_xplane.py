@@ -124,18 +124,17 @@ def test_grouped_breakdown_buckets():
 def test_grouped_breakdown_tags_quant_and_fused_kernels():
     """The Pallas quant/fused kernels show up in device traces under their
     kernel function names; the roofline classifier must fold the int8
-    matmul/conv into the MXU compute buckets and the fused heads into the
+    matmul/conv into the MXU compute buckets and the fused epilogue into the
     elementwise-fusion bucket, not ``other``."""
     rows = [
         xplane.OpTime("_qmm_kernel.4", 6.0, 2, 0.6),
         xplane.OpTime("_qconv_kernel.2", 3.0, 1, 0.3),
-        xplane.OpTime("_sigmoid_mask_kernel.1", 1.0, 1, 0.1),
         xplane.OpTime("_fused_bias_act_kernel.3", 0.5, 1, 0.05),
     ]
     groups = xplane.grouped_breakdown(rows)
     assert groups["matmul"] == 6.0
     assert groups["conv"] == 3.0
-    assert groups["fusion(elementwise/bn)"] == 1.5
+    assert groups["fusion(elementwise/bn)"] == 0.5
     assert "other" not in groups
     assert xplane.classify_bucket("_qmm_kernel.4") == "matmul"
     assert xplane.classify_bucket("_qconv_kernel.2") == "conv"

@@ -311,6 +311,9 @@ def run_bench(args) -> Dict:
         b = params_digest(golden_dir)
         record = {
             "bench": "elastic",
+            # every child runs with JAX_PLATFORMS=cpu (_env)
+            "platform": "cpu",
+            "note": "CPU drill: counts and correctness checks; its timings are not device numbers",
             "preset": PRESET,
             "hosts": 2,
             "devices_per_host": args.devices_per_host,

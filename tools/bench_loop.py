@@ -399,6 +399,9 @@ def main() -> int:
     args = parser.parse_args()
 
     result = run_drill(args)
+    # the fleet and the retrain it drives run with JAX_PLATFORMS=cpu
+    result["platform"] = "cpu"
+    result["note"] = "CPU drill: counts and correctness checks; its timings are not device numbers"
     print(json.dumps(result, indent=1))
     if args.json_out:
         with open(args.json_out, "w", encoding="utf-8") as f:

@@ -1567,7 +1567,16 @@ def main() -> int:
         standalone_detector = RecompileDetector().attach()
     detector = telemetry.detector or standalone_detector
 
+    import jax
+
     record: dict = {
+        # a CPU drill unless JAX_PLATFORMS says otherwise (setdefault above):
+        # this parent exports with jax and then spawns fleets, which on a
+        # chip would take it from its own replicas — ROADMAP S4 replaces it
+        "platform": jax.default_backend(),
+        "note": "a 128->256->16 MLP under closed-loop clients: counts, "
+        "ratios and correctness checks; on the CPU backend its timings are "
+        "not device numbers",
         "model": {"features": FEATURES, "hidden": HIDDEN, "classes": CLASSES},
         "concurrency": args.concurrency,
         "duration_s": args.duration,
@@ -1710,7 +1719,7 @@ def main() -> int:
             kernels = bench_kernels_mod.bench_quant()
         else:
             kernels = bench_kernels_mod.bench_quant(
-                batch=16, features=128, hw=7, conv_channels=16, mask_hw=33,
+                batch=16, features=128, hw=7, conv_channels=16,
                 iters=4, warmup=2, repeats=4,
             )
         kernels["platform"] = jax.default_backend()
