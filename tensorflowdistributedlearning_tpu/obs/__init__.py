@@ -89,9 +89,13 @@ from tensorflowdistributedlearning_tpu.obs.telemetry import (
     SPAN_BARRIER,
     SPAN_CHECKPOINT,
     SPAN_DATA_WAIT,
+    SPAN_DISPATCH_PREPARE,
+    SPAN_DISPATCH_STEP,
     SPAN_EVAL,
     SPAN_FETCH_WAIT,
+    SPAN_IMAGE_SUMMARY,
     SPAN_STEP,
+    SPAN_WINDOW_EMIT,
     Telemetry,
 )
 from tensorflowdistributedlearning_tpu.obs.trace import (
@@ -110,9 +114,13 @@ __all__ = [
     "SPAN_BARRIER",
     "SPAN_CHECKPOINT",
     "SPAN_DATA_WAIT",
+    "SPAN_DISPATCH_PREPARE",
+    "SPAN_DISPATCH_STEP",
     "SPAN_EVAL",
     "SPAN_FETCH_WAIT",
+    "SPAN_IMAGE_SUMMARY",
     "SPAN_STEP",
+    "SPAN_WINDOW_EMIT",
     "STRAGGLER_ALERT_EVENT",
     "TRACE_EVENT",
     "WATERMARK_EVENT",

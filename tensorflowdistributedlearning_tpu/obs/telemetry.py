@@ -20,16 +20,31 @@ so per-WINDOW totals are real wall time even though individual step samples
 measure dispatch+backpressure. The window event carries both the split and
 the per-step percentiles.
 
+One host timeline: every clock reading this module persists
+(``startup_phase.t0_mono``, ``step_window.step_start_mono`` /
+``step_done_mono``) is ``time.perf_counter()`` of the
+writing process — the clock the spans are timed on, and, through the
+``TraceAnnotation`` each span opens, the one a profiler capture shows them on.
+From the entry of ``Trainer.train`` / ``ClassifierTrainer.fit`` to the end of
+a fold every host second lies in a named span: ``startup/<phase>`` spans
+before the loop (each persisted as a ``startup_phase`` event with the
+compiles that fell into it), window spans inside it, and ``host_other_s`` —
+the window's wall less every named span — says what is still unnamed.
+
 ``NULL_TELEMETRY`` is the disabled instance (no workdir, no ledger, no
 detector, spans are near-free) so trainer code never branches on None.
 """
 
 from __future__ import annotations
 
+import collections
 import contextlib
 import logging
+import os
 import time
-from typing import Dict, List, Optional
+from typing import Deque, Dict, List, NamedTuple, Optional, Tuple
+
+import numpy as np
 
 from tensorflowdistributedlearning_tpu.obs import capacity as capacity_lib
 from tensorflowdistributedlearning_tpu.obs import trace as trace_lib
@@ -55,15 +70,59 @@ SPAN_EVAL = "eval"
 # dispatch-ahead and deferred window fetch — train/async_loop.py); disjoint
 # from data_wait/step like the other window spans
 SPAN_FETCH_WAIT = "fetch_wait"
-# checkpoint save wall time (the trainers wrap periodic/forced saves) — not a
-# window span (nothing drains it; the histogram ring bounds it), but a trace
-# boundary: sampled runs show checkpoint spans in the exported timeline
+# checkpoint save wall time (the trainers wrap periodic/forced saves): drained
+# with the window it fell into (`checkpoint_s`), and a trace boundary — sampled
+# runs show checkpoint spans in the exported timeline
 SPAN_CHECKPOINT = "checkpoint"
 # host blocked at a cross-process sync point (parallel/multihost.py wraps its
 # multihost_utils calls in `barrier_probe`): on a healthy fleet this is ~0 on
 # the slowest host and largest on the fastest, so per-host barrier_wait is the
 # signal that separates "slow host" from "slow network" in the fleet report
 SPAN_BARRIER = "barrier_wait"
+# the two calls inside the `step` span, each timed on its own: dispatching the
+# input program (`prepare`) and the train step. Children of `step` — their
+# seconds are part of `compute_s`, and what is left of it is the span's self
+# time. A per-call median tells a call that blocks every step from a mean
+# made by two slow calls. A compile inside one is the loop span's
+# (Telemetry.compile_phase): `step` is what the trainers mark warm
+SPAN_DISPATCH_PREPARE = "dispatch_prepare"
+SPAN_DISPATCH_STEP = "dispatch_step"
+# the deferred window's write-out (the reduction of the fetched metrics to
+# scalars, TB scalars, the window event, health and profiler hooks —
+# train/async_loop.py wraps the trainers' emit) and the
+# train-phase image grids (an extra forward and three device_gets)
+SPAN_WINDOW_EMIT = "window_emit"
+SPAN_IMAGE_SUMMARY = "image_summary"
+# spans named `startup/<phase>` are the start-up timeline: closing one
+# persists a `startup_phase` event (Telemetry.span)
+STARTUP_PREFIX = "startup/"
+# the phase that straddles loop iterations: from the first `next(batches)` to
+# the moment the dispatch tracker retires step 1 (Telemetry.begin_first_step)
+PHASE_FIRST_STEP = "first_step"
+
+# the spans a log window drains, with the step_window field each total is
+# written under. In the trainers' single-process loops they are top-level and
+# disjoint, so their seconds and `host_other_s` add up to the window's
+# `wall_s`; where one nests in another (`barrier_wait` inside `eval` on a
+# multi-host run) its field still reads its own seconds, and `host_other_s`
+# counts every second once: it is taken from the spans that closed at the
+# top of the stack (Telemetry.span)
+_WINDOW_SPAN_FIELDS = (
+    (SPAN_DATA_WAIT, "data_wait_s"),
+    (SPAN_STEP, "compute_s"),
+    (SPAN_FETCH_WAIT, "fetch_wait_s"),
+    (SPAN_BARRIER, "barrier_wait_s"),
+    (SPAN_WINDOW_EMIT, "window_emit_s"),
+    (SPAN_IMAGE_SUMMARY, "image_summary_s"),
+    (SPAN_CHECKPOINT, "checkpoint_s"),
+    (SPAN_EVAL, "eval_s"),
+)
+# the children of `step`, drained with the window but not added to its sum
+_DISPATCH_SPANS = (SPAN_DISPATCH_PREPARE, SPAN_DISPATCH_STEP)
+
+# per-step clock readings kept between two window boundaries; a run that
+# never writes windows (a non-main host) must not grow without bound
+_MAX_STEP_MARKS = 8192
 
 # registry histogram the input prefetcher records its ready-queue depth into
 # (data/pipeline.py:device_prefetch); drained per window like the spans, so
@@ -98,6 +157,15 @@ def run_fingerprint() -> Dict:
     }
 
 
+class _StartupMark(NamedTuple):
+    """An open start-up phase: its name, the clock at entry and how many
+    compiles the detector had heard by then."""
+
+    phase: str
+    t0: float
+    compiles: int
+
+
 class Telemetry:
     """Per-run telemetry: span timing, JSONL ledger, recompile detection."""
 
@@ -115,8 +183,16 @@ class Telemetry:
         process_count: Optional[int] = None,
         capacity_sampling: bool = True,
         controller: bool = False,
+        hold_header: bool = False,
     ):
-        """``controller=True`` is for a process that SPAWNS the chip users
+        """``hold_header=True`` is for a producer that builds its telemetry
+        before it knows all of its run header (the trainers build it first
+        thing, so that spans and the compile listener cover the whole start;
+        the parallelism plan comes later): the header and every event after
+        it are held in memory, each with the time it was made, until
+        :meth:`finish_header` — or :meth:`close` — writes them out in order.
+
+        ``controller=True`` is for a process that SPAWNS the chip users
         (the serve-fleet controller, the flywheel): a chip belongs to one
         process, and a parent that initializes a jax backend takes it from
         its children. Such a telemetry asks jax nothing — its run header
@@ -143,6 +219,24 @@ class Telemetry:
         self.cost = capacity_lib.CostMeter()
         self.registry = MetricsRegistry()
         self._span_stack: List[str] = []
+        # the K-fold trainer sets the fold it is in; start-up phases carry it
+        self.fold: Optional[int] = None
+        # the one host timeline (module docstring): the previous window
+        # boundary, per-step clock readings since it, and the start-up phase
+        # a retired step will close
+        self._window_t0 = time.perf_counter()
+        # seconds of the window that lay in a span of any name, each counted
+        # once (spans closed at the top of the stack)
+        self._named_s = 0.0
+        self._step_starts: Deque[float] = collections.deque(
+            maxlen=_MAX_STEP_MARKS
+        )
+        self._steps_done: Deque[Tuple[int, float]] = collections.deque(
+            maxlen=_MAX_STEP_MARKS
+        )
+        self._first_step: Optional[_StartupMark] = None
+        # (kind, fields) held back until finish_header(); None = write through
+        self._held: Optional[List[Tuple[str, Dict]]] = None
         self._windows = 0
         self._memory_every_windows = max(1, memory_every_windows)
         self._closed = False
@@ -186,8 +280,6 @@ class Telemetry:
         # explicitly-identified serve replica); process 0 keeps the legacy
         # is_main gate
         if is_main or process_index > 0:
-            import os
-
             # fleet ledger contract (obs/fleet.py): under multi-host EVERY
             # process writes its own ledger — process 0 the canonical
             # telemetry.jsonl, process i>0 telemetry-{i}.jsonl — so the merge
@@ -217,11 +309,19 @@ class Telemetry:
                 header["controller"] = True
             else:
                 header["fingerprint"] = run_fingerprint()
+            age = _process_age_s()
+            if age is not None:
+                # how long the process had lived before any training code
+                # ran: interpreter start, imports, device init, whatever the
+                # caller did before it built its trainer
+                header["process_age_s"] = round(age, 3)
             if run_info:
                 header.update(run_info)
-            self.ledger.event("run_header", **header)
+            if hold_header:
+                self._held = []
+            self._event("run_header", **header)
         self.detector = RecompileDetector(
-            phase_fn=lambda: self.current_span,
+            phase_fn=lambda: self.compile_phase,
             on_event=self._on_compile,
         ).attach()
 
@@ -231,16 +331,37 @@ class Telemetry:
     def current_span(self) -> str:
         return self._span_stack[-1] if self._span_stack else ""
 
+    @property
+    def compile_phase(self) -> str:
+        """The span a compile that ends now is attributed to: the innermost
+        open one that is not a child of ``step``. The trainers mark the loop
+        spans warm (``mark_warm(SPAN_STEP, SPAN_DATA_WAIT)``), so a recompile
+        of the train step or the input program inside ``dispatch_step`` /
+        ``dispatch_prepare`` has to read ``step`` to count as post-warm-up."""
+        for name in reversed(self._span_stack):
+            if name not in _DISPATCH_SPANS:
+                return name
+        return ""
+
     @contextlib.contextmanager
     def span(self, name: str):
         """Time a named host-side phase; nested spans attribute to the
         innermost name. Also opens a profiler TraceAnnotation so captured
-        xplane traces carry the same phase names the ledger uses."""
+        xplane traces carry the same phase names the ledger uses. A span
+        named ``startup/<phase>`` is a start-up phase: closing it persists a
+        ``startup_phase`` event."""
         if not self.enabled:
             yield
             return
         self._span_stack.append(name)
-        t0 = time.perf_counter()
+        startup = (
+            self._startup_mark(name[len(STARTUP_PREFIX):])
+            if name.startswith(STARTUP_PREFIX)
+            else None
+        )
+        t0 = time.perf_counter() if startup is None else startup.t0
+        if name == SPAN_STEP:
+            self._step_starts.append(t0)
         try:
             import jax
 
@@ -253,9 +374,14 @@ class Telemetry:
                 else:
                     yield
         finally:
-            dt = time.perf_counter() - t0
+            t1 = time.perf_counter()
+            dt = t1 - t0
             self.registry.histogram(f"span/{name}").record(dt)
             self._span_stack.pop()
+            if not self._span_stack:
+                self._named_s += dt
+            if startup is not None:
+                self._startup_event(startup, t1)
             prof = self.profiler
             if prof is not None and prof.capturing and name == SPAN_STEP:
                 # an active windowed capture counts train steps (and their
@@ -266,29 +392,96 @@ class Telemetry:
                 except Exception:  # noqa: BLE001 — profiling never kills training
                     logger.warning("profiler note_step failed", exc_info=True)
 
+    # -- the start-up timeline ---------------------------------------------
+
+    def _startup_mark(self, phase: str) -> _StartupMark:
+        det = self.detector
+        return _StartupMark(
+            phase, time.perf_counter(), det.compile_count if det else 0
+        )
+
+    def _startup_event(self, mark: _StartupMark, t1: float) -> None:
+        """One ``startup_phase`` event: where the phase lies on the host
+        timeline and the compiles that ended inside it — by time, not by
+        attribution, so a nested span does not take them."""
+        compiles = self.detector.events[mark.compiles:] if self.detector else []
+        self._event(
+            "startup_phase",
+            name=mark.phase,
+            parent="startup",
+            fold=self.fold,
+            t0_mono=round(mark.t0, 6),
+            duration_s=round(t1 - mark.t0, 6),
+            programs=len(compiles),
+            cache_hits=sum(1 for e in compiles if e.cache_hit),
+            cache_misses=sum(1 for e in compiles if e.cache_hit is False),
+            compile_s=round(sum(e.duration_s for e in compiles), 6),
+        )
+
+    def begin_first_step(self) -> None:
+        """Open the ``first_step`` start-up phase — call right before the
+        loop's first ``next(batches)``. It straddles loop iterations, so it
+        is no ``with`` block: the first retired step (:meth:`step_done`)
+        closes it, with no synchronisation of its own. A loop that retires
+        nothing (``dispatch_ahead_steps=0``, or fewer steps than the budget)
+        closes it at its first window boundary, or when the run ends."""
+        if not self.enabled:
+            return
+        self._end_first_step(time.perf_counter())
+        self._first_step = self._startup_mark(PHASE_FIRST_STEP)
+
+    def _end_first_step(self, t1: float) -> None:
+        mark, self._first_step = self._first_step, None
+        if mark is not None:
+            self._startup_event(mark, t1)
+
+    def step_done(self, step: int) -> None:
+        """The dispatch tracker retired train step ``step`` (its
+        ``block_until_ready`` just returned, blocked or not): read the clock.
+        This is the HOST's observation of completion — an upper bound on
+        when the device finished the step, tight while the host sits blocked
+        in ``fetch_wait`` and loose by however long the host was busy
+        elsewhere (an eval pass, a checkpoint) before it looked."""
+        if not self.enabled:
+            return
+        now = time.perf_counter()
+        self._steps_done.append((int(step), now))
+        if self._first_step is not None:
+            self._end_first_step(now)
+
     def _span_delta(self, name: str) -> List[float]:
         """Span samples recorded since the last window boundary. Draining
         (not marking) keeps per-step span histograms bounded by one window —
         a 500k-step run would otherwise retain ~1M floats nothing reads."""
         return self.registry.histogram(f"span/{name}").drain()
 
-    def drain_window_samples(self) -> Dict[str, List[float]]:
-        """Drain the per-window samples NOW and hand them to the caller.
+    def drain_window_samples(self) -> Dict[str, list]:
+        """Drain the per-window samples NOW and hand them to the caller:
+        this call IS the window boundary — ``wall_s`` runs from the previous
+        call to this one.
 
         Deferred-emission callers (the async host loop) snapshot at the
         window BOUNDARY and pass the result back through
         ``window_event(samples=...)`` one window later, so a late-written
         window event still describes its own interval instead of the next
-        one's."""
-        samples = {
+        one's. A loop drains once before its first step, so that its first
+        window starts there and not with what ran before it."""
+        if not self.enabled:
+            return {}
+        now = time.perf_counter()
+        self._end_first_step(now)
+        samples: Dict[str, list] = {
             name: self._span_delta(name)
-            for name in (
-                SPAN_DATA_WAIT,
-                SPAN_STEP,
-                SPAN_FETCH_WAIT,
-                SPAN_BARRIER,
-            )
+            for name in (*(n for n, _ in _WINDOW_SPAN_FIELDS), *_DISPATCH_SPANS)
         }
+        samples["wall_s"] = [now - self._window_t0]
+        self._window_t0 = now
+        samples["named_s"] = [self._named_s]
+        self._named_s = 0.0
+        samples["step_starts"] = list(self._step_starts)
+        self._step_starts.clear()
+        samples["steps_done"] = list(self._steps_done)  # (step, clock) pairs
+        self._steps_done.clear()
         samples["prefetch_depth"] = self.registry.histogram(
             PREFETCH_DEPTH_HISTOGRAM
         ).drain()
@@ -323,9 +516,12 @@ class Telemetry:
         """Price this run's steps analytically so measured time becomes MFU.
 
         ``flops_per_step`` is the planner's dense-proxy model
-        (``6 * param_count * global_batch``) for ONE optimizer step across
-        the whole job; ``peak_flops_per_chip`` defaults to the device peak
-        table (``obs.profiler.resolve_peak_flops``) and stays ``None`` on
+        (``planner.dense_proxy_flops``: ``6 * param_count * global_batch``)
+        for ONE optimizer step across the whole job, which the trainers apply
+        only where it holds — transformer backbones; a convolutional run is
+        not priced and its windows omit ``mfu``. ``peak_flops_per_chip``
+        defaults to the device peak table
+        (``obs.profiler.resolve_peak_flops``) and stays ``None`` on
         unknown kinds — every ``step_window`` then simply omits ``mfu``
         (never a fabricated 0/0). ``collective_bytes_per_step`` is the
         planner's priced per-chip collective volume, which lets rooflines
@@ -356,27 +552,46 @@ class Telemetry:
                 collective_bytes_per_step
             )
 
-    def _window_mfu(self, mean_step_s: float) -> Optional[float]:
-        """Model FLOPs utilization for a window with the given mean step
-        time; None unless both the analytic pricing and a real device peak
+    def _window_mfu(self, step_s: float) -> Optional[float]:
+        """Model FLOPs utilization for a window with the given wall time per
+        step; None unless both the analytic pricing and a real device peak
         are known."""
         sf = self.step_flops
-        if not sf or not mean_step_s or mean_step_s <= 0:
+        if not sf or not step_s or step_s <= 0:
             return None
         peak = sf.get("peak_flops_per_chip")
         if not peak:
             return None
-        achieved = sf["flops_per_step"] / mean_step_s / sf["n_devices"]
+        achieved = sf["flops_per_step"] / step_s / sf["n_devices"]
         return round(achieved / peak, 4)
 
     # -- events ------------------------------------------------------------
 
     def _event(self, kind: str, /, **fields) -> None:
-        if self.ledger is not None:
+        if self.ledger is None:
+            return
+        if self._held is not None:
+            # the header is still open (finish_header): keep the event, with
+            # the time it was made, behind it
+            self._held.append((kind, {"t": time.time(), **fields}))
+            return
+        self.ledger.event(kind, **fields)
+
+    def finish_header(self, **run_info) -> None:
+        """Add what the run header still lacked (``hold_header=True``) and
+        write it out, followed by every event held behind it, each stamped
+        with the time it was made. A no-op when nothing is held."""
+        held, self._held = self._held, None
+        if not held:
+            return
+        held[0][1].update(run_info)  # the run_header itself
+        for kind, fields in held:
             self.ledger.event(kind, **fields)
 
     def _trace_event(self, fields: Dict) -> None:
-        if self.ledger is not None:
+        if self._held is not None:
+            self._event(trace_lib.TRACE_EVENT, **fields)
+        elif self.ledger is not None:
             self.ledger.event_buffered(trace_lib.TRACE_EVENT, **fields)
 
     def flush(self) -> None:
@@ -400,7 +615,7 @@ class Telemetry:
         images_per_sec: Optional[float] = None,
         scalars: Optional[Dict[str, float]] = None,
         dirty: bool = False,
-        samples: Optional[Dict[str, List[float]]] = None,
+        samples: Optional[Dict[str, list]] = None,
         examples: Optional[int] = None,
         **extra,
     ) -> None:
@@ -415,31 +630,61 @@ class Telemetry:
             return
         if samples is None:
             samples = self.drain_window_samples()
-        wait = samples.get(SPAN_DATA_WAIT, [])
         compute = samples.get(SPAN_STEP, [])
-        fetch = samples.get(SPAN_FETCH_WAIT, [])
-        barrier = samples.get(SPAN_BARRIER, [])
         depth = samples.get("prefetch_depth", [])
         # exact totals even when a histogram ring capped the raw samples
         # (obs/metrics.py:SampleWindow)
-        wait_s, compute_s, fetch_s, barrier_s = (
-            window_total_s(wait),
-            window_total_s(compute),
-            window_total_s(fetch),
-            window_total_s(barrier),
+        totals = {
+            field: window_total_s(samples.get(name))
+            for name, field in _WINDOW_SPAN_FIELDS
+        }
+        wait_s, compute_s = totals["data_wait_s"], totals["compute_s"]
+        busy = (
+            wait_s + compute_s + totals["fetch_wait_s"] + totals["barrier_wait_s"]
         )
-        busy = wait_s + compute_s + fetch_s + barrier_s
         fields: Dict = {
             "step": step,
             "steps": steps,
-            "data_wait_s": round(wait_s, 6),
-            "compute_s": round(compute_s, 6),
-            "fetch_wait_s": round(fetch_s, 6),
-            "barrier_wait_s": round(barrier_s, 6),
+            **{field: round(total, 6) for field, total in totals.items()},
             "data_wait_frac": round(wait_s / busy, 4) if busy else 0.0,
             "dirty": dirty,
             **extra,
         }
+        wall_s = window_total_s(samples.get("wall_s"))
+        if wall_s:
+            # boundary to boundary, dirty windows too; what no span names is
+            # what is left of it
+            fields["wall_s"] = round(wall_s, 6)
+            fields["host_other_s"] = round(
+                wall_s - window_total_s(samples.get("named_s")), 6
+            )
+        for name in _DISPATCH_SPANS:
+            calls = samples.get(name)
+            if calls:
+                fields[f"{name}_s"] = round(window_total_s(calls), 6)
+                fields[f"{name}_ms"] = {
+                    "p50": round(float(np.median(calls)) * 1e3, 3),
+                    "max": round(max(calls) * 1e3, 3),
+                }
+        # the `step` span's entry per step; a synchronous loop's window fetch
+        # runs under the same span after the last step and is not a step
+        starts = samples.get("step_starts", [])[:steps]
+        if starts:
+            fields["step_start_mono"] = [round(t, 6) for t in starts]
+        done = samples.get("steps_done")
+        if done:
+            # steps retire in order: entry i is step `step_done_first + i`
+            # (step_done() says what a completion time means)
+            times = [t for _, t in done]
+            fields["step_done_first"] = done[0][0]
+            fields["step_done_mono"] = [round(t, 6) for t in times]
+            if len(times) > 1:
+                gaps = np.diff(times) * 1e3
+                fields["step_interval_ms"] = {
+                    "p50": round(float(np.median(gaps)), 3),
+                    "max": round(float(gaps.max()), 3),
+                    "n": len(gaps),
+                }
         if depth:
             # ready batches behind each consumer take: mean tells how full
             # the input prefetch queue ran, min 0 marks an underrun window
@@ -473,12 +718,13 @@ class Telemetry:
                 for k, v in s.items()
                 if k.endswith("_s") and k != "total_s"
             }
-            # first-class MFU: analytic step FLOPs (set_step_flops) against
-            # this window's mean measured step time; absent without a known
-            # device peak (CPU) — never 0/0
-            mfu = self._window_mfu(s.get("mean_s") or 0.0)
-            if mfu is not None:
-                fields["mfu"] = mfu
+        # first-class MFU: analytic step FLOPs (set_step_flops) against the
+        # window's wall per step — the `step` span is dispatch plus
+        # backpressure, no measure of a step; absent without pricing or a
+        # known device peak (CPU) — never 0/0
+        mfu = self._window_mfu(wall_s / steps if steps else 0.0)
+        if mfu is not None:
+            fields["mfu"] = mfu
         if images_per_sec is not None:
             fields["images_per_sec"] = round(float(images_per_sec), 2)
         if scalars:
@@ -668,6 +914,10 @@ class Telemetry:
         self._closed = True
         if not self.enabled:
             return
+        # a run that ended before its header was finished, or inside its
+        # first step, still tells how far it got
+        self.finish_header()
+        self._end_first_step(time.perf_counter())
         if self.profiler is not None:
             # finish any capture in flight BEFORE run_end/close so its
             # events land inside this run's ledger
@@ -699,10 +949,23 @@ class Telemetry:
             self.ledger.close()
 
 
+def _process_age_s() -> Optional[float]:
+    """Seconds since this process started, from ``/proc/self/stat`` (field
+    22, ``starttime``, in clock ticks since boot) against ``/proc/uptime``;
+    None where there is no procfs."""
+    try:
+        with open("/proc/self/stat") as f:
+            # the command name (field 2) may hold spaces: count from its ")"
+            start_ticks = int(f.read().rsplit(")", 1)[1].split()[19])
+        with open("/proc/uptime") as f:
+            uptime_s = float(f.read().split()[0])
+        return uptime_s - start_ticks / os.sysconf("SC_CLK_TCK")
+    except (OSError, ValueError, IndexError):
+        return None
+
+
 def _host_rss_bytes() -> Optional[int]:
     try:
-        import os
-
         page = os.sysconf("SC_PAGE_SIZE")  # 64KiB-page kernels exist
         with open("/proc/self/statm") as f:
             return int(f.read().split()[1]) * page

@@ -633,6 +633,22 @@ class MeasuredCosts:
         return out
 
 
+def dense_proxy_flops(
+    model_config, param_count: float, global_batch: int
+) -> Optional[float]:
+    """FLOPs of one optimizer step under the dense proxy ``6 * params *
+    examples`` (forward 2x, backward 4x) — or None where the proxy does not
+    hold. It holds for transformer backbones, whose FLOPs sit in their
+    matmuls; a convolution reuses each weight at every output position, so
+    for a convolutional backbone it reads low by orders of magnitude (64
+    GFLOP for a step whose convolutions are 11.8 TFLOP). ``_cost`` still
+    ranks the layouts of ONE model with it, where only the ratio counts; a
+    trainer prices a run's MFU with it only where this returns a number."""
+    if getattr(model_config, "backbone", None) != "vit":
+        return None
+    return 6.0 * float(param_count) * float(global_batch)
+
+
 def _cost(
     profile: ModelProfile,
     layout: Layout,

@@ -47,7 +47,7 @@ from __future__ import annotations
 import dataclasses
 import functools
 from collections import deque
-from typing import Any, Callable, Dict, List, Optional
+from typing import Any, Callable, Dict, Optional
 
 import jax
 import jax.numpy as jnp
@@ -69,7 +69,12 @@ class DispatchBudget:
     nothing) so the host never runs unboundedly ahead of the device.
     ``block_until_ready`` waits for completion without transferring —
     tracking adds no host copies. ``budget <= 0`` disables tracking entirely
-    (the caller owns its own sync points)."""
+    (the caller owns its own sync points).
+
+    A caller that numbers its steps (``track(tree, step)``) gets a completion
+    time for each: the moment the wait on a retired step returns — blocked
+    or not — is handed to ``Telemetry.step_done``, which says what such a
+    time does and does not mean. No synchronisation is added for it."""
 
     def __init__(
         self,
@@ -86,20 +91,22 @@ class DispatchBudget:
     def budget(self) -> int:
         return self._budget
 
-    def track(self, tree: Any) -> None:
+    def track(self, tree: Any, step: Optional[int] = None) -> None:
         if self._budget <= 0:
             return
         leaf = next(iter(jax.tree.leaves(tree)), None)
         if leaf is None:
             return
-        self._inflight.append(leaf)
+        self._inflight.append((step, leaf))
         if len(self._inflight) > self._budget:
-            oldest = self._inflight.popleft()
+            retired, oldest = self._inflight.popleft()
             if self._span is None:
                 jax.block_until_ready(oldest)
             else:
                 with self._tel.span(self._span):
                     jax.block_until_ready(oldest)
+            if retired is not None:
+                self._tel.step_done(retired)
 
 
 def eval_budget(telemetry, dispatch_ahead: int) -> DispatchBudget:
@@ -132,7 +139,7 @@ class PendingWindow:
     images_per_sec: Optional[float] = None
     dirty: bool = False
     extra: Dict[str, Any] = dataclasses.field(default_factory=dict)
-    samples: Optional[Dict[str, List[float]]] = None
+    samples: Optional[Dict[str, list]] = None
 
 
 class HostOverlap:
@@ -155,18 +162,25 @@ class HostOverlap:
         self._emit = emit
         self._tracker = DispatchBudget(telemetry, max(0, int(dispatch_ahead)))
         self._pending: Optional[PendingWindow] = None
+        # the loop starts here: its first window begins now, and holds no
+        # sample of what ran before it (a previous fold's last steps, the
+        # start-up's spans)
+        telemetry.drain_window_samples()
 
     @property
     def async_mode(self) -> bool:
         return self._tracker.budget > 0
 
-    def track(self, metrics: Any) -> None:
+    def track(self, metrics: Any, step: Optional[int] = None) -> None:
         """Bounded dispatch-ahead: call once per dispatched train step with its
-        metric output. Past the budget, blocks on the OLDEST in-flight step
-        (recorded as ``fetch_wait``) so the host never runs unboundedly ahead
-        of the device. Sync mode (budget 0) is a no-op — the legacy loop's
-        only sync point is the window ``device_get``."""
-        self._tracker.track(metrics)
+        metric output and its number. Past the budget, blocks on the OLDEST
+        in-flight step (recorded as ``fetch_wait``) so the host never runs
+        unboundedly ahead of the device, and reads the clock when that wait
+        returns: the retired step's completion time
+        (``step_window.step_done_mono``). Sync mode (budget 0) is a no-op —
+        the legacy loop's only sync point is the window ``device_get`` — and
+        has no completion times."""
+        self._tracker.track(metrics, step)
 
     def window(self, record: PendingWindow) -> None:
         """Log-window boundary. Sync mode fetches and emits in place (the
@@ -177,7 +191,7 @@ class HostOverlap:
         if not self.async_mode:
             with self._tel.span(obs_lib.SPAN_STEP):
                 host = jax.device_get(record.metrics)
-            self._emit(record, self._scalars(record, host))
+            self._emit_window(record, host)
             return
         self.flush()
         record.samples = self._tel.drain_window_samples()
@@ -196,7 +210,16 @@ class HostOverlap:
             return
         with self._tel.span(obs_lib.SPAN_FETCH_WAIT):
             host = jax.device_get(record.metrics)
-        self._emit(record, self._scalars(record, host))
+        self._emit_window(record, host)
+
+    def _emit_window(self, record: PendingWindow, host_metrics: Any) -> None:
+        # the write-out is host time of the window it falls INTO (the one
+        # after the window it describes, in async mode): the boundary
+        # snapshot books it there, like every other span. The reduction to
+        # scalars is inside: `compute_metrics` divides with jnp, so it waits
+        # on the device behind the steps in flight (PERF.md, PR 24)
+        with self._tel.span(obs_lib.SPAN_WINDOW_EMIT):
+            self._emit(record, self._scalars(record, host_metrics))
 
     @staticmethod
     def _scalars(record: PendingWindow, host_metrics: Any) -> Dict[str, float]:

@@ -451,31 +451,11 @@ class ClassifierTrainer:
         eval_every = (
             eval_every_steps or tcfg.eval_every_steps or tcfg.checkpoint_every_steps
         )
-        # fail fast on data-layout problems EVERY split will hit, before any
-        # training happens (e.g. fewer val record shards than processes would
-        # otherwise only surface at the first eval, potentially hours in)
-        self._open_records("val")
-
-        if self._plan is None and tcfg.telemetry:
-            # direct-construction path (no fit_preset): describe the explicit
-            # layout through the planner so the run header carries the plan
-            # (predicted bytes/chip) like every other run. Best-effort — the
-            # mesh already validated divisibility in __init__, so a planner
-            # hiccup here is telemetry loss, not a training error. Skipped
-            # when telemetry is off: the plan's only consumer here is the
-            # run header.
-            try:
-                from tensorflowdistributedlearning_tpu.parallel import (
-                    planner as planner_lib,
-                )
-
-                self._plan = planner_lib.validate_config(
-                    self.model_config, tcfg, batch_size
-                ).header()
-            except Exception as e:  # noqa: BLE001 — plan is telemetry here
-                logger.warning("parallelism plan unavailable: %s", e)
-
-        self._telemetry = obs_lib.Telemetry(
+        # built before anything else, so that the start-up phases are spans
+        # and the compile listener hears the whole start (the K-fold
+        # trainer's names, for the phases this one has); the header waits
+        # for the plan (finish_header)
+        tel = self._telemetry = obs_lib.Telemetry(
             self.model_dir,
             enabled=tcfg.telemetry,
             memory_every_windows=tcfg.telemetry_memory_every_windows,
@@ -483,6 +463,7 @@ class ClassifierTrainer:
             # online health monitors (obs/health.py) ride the window stream
             trace_sample_rate=tcfg.trace_sample_rate,
             health=obs_lib.HealthMonitor.from_train_config(tcfg),
+            hold_header=True,
             run_info={
                 "task": "classification",
                 "steps": steps,
@@ -495,17 +476,43 @@ class ClassifierTrainer:
                 },
                 "model_config": dataclasses.asdict(self.model_config),
                 "train_config": dataclasses.asdict(tcfg),
-                # the parallelism plan (chosen layout + predicted bytes/chip):
-                # telemetry-report renders it, obs/compare hashes its layout,
-                # and the watermark events' measured-vs-predicted deltas are
-                # judged against its prediction
-                **({"plan": self._plan} if self._plan else {}),
             },
         )
         # time cross-process sync points as this run's barrier_wait span —
         # per-host barrier asymmetry is the fleet report's straggler signal
         multihost.instrument(self._telemetry)
         try:
+            with tel.span("startup/load_dataset"):
+                # fail fast on data-layout problems EVERY split will hit,
+                # before any training happens (e.g. fewer val record shards
+                # than processes would otherwise only surface at the first
+                # eval, potentially hours in)
+                self._open_records("val")
+            with tel.span("startup/plan"):
+                if self._plan is None and tcfg.telemetry:
+                    # direct-construction path (no fit_preset): describe the
+                    # explicit layout through the planner so the run header
+                    # carries the plan (predicted bytes/chip) like every
+                    # other run. Best-effort — the mesh already validated
+                    # divisibility in __init__, so a planner hiccup here is
+                    # telemetry loss, not a training error. Skipped when
+                    # telemetry is off: the plan's only consumer here is the
+                    # run header.
+                    try:
+                        from tensorflowdistributedlearning_tpu.parallel import (
+                            planner as planner_lib,
+                        )
+
+                        self._plan = planner_lib.validate_config(
+                            self.model_config, tcfg, batch_size
+                        ).header()
+                    except Exception as e:  # noqa: BLE001 — plan is telemetry here
+                        logger.warning("parallelism plan unavailable: %s", e)
+            # the parallelism plan (chosen layout + predicted bytes/chip):
+            # telemetry-report renders it, obs/compare hashes its layout, and
+            # the watermark events' measured-vs-predicted deltas are judged
+            # against its prediction
+            tel.finish_header(**({"plan": self._plan} if self._plan else {}))
             return self._fit_instrumented(batch_size, steps, eval_every)
         finally:
             # idempotent: the success path already closed with final metrics;
@@ -526,42 +533,57 @@ class ClassifierTrainer:
         (constructed and torn down by ``fit``)."""
         tcfg = self.train_config
         tel = self._telemetry
-        state = self._init_state()
-        # post-init: the params/optimizer footprint, with exact per-device
-        # opt-state accounting (1/dp of it under weight_update_sharding)
-        tel.memory_event(
-            params_bytes_per_device=state_lib.tree_bytes_per_device(state.params),
-            opt_state_bytes_per_device=state_lib.tree_bytes_per_device(
-                state.opt_state
-            ),
-            weight_update_sharding=tcfg.weight_update_sharding,
-        )
-        # MFU pricing + continuous profiling: the planner's analytic FLOP
-        # model (6 * params * batch per step: fwd 2x + bwd 4x) against the
-        # measured step time turns every step_window into an MFU point; the
-        # profiler layers windowed/triggered jax.profiler captures on top and
-        # ledgers the per-op roofline (obs/profiler.py)
-        if tel.enabled:
-            n_dev = self.mesh.devices.size
-            tel.set_step_flops(
-                6.0 * float(self.params) * float(batch_size),
-                n_devices=n_dev,
-                # dominant steady-state collective: the gradient all-reduce,
-                # ~2x params bytes on-wire per step (ring); only priced when
-                # there is a wire to cross
-                collective_bytes_per_step=(
-                    2.0 * float(
-                        state_lib.tree_bytes_per_device(state.params)
-                    ) if n_dev > 1 else None
+        with tel.span("startup/init_state"):
+            state = self._init_state()
+        # `restore` holds the wait for the restored step number too: the
+        # first value the host needs off the device
+        with tel.span("startup/restore"):
+            # post-init: the params/optimizer footprint, with exact
+            # per-device opt-state accounting (1/dp of it under
+            # weight_update_sharding)
+            tel.memory_event(
+                params_bytes_per_device=state_lib.tree_bytes_per_device(
+                    state.params
                 ),
+                opt_state_bytes_per_device=state_lib.tree_bytes_per_device(
+                    state.opt_state
+                ),
+                weight_update_sharding=tcfg.weight_update_sharding,
             )
-            profiler = obs_lib.ContinuousProfiler(
-                tel, every_windows=tcfg.profile_every_windows
-            )
-            tel.set_profiler(profiler)
-        ckpt = self._checkpointer()
-        state = ckpt.restore_latest(state)
-        start_step = int(jax.device_get(state.step))
+            if tel.enabled:
+                # MFU pricing: the planner's dense-proxy FLOPs against the
+                # window's wall per step turn every step_window into an MFU
+                # point — where the proxy holds (planner.dense_proxy_flops);
+                # elsewhere the windows omit `mfu`
+                from tensorflowdistributedlearning_tpu.parallel import (
+                    planner as planner_lib,
+                )
+
+                step_flops = planner_lib.dense_proxy_flops(
+                    self.model_config, self.params, batch_size
+                )
+                if step_flops is not None:
+                    n_dev = self.mesh.devices.size
+                    tel.set_step_flops(
+                        step_flops,
+                        n_devices=n_dev,
+                        # dominant steady-state collective: the gradient
+                        # all-reduce, ~2x params bytes on-wire per step
+                        # (ring); only priced when there is a wire to cross
+                        collective_bytes_per_step=(
+                            2.0 * float(
+                                state_lib.tree_bytes_per_device(state.params)
+                            ) if n_dev > 1 else None
+                        ),
+                    )
+                # continuous profiling: windowed/triggered jax.profiler
+                # captures, the per-op roofline ledgered (obs/profiler.py)
+                tel.set_profiler(obs_lib.ContinuousProfiler(
+                    tel, every_windows=tcfg.profile_every_windows
+                ))
+            ckpt = self._checkpointer()
+            state = ckpt.restore_latest(state)
+            start_step = int(jax.device_get(state.step))
         if start_step >= steps:
             logger.info("already trained to step %d", start_step)
             metrics = self._evaluate(state, batch_size, step_no=start_step)
@@ -580,51 +602,52 @@ class ClassifierTrainer:
             # against (seed, start_step) — the index-keyed resume contract
             self._restored_data_state = ckpt.restore_data_state(start_step)
 
-        if self._tp:
-            from tensorflowdistributedlearning_tpu.parallel import tensor as tp_lib
+        with tel.span("startup/build_step"):
+            if self._tp:
+                from tensorflowdistributedlearning_tpu.parallel import tensor as tp_lib
 
-            train_step = tp_lib.make_train_step_gspmd(
-                self.mesh,
-                self.task,
-                weight_update_sharding=tcfg.weight_update_sharding,
-            )
-        elif self._pp:
-            from tensorflowdistributedlearning_tpu.train import pipeline_step as pp_lib
+                train_step = tp_lib.make_train_step_gspmd(
+                    self.mesh,
+                    self.task,
+                    weight_update_sharding=tcfg.weight_update_sharding,
+                )
+            elif self._pp:
+                from tensorflowdistributedlearning_tpu.train import pipeline_step as pp_lib
 
-            train_step = pp_lib.make_train_step_pipeline(
-                self.mesh, self.task, self.model_config, self._pp_microbatches,
-                seed=self.train_config.seed,
-            )
-        else:
-            train_step = step_lib.make_train_step(
-                self.mesh,
-                self.task,
-                weight_decay=self.model_config.weight_decay,
-                spatial=self._spatial,
-                accum=self.train_config.grad_accum_steps,
-                seed=self.train_config.seed,
-                weight_update_sharding=tcfg.weight_update_sharding,
-            )
-        is_main = jax.process_index() == 0
-        tb_train = SummaryWriter(os.path.join(self.model_dir, "train")) if is_main else None
-        tb_eval = SummaryWriter(os.path.join(self.model_dir, "eval")) if is_main else None
+                train_step = pp_lib.make_train_step_pipeline(
+                    self.mesh, self.task, self.model_config, self._pp_microbatches,
+                    seed=self.train_config.seed,
+                )
+            else:
+                train_step = step_lib.make_train_step(
+                    self.mesh,
+                    self.task,
+                    weight_decay=self.model_config.weight_decay,
+                    spatial=self._spatial,
+                    accum=self.train_config.grad_accum_steps,
+                    seed=self.train_config.seed,
+                    weight_update_sharding=tcfg.weight_update_sharding,
+                )
+            is_main = jax.process_index() == 0
+            tb_train = SummaryWriter(os.path.join(self.model_dir, "train")) if is_main else None
+            tb_eval = SummaryWriter(os.path.join(self.model_dir, "eval")) if is_main else None
 
-        batches = pipeline_lib.device_prefetch(
-            self._train_stream(batch_size, steps - start_step, start_step),
-            self._place_batch,
-            depth=tcfg.prefetch_depth,
-            # the gauge is drained per log window; a run that never writes
-            # windows (telemetry off, or a non-main host with no TB writer)
-            # must not record into it — the samples would accumulate for the
-            # life of the run with nothing reading them
-            registry=(
-                tel.registry if tel.enabled and tb_train is not None else None
-            ),
-        )
+            batches = pipeline_lib.device_prefetch(
+                self._train_stream(batch_size, steps - start_step, start_step),
+                self._place_batch,
+                depth=tcfg.prefetch_depth,
+                # the gauge is drained per log window; a run that never writes
+                # windows (telemetry off, or a non-main host with no TB writer)
+                # must not record into it — the samples would accumulate for the
+                # life of the run with nothing reading them
+                registry=(
+                    tel.registry if tel.enabled and tb_train is not None else None
+                ),
+            )
+            prepare = self._make_prepare_train()
         step_no = start_step
         last_eval_step = -1
         final_metrics: Dict[str, float] = {}
-        prepare = self._make_prepare_train()
         window_t0 = time.perf_counter()
         window_start = step_no
         # first window contains the compile; eval/save windows are not training
@@ -669,6 +692,8 @@ class ClassifierTrainer:
 
         batches_it = iter(batches)
         _end = object()
+        # the last start-up phase: until the tracker retires the first step
+        tel.begin_first_step()
         while True:
             # host blocked on the loader (prefetch underrun) vs dispatching
             # compute: the split the ledger's step windows record
@@ -677,12 +702,15 @@ class ClassifierTrainer:
             if raw is _end:
                 break
             with tel.span(obs_lib.SPAN_STEP):
-                batch = prepare(jax.numpy.asarray(step_no), raw)
-                state, metrics = train_step(state, batch)
+                with tel.span(obs_lib.SPAN_DISPATCH_PREPARE):
+                    batch = prepare(jax.numpy.asarray(step_no), raw)
+                with tel.span(obs_lib.SPAN_DISPATCH_STEP):
+                    state, metrics = train_step(state, batch)
             step_no += 1
             # bounded dispatch-ahead: block (as fetch_wait) once more than
-            # dispatch_ahead_steps steps are in flight
-            overlap.track(metrics)
+            # dispatch_ahead_steps steps are in flight; the step that wait
+            # retires gets its completion time
+            overlap.track(metrics, step_no)
             # resilience boundary: injected faults fire here (a SIGTERM lands
             # in the preemption handler below within the same boundary), and a
             # pending preemption turns into a final checkpoint + distinct exit

@@ -303,36 +303,11 @@ class Trainer:
         mesh_lib.check_accum_divisibility(
             batch_size, self.mesh, tcfg.grad_accum_steps
         )
-        dataset = pipeline_lib.InMemoryDataset.from_directory(
-            self.data_directory, ids=list(X)
-        )
-        if y is None:
-            y = folds_lib.coverage_to_class(
-                pipeline_lib.mask_coverage(dataset.masks)
-            )
-        manifests = folds_lib.write_fold_manifests(
-            self.model_dir, list(X), list(np.asarray(y)), tcfg.n_folds, tcfg.seed
-        )
-        # describe this run's layout through the parallelism planner so the
-        # run header carries the plan (predicted bytes/chip); best-effort —
-        # the mesh already validated divisibility in __init__, so a planner
-        # hiccup here is telemetry loss, not a training error (the CLI's
-        # --parallelism auto resolves its plan BEFORE this trainer exists)
-        run_plan = self._plan
-        if run_plan is None and tcfg.telemetry:
-            # the plan's only consumer here is the run header
-            try:
-                from tensorflowdistributedlearning_tpu.parallel import (
-                    planner as planner_lib,
-                )
-
-                run_plan = planner_lib.validate_config(
-                    self.model_config, tcfg, batch_size
-                ).header()
-            except Exception as e:  # noqa: BLE001 — plan is telemetry here
-                logger.warning("parallelism plan unavailable: %s", e)
-        # one ledger for the whole K-fold run; events carry their fold
-        self._telemetry = obs_lib.Telemetry(
+        # one ledger for the whole K-fold run; events carry their fold. Built
+        # before anything else, so that the start-up phases below are spans
+        # and the compile listener hears the whole start; the header waits
+        # for the plan (finish_header)
+        tel = self._telemetry = obs_lib.Telemetry(
             self.model_dir,
             enabled=tcfg.telemetry,
             memory_every_windows=tcfg.telemetry_memory_every_windows,
@@ -340,6 +315,7 @@ class Trainer:
             # online health monitors (obs/health.py) ride the window stream
             trace_sample_rate=tcfg.trace_sample_rate,
             health=obs_lib.HealthMonitor.from_train_config(tcfg),
+            hold_header=True,
             run_info={
                 "task": "segmentation",
                 "steps": steps,
@@ -353,15 +329,48 @@ class Trainer:
                 },
                 "model_config": dataclasses.asdict(self.model_config),
                 "train_config": dataclasses.asdict(tcfg),
-                # chosen layout + predicted bytes/chip (parallel/planner.py):
-                # rendered by telemetry-report, hashed by obs/compare
-                **({"plan": run_plan} if run_plan else {}),
             },
         )
         # time cross-process sync points as this run's barrier_wait span —
         # per-host barrier asymmetry is the fleet report's straggler signal
         multihost.instrument(self._telemetry)
         try:
+            with tel.span("startup/load_dataset"):
+                dataset = pipeline_lib.InMemoryDataset.from_directory(
+                    self.data_directory, ids=list(X)
+                )
+                if y is None:
+                    y = folds_lib.coverage_to_class(
+                        pipeline_lib.mask_coverage(dataset.masks)
+                    )
+            with tel.span("startup/folds"):
+                manifests = folds_lib.write_fold_manifests(
+                    self.model_dir, list(X), list(np.asarray(y)), tcfg.n_folds,
+                    tcfg.seed,
+                )
+            # describe this run's layout through the parallelism planner so
+            # the run header carries the plan (predicted bytes/chip);
+            # best-effort — the mesh already validated divisibility in
+            # __init__, so a planner hiccup here is telemetry loss, not a
+            # training error (the CLI's --parallelism auto resolves its plan
+            # BEFORE this trainer exists)
+            run_plan = self._plan
+            with tel.span("startup/plan"):
+                if run_plan is None and tcfg.telemetry:
+                    # the plan's only consumer here is the run header
+                    try:
+                        from tensorflowdistributedlearning_tpu.parallel import (
+                            planner as planner_lib,
+                        )
+
+                        run_plan = planner_lib.validate_config(
+                            self.model_config, tcfg, batch_size
+                        ).header()
+                    except Exception as e:  # noqa: BLE001 — plan is telemetry here
+                        logger.warning("parallelism plan unavailable: %s", e)
+            # chosen layout + predicted bytes/chip (parallel/planner.py):
+            # rendered by telemetry-report, hashed by obs/compare
+            tel.finish_header(**({"plan": run_plan} if run_plan else {}))
             results = []
             for fold, manifest in enumerate(manifests):
                 logger.info("Processing fold %d", fold)  # reference: model.py:162
@@ -392,53 +401,72 @@ class Trainer:
         steps: int,
     ) -> Dict[str, float]:
         tcfg = self.train_config
+        tel = self._telemetry
+        tel.fold = fold
         # one telemetry (and one HealthMonitor) spans all K folds, but loss
         # history and step-time baselines are per-FOLD facts: a converged
         # fold's low-loss median would flag the next fold's fresh untrained
         # loss as a spike
-        if self._telemetry.health is not None:
-            self._telemetry.health.reset()
-        # per-process data: each host loads only its round-robin shard of the fold
-        # and draws batch/P examples per step; global_shard_batch assembles them
-        # into one globally-sharded batch (the per-host generalization of the
-        # reference's per-tower batch/n_gpus contract, model.py:156-159)
-        local_bs = multihost.per_process_batch_size(batch_size)
-        train_ds = dataset.select(pipeline_lib.host_shard(manifest["train"]))
-        eval_ds = dataset.select(pipeline_lib.host_shard(manifest["eval"]))
-        eval_global_n = len(manifest["eval"])
-
-        ckpt = self._checkpointer(fold)
-        state = ckpt.restore_latest(self._init_state())
-        # post-init params/optimizer footprint, with exact per-device
-        # opt-state accounting (1/dp of it under weight_update_sharding)
-        self._telemetry.memory_event(
-            params_bytes_per_device=state_lib.tree_bytes_per_device(state.params),
-            opt_state_bytes_per_device=state_lib.tree_bytes_per_device(
-                state.opt_state
-            ),
-            weight_update_sharding=tcfg.weight_update_sharding,
-        )
-        # MFU pricing + continuous profiling: analytic 6*params*batch FLOPs
-        # against measured step time makes every step_window carry `mfu`; the
-        # profiler adds windowed/triggered jax.profiler captures and ledgers
-        # the per-op roofline (obs/profiler.py)
-        if self._telemetry.enabled:
-            n_dev = self.mesh.devices.size
-            self._telemetry.set_step_flops(
-                6.0 * float(self.params) * float(batch_size),
-                n_devices=n_dev,
-                collective_bytes_per_step=(
-                    2.0 * float(
-                        state_lib.tree_bytes_per_device(state.params)
-                    ) if n_dev > 1 else None
+        if tel.health is not None:
+            tel.health.reset()
+        # the fold's start-up phases lie back to back, so that no second
+        # before the first step is unnamed: `init_state` holds the fold's data
+        # selection and its checkpointer too, `restore` the wait for the
+        # restored step number (the first value the host needs off the device)
+        with tel.span("startup/init_state"):
+            # per-process data: each host loads only its round-robin shard of
+            # the fold and draws batch/P examples per step; global_shard_batch
+            # assembles them into one globally-sharded batch (the per-host
+            # generalization of the reference's per-tower batch/n_gpus
+            # contract, model.py:156-159)
+            local_bs = multihost.per_process_batch_size(batch_size)
+            train_ds = dataset.select(pipeline_lib.host_shard(manifest["train"]))
+            eval_ds = dataset.select(pipeline_lib.host_shard(manifest["eval"]))
+            eval_global_n = len(manifest["eval"])
+            ckpt = self._checkpointer(fold)
+            state = self._init_state()
+        with tel.span("startup/restore"):
+            state = ckpt.restore_latest(state)
+            # post-init params/optimizer footprint, with exact per-device
+            # opt-state accounting (1/dp of it under weight_update_sharding)
+            tel.memory_event(
+                params_bytes_per_device=state_lib.tree_bytes_per_device(
+                    state.params
                 ),
+                opt_state_bytes_per_device=state_lib.tree_bytes_per_device(
+                    state.opt_state
+                ),
+                weight_update_sharding=tcfg.weight_update_sharding,
             )
-            if self._telemetry.profiler is None:
-                self._telemetry.set_profiler(obs_lib.ContinuousProfiler(
-                    self._telemetry,
-                    every_windows=tcfg.profile_every_windows,
-                ))
-        start_step = int(jax.device_get(state.step))
+            if tel.enabled:
+                # MFU pricing where the planner's dense proxy holds
+                # (planner.dense_proxy_flops): not for this trainer's
+                # convolutional backbones, whose windows omit `mfu`
+                from tensorflowdistributedlearning_tpu.parallel import (
+                    planner as planner_lib,
+                )
+
+                step_flops = planner_lib.dense_proxy_flops(
+                    self.model_config, self.params, batch_size
+                )
+                if step_flops is not None:
+                    n_dev = self.mesh.devices.size
+                    tel.set_step_flops(
+                        step_flops,
+                        n_devices=n_dev,
+                        collective_bytes_per_step=(
+                            2.0 * float(
+                                state_lib.tree_bytes_per_device(state.params)
+                            ) if n_dev > 1 else None
+                        ),
+                    )
+                # continuous profiling: windowed/triggered jax.profiler
+                # captures, the per-op roofline ledgered (obs/profiler.py)
+                if tel.profiler is None:
+                    tel.set_profiler(obs_lib.ContinuousProfiler(
+                        tel, every_windows=tcfg.profile_every_windows,
+                    ))
+            start_step = int(jax.device_get(state.step))
         if start_step >= steps:
             logger.info("fold %d already trained to step %d", fold, start_step)
             ckpt.close()
@@ -450,95 +478,96 @@ class Trainer:
             # resume verification: training actually CONTINUES from a prior
             # checkpoint (an already-trained fold rerun above is not a resume);
             # telemetry-report lines restarts up with the recovered progress
-            self._telemetry.event("resumed", step=start_step, fold=fold)
+            tel.event("resumed", step=start_step, fold=fold)
 
-        train_step = step_lib.make_train_step(
-            self.mesh,
-            self.task,
-            weight_decay=self.model_config.weight_decay,
-            spatial=self._spatial,
-            accum=self.train_config.grad_accum_steps,
-            seed=self.train_config.seed,
-            auto_model=self._tp,
-            weight_update_sharding=tcfg.weight_update_sharding,
-        )
-        prepare = self._make_prepare_train(fold)
-
-        is_main = jax.process_index() == 0
-        tb_train = SummaryWriter(os.path.join(self._fold_dir(fold), "train")) if is_main else None
-        tb_eval = SummaryWriter(os.path.join(self._fold_dir(fold), "eval")) if is_main else None
-        last_eval_time = 0.0
-        final_metrics: Dict[str, float] = {}
-
-        data_service = None
-        if tcfg.data_service_workers > 0:
-            # streaming data service over the in-memory fold (data/service.py
-            # ArrayBatchSource): batch assembly moves off the host loop onto
-            # N workers, and the stream is INDEX-KEYED — batch i is a pure
-            # function of (seed+fold, i), so a resumed fold replays the exact
-            # remaining stream instead of approximating it by folding the
-            # resume step into the seed
-            from tensorflowdistributedlearning_tpu.data import (
-                service as service_lib,
+        with tel.span("startup/build_step"):
+            train_step = step_lib.make_train_step(
+                self.mesh,
+                self.task,
+                weight_decay=self.model_config.weight_decay,
+                spatial=self._spatial,
+                accum=self.train_config.grad_accum_steps,
+                seed=self.train_config.seed,
+                auto_model=self._tp,
+                weight_update_sharding=tcfg.weight_update_sharding,
             )
+            prepare = self._make_prepare_train(fold)
 
-            svc = service_lib.StreamingDataService(
-                service_lib.ArrayBatchSource(
-                    {"images": train_ds.images, "masks": train_ds.masks},
-                    # the fold arrays were host-sharded for THIS world size:
-                    # stamping it into the resume sidecar makes a resume that
-                    # crossed a world resize an explicit, ledgered re-deal
-                    # (the per-host rows change meaning) instead of a silent
-                    # re-index — the same resize-aware contract as fit()'s
-                    # record path
-                    process_count=jax.process_count(),
+            is_main = jax.process_index() == 0
+            tb_train = SummaryWriter(os.path.join(self._fold_dir(fold), "train")) if is_main else None
+            tb_eval = SummaryWriter(os.path.join(self._fold_dir(fold), "eval")) if is_main else None
+            last_eval_time = 0.0
+            final_metrics: Dict[str, float] = {}
+
+            data_service = None
+            if tcfg.data_service_workers > 0:
+                # streaming data service over the in-memory fold (data/service.py
+                # ArrayBatchSource): batch assembly moves off the host loop onto
+                # N workers, and the stream is INDEX-KEYED — batch i is a pure
+                # function of (seed+fold, i), so a resumed fold replays the exact
+                # remaining stream instead of approximating it by folding the
+                # resume step into the seed
+                from tensorflowdistributedlearning_tpu.data import (
+                    service as service_lib,
+                )
+
+                svc = service_lib.StreamingDataService(
+                    service_lib.ArrayBatchSource(
+                        {"images": train_ds.images, "masks": train_ds.masks},
+                        # the fold arrays were host-sharded for THIS world size:
+                        # stamping it into the resume sidecar makes a resume that
+                        # crossed a world resize an explicit, ledgered re-deal
+                        # (the per-host rows change meaning) instead of a silent
+                        # re-index — the same resize-aware contract as fit()'s
+                        # record path
+                        process_count=jax.process_count(),
+                    ),
+                    batch_size=local_bs,
+                    seed=tcfg.seed + fold,
+                    workers=tcfg.data_service_workers,
+                    start_batch=start_step,
+                    registry=(
+                        self._telemetry.registry
+                        if self._telemetry.enabled and tb_train is not None
+                        else None
+                    ),
+                    resume_state=(
+                        ckpt.restore_data_state(start_step)
+                        if start_step > 0 else None
+                    ),
+                )
+                data_service = svc
+                if svc.redeal is not None:
+                    self._telemetry.event(
+                        "data_redeal", step=start_step, fold=fold, **svc.redeal
+                    )
+                batches = svc.batches(steps=steps - start_step)
+            else:
+                batches = pipeline_lib.train_batches(
+                    train_ds,
+                    local_bs,
+                    # fold the resume point into the shuffle seed so a resumed
+                    # run does not replay the same shuffled order from the
+                    # beginning (see ClassifierTrainer._train_stream)
+                    seed=tcfg.seed + fold + 7919 * start_step,
+                    steps=steps - start_step,
+                )
+            batches = pipeline_lib.device_prefetch(
+                batches,
+                lambda b: multihost.global_shard_batch(
+                    b, self.mesh, spatial=self._spatial
                 ),
-                batch_size=local_bs,
-                seed=tcfg.seed + fold,
-                workers=tcfg.data_service_workers,
-                start_batch=start_step,
+                depth=tcfg.prefetch_depth,
+                # the gauge is drained per log window; a run that never writes
+                # windows (telemetry off, or a non-main host with no TB writer)
+                # must not record into it — the samples would accumulate for the
+                # life of the run with nothing reading them
                 registry=(
                     self._telemetry.registry
                     if self._telemetry.enabled and tb_train is not None
                     else None
                 ),
-                resume_state=(
-                    ckpt.restore_data_state(start_step)
-                    if start_step > 0 else None
-                ),
             )
-            data_service = svc
-            if svc.redeal is not None:
-                self._telemetry.event(
-                    "data_redeal", step=start_step, fold=fold, **svc.redeal
-                )
-            batches = svc.batches(steps=steps - start_step)
-        else:
-            batches = pipeline_lib.train_batches(
-                train_ds,
-                local_bs,
-                # fold the resume point into the shuffle seed so a resumed
-                # run does not replay the same shuffled order from the
-                # beginning (see ClassifierTrainer._train_stream)
-                seed=tcfg.seed + fold + 7919 * start_step,
-                steps=steps - start_step,
-            )
-        batches = pipeline_lib.device_prefetch(
-            batches,
-            lambda b: multihost.global_shard_batch(
-                b, self.mesh, spatial=self._spatial
-            ),
-            depth=tcfg.prefetch_depth,
-            # the gauge is drained per log window; a run that never writes
-            # windows (telemetry off, or a non-main host with no TB writer)
-            # must not record into it — the samples would accumulate for the
-            # life of the run with nothing reading them
-            registry=(
-                self._telemetry.registry
-                if self._telemetry.enabled and tb_train is not None
-                else None
-            ),
-        )
         step_no = start_step
         last_eval_step = -1
         window_t0 = time.perf_counter()
@@ -549,7 +578,6 @@ class Trainer:
         window_dirty = True
         # host-side schedule mirror: the lr log line adds zero device work
         lr_sched = step_lib.make_host_lr_schedule(tcfg)
-        tel = self._telemetry
 
         def emit_window(rec: async_loop.PendingWindow, scalars) -> None:
             if tb_train is not None:
@@ -586,6 +614,9 @@ class Trainer:
 
         batches_it = iter(batches)
         _end = object()
+        # the last start-up phase: until the tracker retires this fold's
+        # first step
+        tel.begin_first_step()
         while True:
             # host blocked on the loader vs dispatching compute: the split
             # the ledger's step windows record
@@ -594,12 +625,15 @@ class Trainer:
             if raw is _end:
                 break
             with tel.span(obs_lib.SPAN_STEP):
-                batch = prepare(jnp.asarray(step_no), raw)
-                state, metrics = train_step(state, batch)
+                with tel.span(obs_lib.SPAN_DISPATCH_PREPARE):
+                    batch = prepare(jnp.asarray(step_no), raw)
+                with tel.span(obs_lib.SPAN_DISPATCH_STEP):
+                    state, metrics = train_step(state, batch)
             step_no += 1
             # bounded dispatch-ahead: block (as fetch_wait) once more than
-            # dispatch_ahead_steps steps are in flight
-            overlap.track(metrics)
+            # dispatch_ahead_steps steps are in flight; the step that wait
+            # retires gets its completion time
+            overlap.track(metrics, step_no)
             # resilience boundary: injected faults fire here (a SIGTERM lands
             # in the preemption handler below within the same boundary), and a
             # pending preemption turns into a final checkpoint + distinct exit
@@ -816,17 +850,20 @@ class Trainer:
                 params=jax.device_get(state.params),
                 batch_stats=jax.device_get(state.batch_stats),
             )
-        outputs = self._forward(state, batch["images"])
-        probs = np.asarray(jax.device_get(jax.nn.sigmoid(outputs)))[..., 0]
-        images = np.asarray(jax.device_get(batch["images"]))[..., 0]
-        labels = np.asarray(jax.device_get(batch["labels"]))[..., 0]
-        n = min(3, images.shape[0])
-        for i in range(n):
-            lo, hi = images[i].min(), images[i].max()
-            writer.image(f"image/{i}", (images[i] - lo) / max(hi - lo, 1e-6), step_no)
-            writer.image(f"label/{i}", labels[i], step_no)
-            writer.image(f"probability/{i}", probs[i], step_no)
-            writer.image(f"prediction/{i}", (probs[i] > 0.5).astype(np.float32), step_no)
+        # an extra forward and three device_gets behind whatever is in flight:
+        # host time of the window it falls into (`image_summary_s`)
+        with self._telemetry.span(obs_lib.SPAN_IMAGE_SUMMARY):
+            outputs = self._forward(state, batch["images"])
+            probs = np.asarray(jax.device_get(jax.nn.sigmoid(outputs)))[..., 0]
+            images = np.asarray(jax.device_get(batch["images"]))[..., 0]
+            labels = np.asarray(jax.device_get(batch["labels"]))[..., 0]
+            n = min(3, images.shape[0])
+            for i in range(n):
+                lo, hi = images[i].min(), images[i].max()
+                writer.image(f"image/{i}", (images[i] - lo) / max(hi - lo, 1e-6), step_no)
+                writer.image(f"label/{i}", labels[i], step_no)
+                writer.image(f"probability/{i}", probs[i], step_no)
+                writer.image(f"prediction/{i}", (probs[i] > 0.5).astype(np.float32), step_no)
 
     # -- cached jitted helpers --------------------------------------------
 
