@@ -4,10 +4,11 @@ TPU-first redesign of the reference's per-image host-side tf.data augmentation
 (reference: preprocessing/preprocessing.py:112-246):
 
 - The whole augmentation is a jittable function of ``(key, images, masks)``; the host
-  only decodes PNGs. Geometry runs on TPU as one composed inverse-warp gather per
-  image (the reference likewise composed flips/rotation/shift/crop into ONE projective
-  transform, reference: preprocessing/preprocessing.py:162-238 — but executed it on the
-  host CPU per image).
+  only decodes PNGs. Geometry runs on TPU as one composed inverse warp per image (the
+  reference likewise composed flips/rotation/shift/crop into ONE projective transform,
+  reference: preprocessing/preprocessing.py:162-238 — but executed it on the host CPU
+  per image), sampled only at the pixels the central crop keeps and as dense
+  interpolation weights contracted on the MXU, not gathered (``_warp_crop``).
 - Randomness uses per-image PRNG keys from ``jax.random.split``, fixing the reference's
   graph-construction-time numpy RNG for shifts, which sampled ONE shift per pipeline
   and reused it for every image (reference: preprocessing/preprocessing.py:196-203,
@@ -152,32 +153,89 @@ def _zoom_crop(pct: jax.Array, off_x: jax.Array, off_y: jax.Array) -> jax.Array:
     )
 
 
-def _apply_warp(image: jax.Array, matrix: jax.Array, order: int) -> jax.Array:
-    """Inverse-warp a [H, W, C] image by a 3x3 affine matrix. ``order=1`` bilinear
-    (image), ``order=0`` nearest (mask) — reference: preprocessing.py:230-238. Out-of-
-    bounds samples fill with 0, matching ``tf.contrib.image.transform``."""
-    h, w, c = image.shape
-    ys, xs = jnp.meshgrid(
-        jnp.arange(h, dtype=jnp.float32), jnp.arange(w, dtype=jnp.float32), indexing="ij"
-    )
-    in_x = matrix[0, 0] * xs + matrix[0, 1] * ys + matrix[0, 2]
-    in_y = matrix[1, 0] * xs + matrix[1, 1] * ys + matrix[1, 2]
+def _hat(d: jax.Array) -> jax.Array:
+    """Linear interpolation weight of a sample ``d`` pixels from a grid point."""
+    return jnp.maximum(0.0, 1.0 - jnp.abs(d))
 
-    def warp_channel(ch: jax.Array) -> jax.Array:
-        return jax.scipy.ndimage.map_coordinates(
-            ch, [in_y, in_x], order=order, mode="constant", cval=0.0
+
+def _warp_crop(
+    image: jax.Array,
+    mask: jax.Array,
+    matrix: jax.Array,
+    out_hw: Tuple[int, int],
+    rows_per_chunk: int,
+) -> Tuple[jax.Array, jax.Array]:
+    """Inverse-warp an [H, W] image/mask pair by a 3x3 affine matrix, at the output
+    pixels of the central ``out_hw`` crop only (the reference's
+    ``tf.image.central_crop(x, 101/181)`` after the transform,
+    preprocessing/preprocessing.py:230-241). Bilinear for the image, nearest (half
+    away from zero) for the mask, samples outside the input fill with 0, matching
+    ``tf.contrib.image.transform``.
+
+    A TPU gathers scalars one at a time and has a matrix unit, so nothing is
+    gathered: each output pixel's interpolation weights over a whole input row and
+    column are written out densely (two non-zeros each, the rest 0) and contracted
+    with the image, ``out[y, x] = sum_i wy[y, x, i] * sum_j wx[y, x, j] * I[i, j]``.
+    Only the in-range ``i, j`` exist, which is the zero fill. Output rows are walked
+    ``rows_per_chunk`` at a time so the [rows, out_w, W] weights stay small; the
+    batch (this function is vmapped) stays the leading axis of every operand.
+
+    The first contraction costs ``2 * out_h * out_w * H * W`` FLOPs an image — the
+    fourth power of the side where a gather is the second; PERF.md §6 (PR 25) has
+    the size at which it would have to be banded."""
+    h, w = image.shape
+    out_h, out_w = out_hw
+    top, left = (h - out_h) // 2, (w - out_w) // 2
+    xs = left + jnp.arange(out_w, dtype=jnp.float32)
+    rows = jnp.arange(h, dtype=jnp.float32)
+    cols = jnp.arange(w, dtype=jnp.float32)
+    # 0 and 1 are exact in bfloat16 and every sum below has one non-zero term, so
+    # the mask takes one bfloat16 pass of the MXU and is exact; the image's pixels
+    # and weights are not, and take float32 accuracy (a default-precision dot on
+    # a TPU would round both to bfloat16).
+    mask_dtype, mask = mask.dtype, mask.astype(jnp.bfloat16)
+
+    def sample_row(y: jax.Array) -> Tuple[jax.Array, jax.Array]:
+        in_x = matrix[0, 0] * xs + matrix[0, 1] * y + matrix[0, 2]
+        in_y = matrix[1, 0] * xs + matrix[1, 1] * y + matrix[1, 2]
+        along_x = jnp.einsum(
+            "xj,ij->xi",
+            _hat(in_x[:, None] - cols),
+            image,
+            precision=lax.Precision.HIGHEST,
         )
+        image_row = jnp.sum(_hat(in_y[:, None] - rows) * along_x, axis=-1)
+        along_x = jnp.einsum(
+            "xj,ij->xi",
+            (lax.round(in_x)[:, None] == cols).astype(jnp.bfloat16),
+            mask,
+            preferred_element_type=jnp.float32,
+        )
+        mask_row = jnp.sum(
+            jnp.where(lax.round(in_y)[:, None] == rows, along_x, 0.0), axis=-1
+        )
+        return image_row, mask_row.astype(mask_dtype)
 
-    return jnp.stack([warp_channel(image[..., i]) for i in range(c)], axis=-1)
+    ys = top + jnp.arange(out_h, dtype=jnp.float32)
+    return lax.map(sample_row, ys, batch_size=rows_per_chunk)
 
 
-def central_crop(x: jax.Array, out_hw: Tuple[int, int]) -> jax.Array:
-    """Static central crop (the reference's ``tf.image.central_crop(x, 101/181)``,
-    preprocessing/preprocessing.py:240-241)."""
-    h, w = x.shape[-3], x.shape[-2]
-    th, tw = out_hw
-    top, left = (h - th) // 2, (w - tw) // 2
-    return x[..., top : top + th, left : left + tw, :]
+# Bytes one [batch, rows, out_w, side] float32 intermediate of ``_warp_crop`` may
+# take; a handful are live at once if the compiler fuses none of them, so the
+# augmentation's temporaries stay near 1 GiB. (The v5e's compiler fuses them all,
+# and there the size matters another way: one piece of 1.9 GB read wrong on the
+# chip, pieces of 1.2 GB and less read right — PERF.md §6, PR 25.)
+_WARP_CHUNK_BYTES = 256 << 20
+
+
+def _rows_per_chunk(batch: int, side: int, out_hw: Tuple[int, int]) -> int:
+    """Output rows ``_warp_crop`` takes at a time: as many as the budget above
+    holds for the whole batch (``side``: the padded frame's longer side), evened
+    out over the chunks."""
+    out_h, out_w = out_hw
+    most = max(1, _WARP_CHUNK_BYTES // (4 * batch * out_w * side))
+    n_chunks = -(-out_h // most)
+    return -(-out_h // n_chunks)
 
 
 def _sample_affine(
@@ -234,19 +292,21 @@ def _augment_one(
     mask: jax.Array,
     cfg: AugmentConfig,
     out_hw: Tuple[int, int],
+    rows_per_chunk: int,
 ) -> Tuple[jax.Array, jax.Array]:
     """Augment a single [H, W, 1] image/mask pair. vmapped over the batch."""
-    pad = cfg.pad
-    pad_spec = [(pad, pad), (pad, pad), (0, 0)]
-    image = jnp.pad(image, pad_spec, mode="reflect")
-    mask = jnp.pad(mask, pad_spec, mode="reflect")
+    pad_spec = [(cfg.pad, cfg.pad), (cfg.pad, cfg.pad)]
+    image = jnp.pad(image[..., 0], pad_spec, mode="reflect")
+    mask = jnp.pad(mask[..., 0], pad_spec, mode="reflect")
 
     k_transpose, k_bright, k_affine = jax.random.split(key, 3)
 
-    # random transpose (reference: preprocessing/preprocessing.py:165-167)
-    do_t = jax.random.uniform(k_transpose) < cfg.transpose_probability
-    image = jnp.where(do_t, jnp.transpose(image, (1, 0, 2)), image)
-    mask = jnp.where(do_t, jnp.transpose(mask, (1, 0, 2)), mask)
+    # random transpose (reference: preprocessing/preprocessing.py:165-167); only a
+    # square frame can be transposed in place, so one that never is may be oblong
+    if cfg.transpose_probability > 0:
+        do_t = jax.random.uniform(k_transpose) < cfg.transpose_probability
+        image = jnp.where(do_t, image.T, image)
+        mask = jnp.where(do_t, mask.T, mask)
 
     # brightness jitter (reference: preprocessing/preprocessing.py:169-170)
     if cfg.brightness_range > 0:
@@ -255,14 +315,10 @@ def _augment_one(
         )
         image = image + delta
 
-    h, w = image.shape[0], image.shape[1]
+    h, w = image.shape
     matrix = _sample_affine(k_affine, cfg, float(h), float(w))
-    image = _apply_warp(image, matrix, order=1)
-    mask = _apply_warp(mask, matrix, order=0)
-
-    image = central_crop(image, out_hw)
-    mask = central_crop(mask, out_hw)
-    return image, mask
+    image, mask = _warp_crop(image, mask, matrix, out_hw, rows_per_chunk)
+    return image[..., None], mask[..., None]
 
 
 def augment_batch(
@@ -281,9 +337,11 @@ def augment_batch(
     """
     if out_hw is None:
         out_hw = (images.shape[1], images.shape[2])
-    keys = jax.random.split(key, images.shape[0])
+    batch = images.shape[0]
+    rows_per_chunk = _rows_per_chunk(batch, max(images.shape[1:3]) + 2 * cfg.pad, out_hw)
+    keys = jax.random.split(key, batch)
     aug_images, aug_masks = jax.vmap(
-        lambda k, i, m: _augment_one(k, i, m, cfg, out_hw)
+        lambda k, i, m: _augment_one(k, i, m, cfg, out_hw, rows_per_chunk)
     )(keys, images, masks)
     return {"images": add_laplace_channel(aug_images), "labels": aug_masks}
 

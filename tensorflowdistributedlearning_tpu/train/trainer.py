@@ -755,7 +755,8 @@ class Trainer:
     def _make_prepare_train(self, fold: int):
         """Jitted on-device augmentation: {'images','masks'} -> {'images','labels'}
         with the Laplacian channel (the reference's augmenting input_fn map,
-        model.py:315-317, run on TPU instead of the host). The fold's base PRNG key
+        model.py:315-317, run on TPU instead of the host: ``augment_batch``, whose
+        warp is matrix products over the kept pixels). The fold's base PRNG key
         is a traced argument, so every fold (and every Trainer with the same
         augment config) shares ONE compiled executable."""
         base_key = jax.random.PRNGKey(self.train_config.seed + fold)

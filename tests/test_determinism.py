@@ -93,6 +93,9 @@ def test_golden_loss_after_k_steps(runs):
     )
 
 
-# Recorded 2026-07-30, jax 0.9.0, 8-device CPU mesh, width_multiplier=1/16 fixture
-# (re-recorded when the fixture architecture gained width_multiplier)
-GOLDEN_LOSSES = [1.5637928247451782, 1.5359129905700684, 1.3671655654907227]
+# Recorded 2026-10-01, jax 0.9.0, 8-device CPU mesh, width_multiplier=1/16 fixture
+# (re-recorded when the fixture architecture gained width_multiplier, and when the
+# augmentation's warp became dense weights over the kept pixels: the same pixels to
+# 6e-5, tests/test_augment.py, but this fixture answers a 1e-7 perturbation of the
+# gathered warp's images with 0.2% in the first loss and 3% in the third)
+GOLDEN_LOSSES = [1.5611239671707153, 1.5893504619598389, 1.33280611038208]
