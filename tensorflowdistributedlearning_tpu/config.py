@@ -14,6 +14,108 @@ from typing import Optional, Tuple
 
 
 @dataclasses.dataclass(frozen=True)
+class DecoderConfig:
+    """A causal mixture-of-experts decoder (``backbone="decoder"``,
+    models/decoder.py). The fields are the keys of the model's published
+    ``config.json`` under their published names, then the share and the
+    training sequence length.
+
+    **The share.** A layer may be divided over ``share_count`` chips (tensor
+    and expert parallel): each holds ``num_attention_heads`` query heads with
+    ``num_key_value_heads`` key-value heads, ``num_experts`` experts and
+    ``vocab_size`` rows of the vocabulary — the counts HELD HERE, a
+    ``share_count``-th of the published ones — and is share ``share_index`` of
+    them. The router keeps its published width (``num_experts *
+    share_count`` outputs), its ``num_experts_per_tok`` and its
+    renormalisation over all chosen experts, held or not; the layer computes
+    the part of the attention and expert sums that its own heads and experts
+    give. That partial result is what the layer returns: the all-reduce over
+    the shares that completes it is the exchange, and on one chip the layer
+    runs without it (``share_count=1`` is the whole model and needs none)."""
+
+    hidden_size: int = 2304
+    head_dim: int = 128
+    num_attention_heads: int = 32
+    num_key_value_heads: int = 4
+    num_hidden_layers: int = 28
+    # one entry per layer: "sliding_attention" | "full_attention"
+    layer_types: Tuple[str, ...] = (
+        ("sliding_attention",) * 3 + ("full_attention",)
+    ) * 7
+    sliding_window: int = 1024
+    num_experts: int = 64
+    num_experts_per_tok: int = 8
+    moe_intermediate_size: int = 896
+    norm_topk_prob: bool = True
+    rms_norm_eps: float = 1e-6
+    vocab_size: int = 98304
+    # rope_parameters by layer type, each a sorted tuple of (key, value)
+    # pairs (hashable: the config is a jit static); `rope()` gives the dict
+    rope_parameters: Tuple[Tuple[str, Tuple[Tuple[str, object], ...]], ...] = (
+        ("full_attention", (
+            ("attention_factor", 1.2772588722239782), ("beta_fast", 32),
+            ("beta_slow", 1), ("factor", 16),
+            ("original_max_position_embeddings", 8192),
+            ("rope_theta", 500000), ("rope_type", "yarn"),
+        )),
+        ("sliding_attention", (("rope_theta", 500000), ("rope_type", "default"))),
+    )
+    share_count: int = 1
+    share_index: int = 0
+    # tokens of one packed training sequence (data/tokens.py)
+    sequence_length: int = 8192
+
+    def __post_init__(self):
+        if len(self.layer_types) < self.num_hidden_layers:
+            raise ValueError(
+                f"layer_types names {len(self.layer_types)} layers, "
+                f"num_hidden_layers is {self.num_hidden_layers}"
+            )
+        unknown = set(self.layer_types) - {"sliding_attention", "full_attention"}
+        if unknown:
+            raise ValueError(f"Unknown layer types {sorted(unknown)}")
+        if self.num_attention_heads % self.num_key_value_heads:
+            raise ValueError("num_attention_heads must be a multiple of num_key_value_heads")
+        if not 0 <= self.share_index < self.share_count:
+            raise ValueError(
+                f"share_index {self.share_index} is not one of {self.share_count} shares"
+            )
+        if self.num_experts_per_tok > self.num_experts * self.share_count:
+            raise ValueError("num_experts_per_tok exceeds the experts routed over")
+
+    @classmethod
+    def from_published(cls, config: dict, **share) -> "DecoderConfig":
+        """From a ``config.json`` dict (keys this class does not hold are
+        passed over); ``share`` gives share_count, share_index,
+        sequence_length."""
+        names = {f.name for f in dataclasses.fields(cls)}
+        kept = {k: v for k, v in config.items() if k in names}
+        kept["layer_types"] = tuple(config["layer_types"])
+        kept["rope_parameters"] = tuple(
+            (kind, tuple(sorted(params.items())))
+            for kind, params in sorted(config["rope_parameters"].items())
+        )
+        kept.update(share)
+        return cls(**kept)
+
+    def rope(self, layer_type: str) -> dict:
+        return dict(dict(self.rope_parameters)[layer_type])
+
+
+@dataclasses.dataclass(frozen=True)
+class TokenStreamConfig:
+    """The shape of the synthetic token stream the decoder trains on
+    (data/tokens.py). Defaults: repository-length files, mostly longer than a
+    1,024-token window."""
+
+    median_length: float = 4096.0
+    sigma: float = 1.0  # of the log of the length
+    min_length: int = 64
+    max_length: int = 8192
+    zipf_exponent: float = 1.0
+
+
+@dataclasses.dataclass(frozen=True)
 class ModelConfig:
     """Architecture hyperparameters.
 
@@ -21,7 +123,7 @@ class ModelConfig:
     ``Model.__init__`` fallbacks (reference: model.py:63-106).
     """
 
-    backbone: str = "resnet"  # "resnet" | "xception"
+    backbone: str = "resnet"  # "resnet" | "xception" | "vit" | "decoder"
     # l2 regularisation (reference: model.py:14 WEIGHT_DECAY = 0.001)
     weight_decay: float = 0.001
     # batch norm (reference: model.py:16-18)
@@ -111,10 +213,18 @@ class ModelConfig:
     # weight of the sown load-balancing loss in the training objective (the
     # Switch paper's alpha = 0.01)
     moe_aux_weight: float = 0.01
+    # backbone="decoder": the causal MoE decoder's own configuration
+    # (DecoderConfig above); None for every image backbone
+    decoder: Optional[DecoderConfig] = None
 
     def __post_init__(self):
-        if self.backbone not in ("resnet", "xception", "vit"):
+        if self.backbone not in ("resnet", "xception", "vit", "decoder"):
             raise ValueError(f"Unknown backbone {self.backbone!r}")
+        if (self.backbone == "decoder") != (self.decoder is not None):
+            raise ValueError(
+                "backbone='decoder' and a DecoderConfig go together: "
+                f"backbone={self.backbone!r}, decoder={self.decoder!r}"
+            )
         if self.block_type not in ("bottleneck", "basic_block"):
             raise ValueError(f"Unknown block type {self.block_type!r}")
         if self.dtype not in ("float32", "bfloat16"):
@@ -229,6 +339,9 @@ class TrainConfig:
     # require the standard data-parallel/tensor-parallel step (not
     # sequence/pipeline parallel). Eval is never augmented.
     augmentation: str = "flip_crop"
+    # the decoder's synthetic token stream (TokenStreamConfig above); None is
+    # its defaults, and what every image task leaves it at
+    token_stream: Optional[TokenStreamConfig] = None
     lr: float = 0.001
     # "exponential" reproduces the reference's continuous decay (model.py:457-459);
     # "cosine" is the standard ImageNet recipe (linear warmup to `lr` over

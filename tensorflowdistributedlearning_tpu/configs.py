@@ -12,7 +12,7 @@ from __future__ import annotations
 import dataclasses
 from typing import Dict, Tuple
 
-from tensorflowdistributedlearning_tpu.config import ModelConfig, TrainConfig
+from tensorflowdistributedlearning_tpu.config import DecoderConfig, ModelConfig, TrainConfig
 
 
 @dataclasses.dataclass(frozen=True)
@@ -245,6 +245,44 @@ PRESETS: Dict[str, Preset] = {
         global_batch=8192,
         description="ResNet-50 bf16 large-batch (8k) pod config (v5e-64: 128/chip), "
         "LARS optimizer, ZeRO-1 weight-update sharding",
+    ),
+    # Mellum2-12B-A2.5B (https://huggingface.co/JetBrains/Mellum2-12B-A2.5B-Instruct
+    # config.json): every width as published; one chip's share of a layer
+    # divided over four (8 of 32 query heads with 1 of 4 key-value heads, 16
+    # of 64 experts, 24,576 of 98,304 vocabulary rows) and one period of the
+    # layer pattern (sliding x3, full) of the 28 layers — 531.5M parameters
+    # here, 8.5 GB with gradients and Adam's moments. Assumed (the config has
+    # no key): AdamW 3e-7 / 0.1, no q/k norm, no auxiliary router loss.
+    "mellum2_12b_a2p5b_share4": Preset(
+        model=ModelConfig(
+            backbone="decoder",
+            dtype="bfloat16",
+            decoder=DecoderConfig(
+                num_hidden_layers=4,
+                layer_types=(("sliding_attention",) * 3 + ("full_attention",)) * 7,
+                num_attention_heads=8,
+                num_key_value_heads=1,
+                num_experts=16,
+                vocab_size=24576,
+                share_count=4,
+                share_index=0,
+                sequence_length=8192,
+            ),
+        ),
+        # the rate is small on purpose: these are random weights. At 3e-4
+        # every token took the same experts within a few steps on the chip,
+        # routers trained or frozen (PERF.md §6, PR 26) — by the update's
+        # arithmetic, not traced: the first thing random weights learn is the
+        # unigram distribution, as one vector added to every position of the
+        # residual stream (the head has no bias), and it soon outgrows the
+        # embedding. 110 steps at 3e-7 leave the stream, and so the routing,
+        # where the weights put them; pass --lr for trained weights
+        train=TrainConfig(optimizer="adam", lr=3e-7, weight_decay=0.1, augmentation="none"),
+        global_batch=2,
+        description="Mellum2-12B-A2.5B decoder (top-8-of-64 experts, window/full "
+        "attention 3:1, YaRN), next-token training on packed 8,192-token "
+        "sequences: chip 0's share of a layer divided over 4 chips, 4 of 28 "
+        "layers — a partial result by design (config.py:DecoderConfig)",
     ),
 }
 

@@ -9,6 +9,7 @@ from tensorflowdistributedlearning_tpu.models.resnet import (
     ResNetClassifier,
     ResNetSegmentation,
     build_model,
+    sample_input,
 )
 from tensorflowdistributedlearning_tpu.models.vit import (
     TransformerBlock,
@@ -31,6 +32,7 @@ __all__ = [
     "ResNetClassifier",
     "ResNetSegmentation",
     "build_model",
+    "sample_input",
     "TransformerBlock",
     "ViTClassifier",
     "pipeline_stage_fn",

@@ -30,6 +30,7 @@ from typing import Dict, Optional, Tuple
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from tensorflowdistributedlearning_tpu.config import ModelConfig
 from tensorflowdistributedlearning_tpu.models.layers import (
@@ -546,6 +547,20 @@ class ResNetClassifier(nn.Module):
         return logits
 
 
+def sample_input(config: ModelConfig, full: bool = False) -> np.ndarray:
+    """Zeros shaped like one example of the model's input: what the trainers
+    initialise on and the planner traces. Images for the image backbones;
+    for the decoder a row of token ids — a few tokens to initialise on
+    (nothing of the parameters depends on the length), the training sequence
+    length with ``full``."""
+    if config.decoder is not None:
+        from tensorflowdistributedlearning_tpu.models.decoder import INIT_TOKENS
+
+        length = config.decoder.sequence_length if full else INIT_TOKENS
+        return np.zeros((1, length), np.int32)
+    return np.zeros((1, *config.input_shape, config.input_channels), np.float32)
+
+
 def build_model(
     config: ModelConfig,
     bn_axis_name: Optional[str] = None,
@@ -581,6 +596,15 @@ def _build_model_cached(
     spatial_axis_name: Optional[str],
     expert_axis_name: Optional[str],
 ) -> nn.Module:
+    if config.backbone == "decoder":
+        if bn_axis_name or spatial_axis_name or expert_axis_name:
+            raise ValueError(
+                "backbone='decoder' takes no mesh axis: its share of a layer is "
+                "in its configuration (config.py:DecoderConfig)"
+            )
+        from tensorflowdistributedlearning_tpu.models.decoder import MoEDecoder
+
+        return MoEDecoder(config)
     if config.backbone == "vit":
         from tensorflowdistributedlearning_tpu.models.vit import ViTClassifier
 

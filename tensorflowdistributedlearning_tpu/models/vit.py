@@ -59,6 +59,10 @@ from tensorflowdistributedlearning_tpu.models.layers import (  # noqa: E402
 # auxiliary tokens (cls, registers) declares them via
 # MultiHeadSelfAttention.num_prefix_tokens so a 1024-patch image does not
 # fall back to XLA one token early (ADVICE round 5).
+# After PR 26 this constant gates the ViT family's kernel only
+# (ops/flash_attention.py); the decoder family's attention
+# (ops/blocked_attention.py, chosen by shapes) has no such ceiling and is
+# not routed here: it wants head sizes that are multiples of 128.
 _FUSED_MAX_SEQ = 1024
 
 

@@ -25,6 +25,16 @@ device's block. The XLA oracle (`attention_reference`) is the numerical
 fallback for shapes that exceed the VMEM budget and the test oracle; off-TPU
 the kernel runs in interpreter mode so CPU CI exercises the identical code
 path.
+
+Which attention path serves which shapes (after PR 26): this kernel serves the
+ViT family — bidirectional or plainly causal attention over one device's
+block, all of K and V in VMEM, up to ``models/vit.py:_FUSED_MAX_SEQ`` patch
+tokens, behind ``use_fused_attention``; longer ViT sequences and the ring take
+``attention_reference``. The decoder family (``models/decoder.py``: grouped
+queries, sliding window, packed documents, 8,192 tokens) takes
+``ops/blocked_attention.py``, which blocks over keys with an online softmax
+in both passes. The ViT path is left as it was: the blocked kernel wants head
+sizes that are multiples of 128, and ViT-S heads are 64 wide.
 """
 
 from __future__ import annotations

@@ -287,15 +287,14 @@ def profile_model(model_config, train_config) -> ModelProfile:
 def _profile_model_cached(model_config, tx) -> ModelProfile:
     import jax
 
-    from tensorflowdistributedlearning_tpu.models import build_model
+    from tensorflowdistributedlearning_tpu.models import build_model, sample_input
     from tensorflowdistributedlearning_tpu.train.state import create_train_state
     from tensorflowdistributedlearning_tpu.utils.params import count_params
 
     model = build_model(model_config)
-    h, w = model_config.input_shape
-    sample = jax.ShapeDtypeStruct(
-        (1, h, w, model_config.input_channels), np.float32
-    )
+    # one example at its training size: an image, or a packed token sequence
+    example = sample_input(model_config, full=True)
+    sample = jax.ShapeDtypeStruct(example.shape, example.dtype)
     state = jax.eval_shape(
         lambda rng, x: create_train_state(model, tx, rng, x),
         jax.ShapeDtypeStruct((2,), np.uint32),

@@ -2,6 +2,7 @@ from tensorflowdistributedlearning_tpu.train.state import TrainState, create_tra
 from tensorflowdistributedlearning_tpu.train.step import (
     ClassificationTask,
     SegmentationTask,
+    SequenceTask,
     make_eval_step,
     make_optimizer,
     make_predict_step,
@@ -14,6 +15,7 @@ __all__ = [
     "create_train_state",
     "ClassificationTask",
     "SegmentationTask",
+    "SequenceTask",
     "make_eval_step",
     "make_optimizer",
     "make_predict_step",

@@ -270,4 +270,6 @@ def fetch_metrics(acc: Any, telemetry=None) -> Dict[str, float]:
         telemetry.registry.counter(EVAL_FETCH_COUNTER).inc()
     from tensorflowdistributedlearning_tpu.train import step as step_lib
 
-    return step_lib.compute_metrics(jax.device_get(acc))
+    # an eval pass reports scalars; a vector-valued stream (the decoder's
+    # per-expert counts) is a train-window field only
+    return step_lib.split_scalars(step_lib.compute_metrics(jax.device_get(acc)))[0]

@@ -31,7 +31,7 @@ from tensorflowdistributedlearning_tpu.config import ModelConfig, TrainConfig
 from tensorflowdistributedlearning_tpu.data import imagefolder
 from tensorflowdistributedlearning_tpu.data import pipeline as pipeline_lib
 from tensorflowdistributedlearning_tpu.data import synthetic as synthetic_lib
-from tensorflowdistributedlearning_tpu.models import build_model
+from tensorflowdistributedlearning_tpu.models import build_model, sample_input
 from tensorflowdistributedlearning_tpu.parallel import mesh as mesh_lib
 from tensorflowdistributedlearning_tpu.parallel import multihost
 from tensorflowdistributedlearning_tpu.resilience import faults as faults_lib
@@ -82,7 +82,12 @@ class FitResult:
 
 
 class ClassifierTrainer:
-    """Streaming classification trainer (one run, no folds).
+    """Streaming trainer (one run, no folds): classification, and next-token
+    prediction for the decoder family (``backbone="decoder"``), which runs the
+    same loop on packed token sequences (data/tokens.py) under
+    ``train.step.SequenceTask``; its batch size counts sequences. What the
+    two differ in beyond the objective — the data, the run header, the window
+    fields — the task answers (``train.step.fit_task``).
 
     ``data_dir`` uses the ImageFolder layout: ``{data_dir}/train/{class}/*.png``
     and optionally ``{data_dir}/val/{class}/*.png`` (eval falls back to the train
@@ -98,11 +103,9 @@ class ClassifierTrainer:
         train_config: Optional[TrainConfig] = None,
         plan: Optional[Dict] = None,
     ):
-        if model_config.num_classes is None:
-            raise ValueError(
-                "fit() trains classification models; model_config.num_classes is None "
-                "(use train.trainer.Trainer for the segmentation task)"
-            )
+        # classification, or next-token prediction for a decoder (refuses a
+        # segmentation configuration, and a layout the task cannot train under)
+        self.task = step_lib.fit_task(model_config, train_config or TrainConfig())
         self.model_dir = model_dir
         self.data_dir = data_dir
         self.model_config = model_config
@@ -128,9 +131,6 @@ class ClassifierTrainer:
                 "global_batch), apply plan.overrides() onto the config, and "
                 "pass plan=plan.header())"
             )
-        self.task = step_lib.ClassificationTask(
-            label_smoothing=self.train_config.label_smoothing
-        )
         tcfg = self.train_config
         self.mesh = mesh_lib.make_mesh(
             tcfg.n_devices,
@@ -394,6 +394,10 @@ class ClassifierTrainer:
                 seed=seed,
                 steps=steps,
             )
+        if self.task.batches is not None:
+            return self._task_batches(
+                local_bs, tcfg.seed + jax.process_index(), steps, start_step
+            )
         train_split = self._open_split("train")
         if train_split is None:
             cfg = self.model_config
@@ -422,6 +426,16 @@ class ClassifierTrainer:
             steps=steps,
             augment=False,
         )
+
+    def _task_batches(self, local_bs: int, seed: int, steps, start_index: int = 0):
+        """The stream of a task that brings its own data (SequenceTask: the
+        packed synthetic token stream)."""
+        if self.data_dir is not None:
+            raise ValueError(
+                f"the {self.task.name} task trains on its own synthetic stream "
+                "(data/tokens.py); it reads no data_dir yet"
+            )
+        return self.task.batches(local_bs, seed, steps, start_index)
 
     # -- training ---------------------------------------------------------
 
@@ -465,7 +479,8 @@ class ClassifierTrainer:
             health=obs_lib.HealthMonitor.from_train_config(tcfg),
             hold_header=True,
             run_info={
-                "task": "classification",
+                "task": self.task.name,
+                **self.task.run_header(),
                 "steps": steps,
                 "global_batch": batch_size,
                 "mesh": {
@@ -658,6 +673,7 @@ class ClassifierTrainer:
         lr_sched = step_lib.make_host_lr_schedule(tcfg)
 
         def emit_window(rec: async_loop.PendingWindow, scalars) -> None:
+            scalars, vectors = step_lib.split_scalars(scalars)
             if tb_train is not None:
                 tb_train.scalars(scalars, rec.step)
             tel.window_event(
@@ -667,6 +683,9 @@ class ClassifierTrainer:
                 scalars=scalars,
                 dirty=rec.dirty,
                 samples=rec.samples,
+                **self.task.window_fields(
+                    rec.steps * batch_size, scalars, vectors, rec.images_per_sec
+                ),
                 # cost accounting (obs/capacity.py): examples THIS PROCESS's
                 # chips handled this window — the meter counts local devices,
                 # so a multi-host run must price the per-process batch share,
@@ -882,6 +901,11 @@ class ClassifierTrainer:
         # otherwise be all-gathered into the eval executable for nothing
         state = step_lib.with_ema_params(state).replace(opt_state=None)
         local_bs = multihost.per_process_batch_size(batch_size)
+        if self.task.batches is not None:
+            # held-out batches of the task's own stream: another seed
+            return self._eval_pass(
+                state, self._task_batches(local_bs, tcfg.seed + 1, steps=2), step_no
+            )
         val_folder = self._open_split("val")
         eval_records = self._open_records("val")
         if eval_records is None and val_folder is None:
@@ -1021,7 +1045,7 @@ class ClassifierTrainer:
             self._plain_model,
             step_lib.make_optimizer(tcfg),
             jax.random.PRNGKey(tcfg.seed),
-            np.zeros((1, *cfg.input_shape, cfg.input_channels), np.float32),
+            sample_input(cfg),
         )
 
     def _restore_best_host(self) -> TrainState:
@@ -1052,6 +1076,11 @@ class ClassifierTrainer:
         quantized-compute kernels): float32 wire contract either way,
         quantized constants inside; the closure carries its manifest section
         as ``serve.quantization``."""
+        if not hasattr(self.task, "predictions"):
+            raise NotImplementedError(
+                f"the {self.task.name} task trains only: serving a step that "
+                "yields a token (a cache, a sampler) is not built yet"
+            )
         from tensorflowdistributedlearning_tpu.ops import quant_kernels
         from tensorflowdistributedlearning_tpu.train import quantize
         from tensorflowdistributedlearning_tpu.train.trainer import _forward_cached
@@ -1196,7 +1225,7 @@ def fit_preset(
     from tensorflowdistributedlearning_tpu.configs import get_preset
 
     preset = get_preset(preset_name)
-    if preset.model.num_classes is None:
+    if preset.model.num_classes is None and preset.model.decoder is None:
         raise ValueError(
             f"Preset {preset_name!r} is a segmentation config; use the `train` "
             "command (K-fold Trainer) for it"

@@ -26,10 +26,16 @@ from typing import Any, Callable, Dict, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 import optax
 from jax.sharding import Mesh, PartitionSpec as P
 
-from tensorflowdistributedlearning_tpu.config import ModelConfig, TrainConfig
+from tensorflowdistributedlearning_tpu.config import (
+    DecoderConfig,
+    ModelConfig,
+    TokenStreamConfig,
+    TrainConfig,
+)
 from tensorflowdistributedlearning_tpu.ops import losses as losses_lib
 from tensorflowdistributedlearning_tpu.ops import metrics as metrics_lib
 from tensorflowdistributedlearning_tpu.parallel.mesh import BATCH_AXIS, SEQUENCE_AXIS
@@ -110,7 +116,9 @@ def make_host_lr_schedule(cfg: TrainConfig) -> Callable[[int], float]:
 # weight-matrix leaf names: flax conv/dense "kernel", plus the MoE FFN's
 # explicitly-declared expert matrices and router (models/vit.py:MoEMlp) —
 # the direct replacements for the dense mlp kernels they stand in for
-_DECAYED_LEAF_NAMES = frozenset({"kernel", "w_in", "w_out", "router"})
+_DECAYED_LEAF_NAMES = frozenset(
+    {"kernel", "w_in", "w_out", "router", "w_gate", "w_up", "w_down"}
+)
 
 
 def kernel_decay_mask(params: Any) -> Any:
@@ -326,6 +334,17 @@ class ClassificationTask:
 
     label_smoothing: float = 0.0
 
+    name = "classification"
+    # the trainer's loaders feed it; a task with a stream of its own
+    # (SequenceTask) defines batches()
+    batches = None
+
+    def run_header(self) -> Dict[str, Any]:
+        return {}
+
+    def window_fields(self, examples: int, scalars, vectors, images_per_sec) -> Dict[str, Any]:
+        return {}
+
     def loss(self, logits: jax.Array, batch: Dict[str, jax.Array]) -> jax.Array:
         if "lam" in batch:
             # mixup/cutmix pairing (data/augment.py:mixup_batch/cutmix_batch):
@@ -369,6 +388,151 @@ class ClassificationTask:
         return {"probabilities": probs, "class": jnp.argmax(logits, axis=-1)}
 
 
+@dataclasses.dataclass(frozen=True)
+class SequenceTask:
+    """Next-token prediction over packed documents (``backbone="decoder"``,
+    models/decoder.py). The model takes the whole token batch (tokens,
+    segment ids, positions, targets: data/tokens.py) and computes the
+    cross-entropy itself, in token chunks, so the task reads sums: the loss
+    is their quotient, and the step's metrics are counted in tokens.
+
+    What differs from the image tasks beyond the objective is the task's too
+    (train/fit.py asks it): its data (``batches``), what it adds to the run
+    header and to each window event, and the layouts it can train under."""
+
+    decoder: DecoderConfig
+    stream: TokenStreamConfig
+
+    name = "next_token"
+    # the model runs library Pallas kernels (jax's splash attention and
+    # megablox) whose out_shapes carry no vma: pallas_call refuses them inside
+    # a shard_map that checks varying manual axes, so this task's steps are
+    # built with check_vma=False and reduce their gradients explicitly
+    check_vma = False
+
+    def check_train_config(self, tcfg: TrainConfig) -> None:
+        if max(tcfg.model_parallel, tcfg.pipeline_parallel, tcfg.expert_parallel,
+               tcfg.sequence_parallel) > 1:
+            raise ValueError(
+                "the decoder trains data-parallel only: its share of a layer is "
+                "in its configuration (DecoderConfig.share_count), and the "
+                "exchange between shares is not built yet"
+            )
+        if tcfg.augmentation != "none":
+            raise ValueError(
+                "token batches are fed as packed: TrainConfig.augmentation must "
+                f"be 'none' for the decoder, not {tcfg.augmentation!r}"
+            )
+
+    def run_header(self) -> Dict[str, Any]:
+        """The run header's ``decoder`` block: the share of a layer this chip
+        holds and the pattern of the layers kept."""
+        cfg = self.decoder
+        return {
+            "decoder": {
+                "share": [cfg.share_index, cfg.share_count],
+                "layer_types": list(cfg.layer_types[: cfg.num_hidden_layers]),
+                "sequence_length": cfg.sequence_length,
+            }
+        }
+
+    def batches(self, batch_size: int, seed: int, steps=None, start_index: int = 0):
+        """The packed synthetic token stream (index-keyed, so a resumed run
+        replays it)."""
+        from tensorflowdistributedlearning_tpu.data import tokens as tokens_lib
+
+        cfg = self.decoder
+        return tokens_lib.packed_token_batches(
+            batch_size, cfg.sequence_length, cfg.vocab_size, self.stream,
+            seed=seed, steps=steps, start_index=start_index,
+        )
+
+    def window_fields(self, sequences: int, scalars, vectors, images_per_sec) -> Dict[str, Any]:
+        """The decoder's counters of one log window of ``sequences`` sequences,
+        from the step's own metrics (fetched with the loss, no extra sync):
+        docs/LEDGER_SCHEMA.md "The decoder's window fields"."""
+        per_expert = np.asarray(vectors["moe/expert_tokens"]) * sequences  # [layers, held]
+        fields = {
+            "tokens": int(round(scalars["tokens"] * sequences)),
+            "moe_pairs": int(round(float(per_expert.sum()))),
+            "moe_pairs_dropped": int(round(scalars["moe/pairs_dropped"] * sequences)),
+            # over the held experts, the worst layer of the window
+            "moe_load_max_over_mean": round(float(
+                (per_expert.max(axis=1) / np.maximum(per_expert.mean(axis=1), 1e-9)).max()
+            ), 4),
+            "moe_expert_tokens": [[int(round(x)) for x in row] for row in per_expert],
+            "attn_keys_per_query": {
+                "full_attention": round(scalars["attn/keys_per_query_full"], 2),
+                "sliding_attention": round(scalars["attn/keys_per_query_sliding"], 2),
+            },
+        }
+        if images_per_sec is not None:
+            fields["tokens_per_sec"] = round(images_per_sec * self.decoder.sequence_length, 2)
+        return fields
+
+    def model_input(self, batch: Dict[str, jax.Array]) -> Dict[str, jax.Array]:
+        return batch
+
+    def loss(self, outputs: Dict[str, jax.Array], batch) -> jax.Array:
+        # the mean over the GLOBAL batch's target positions: the shards'
+        # gradients are averaged afterwards, so each divides by the mean count
+        n = jax.lax.pmean(outputs["n_targets"], BATCH_AXIS)
+        return outputs["loss_sum"] / jnp.maximum(n, 1.0)
+
+    def metric_deltas(self, outputs: Dict[str, jax.Array], batch) -> Metrics:
+        """Mean states whose totals are the step's counters. Per target
+        position: ``loss``, ``metrics/top1``. Per sequence: ``tokens`` (target
+        positions), ``moe/expert_tokens`` ([layers, experts held]: tokens
+        routed to each), ``moe/pairs_dropped``. Per position:
+        ``attn/keys_per_query_*`` by layer type."""
+        mean = metrics_lib.Mean
+        targets, rows = outputs["n_targets"], outputs["n_sequences"]
+        return {
+            "loss": mean(outputs["loss_sum"], targets),
+            "metrics/top1": mean(outputs["n_correct"], targets),
+            "tokens": mean(targets, rows),
+            "moe/expert_tokens": mean(outputs["expert_tokens"], rows),
+            "moe/pairs_dropped": mean(outputs["pairs_dropped"], rows),
+            "attn/keys_per_query_full": mean(outputs["attn_keys_full"], outputs["n_positions"]),
+            "attn/keys_per_query_sliding": mean(
+                outputs["attn_keys_sliding"], outputs["n_positions"]
+            ),
+        }
+
+
+def fit_task(model_config, train_config: TrainConfig):
+    """The task ``fit`` trains a configuration under: what the configuration
+    carries decides (a decoder's own block, or a class count)."""
+    if model_config.decoder is not None:
+        task = SequenceTask(
+            model_config.decoder, train_config.token_stream or TokenStreamConfig()
+        )
+        task.check_train_config(train_config)
+        return task
+    if model_config.num_classes is None:
+        raise ValueError(
+            "fit() trains classification models; model_config.num_classes is None "
+            "(use train.trainer.Trainer for the segmentation task)"
+        )
+    return ClassificationTask(label_smoothing=train_config.label_smoothing)
+
+
+def _model_input(task, batch: Dict[str, jax.Array]):
+    """What the model's forward takes of a batch: the images, or what a task
+    that feeds its model otherwise (SequenceTask) names."""
+    pick = getattr(task, "model_input", None)
+    return batch["images"] if pick is None else pick(batch)
+
+
+def _task_deltas(task, outputs, batch, loss, weights=None) -> Metrics:
+    """The step's metric contributions: a task's own (``metric_deltas``), or
+    the per-example scores and the loss as Mean states."""
+    own = getattr(task, "metric_deltas", None)
+    if own is not None:
+        return own(outputs, batch)
+    return _metric_deltas(task.metric_scores(outputs, batch), loss, weights)
+
+
 def _l2_penalty(params: Any) -> jax.Array:
     """slim-style l2: scale * sum(w^2)/2 over conv/dense kernels only (reference:
     core/resnet.py:376 attached l2_regularizer to conv weights — though the reference
@@ -399,9 +563,16 @@ def _metric_deltas(
     return out
 
 
-def _mean_grads(grads: Any) -> Any:
+def _checks_vma(task) -> bool:
+    """Whether a task's steps run under shard_map's varying-manual-axes
+    check (every task does that does not say otherwise: SequenceTask)."""
+    return getattr(task, "check_vma", True)
+
+
+def _mean_grads(grads: Any, checked: bool = True) -> Any:
     """Average gradients across the batch (and sequence) mesh axes, leaf-by-leaf
-    vma-aware.
+    vma-aware. With the check off (``checked=False``) nothing was summed for
+    us and every leaf is per-shard: a plain mean over both axes.
 
     Inside ``shard_map`` with varying-manual-axes checking, the gradient of a
     REPLICATED (unvarying) parameter is already psum'd by the automatic
@@ -413,6 +584,9 @@ def _mean_grads(grads: Any) -> Any:
     non-spatial meshes) makes it a no-op.
     """
     from tensorflowdistributedlearning_tpu.parallel.collectives import vma_of
+
+    if not checked:
+        return jax.lax.pmean(grads, (BATCH_AXIS, SEQUENCE_AXIS))
 
     def mean_leaf(g):
         vma = vma_of(g)
@@ -468,8 +642,25 @@ def _merge_stacked_metrics(stacked: Metrics) -> Metrics:
     return jax.tree.map(lambda x: jnp.sum(x, axis=0), stacked)
 
 
-def compute_metrics(acc: Metrics) -> Dict[str, float]:
-    return {k: float(v.compute()) for k, v in acc.items()}
+def compute_metrics(acc: Metrics) -> Dict[str, Any]:
+    """Each stream's mean: a float, or a nested list where the stream is of
+    vectors (``moe/expert_tokens``) — ``split_scalars`` parts the two. Worked
+    out in numpy on the host (device arrays are fetched first): a window's
+    emission dispatches nothing and compiles nothing, so it neither queues
+    behind the steps in flight nor puts a compile into a run's first window."""
+    out = {}
+    for k, v in acc.items():
+        total, count = np.asarray(v.total, np.float32), np.asarray(v.count, np.float32)
+        value = total / np.maximum(count, np.float32(1.0))
+        out[k] = float(value) if value.ndim == 0 else value.tolist()
+    return out
+
+
+def split_scalars(computed: Dict[str, Any]) -> Tuple[Dict[str, float], Dict[str, list]]:
+    """(the float-valued metrics, the vector-valued ones): scalar sinks
+    (TensorBoard, the ledger's ``scalars``) take the first only."""
+    scalars = {k: v for k, v in computed.items() if not isinstance(v, list)}
+    return scalars, {k: v for k, v in computed.items() if isinstance(v, list)}
 
 
 def _batch_in_specs(spatial: bool, keys: Tuple[str, ...]):
@@ -600,7 +791,7 @@ def _make_train_step_cached(
             def loss_fn(params):
                 outputs, mutated = state.apply_fn(
                     {"params": params, "batch_stats": batch_stats},
-                    chunk["images"],
+                    _model_input(task, chunk),
                     train=True,
                     mutable=["batch_stats", "aux_loss"],
                     rngs={"dropout": jax.random.fold_in(dropout_rng, chunk_idx)},
@@ -623,9 +814,9 @@ def _make_train_step_cached(
             (loss, (outputs, new_batch_stats)), grads = grads_of(
                 state.batch_stats, batch, 0
             )
-            metrics = _metric_deltas(task.metric_scores(outputs, batch), loss)
+            metrics = _task_deltas(task, outputs, batch, loss)
         else:
-            local = batch["images"].shape[0]
+            local = jax.tree.leaves(batch)[0].shape[0]
             if local % accum:
                 raise ValueError(
                     f"grad accumulation needs the per-shard batch ({local}) "
@@ -652,7 +843,7 @@ def _make_train_step_cached(
                 grads_acc = jax.tree.map(
                     lambda a, g: a + g / accum, grads_acc, grads
                 )
-                deltas = _metric_deltas(task.metric_scores(outputs, chunk), loss)
+                deltas = _task_deltas(task, outputs, chunk, loss)
                 return (new_stats, grads_acc), deltas
 
             # unfreeze so the carry's pytree TYPE matches what flax's mutable
@@ -678,7 +869,7 @@ def _make_train_step_cached(
         # grads; _mean_grads turns that into the global mean — and still works
         # if a grad leaf arrives per-shard (varying), where an explicit pmean is
         # the right reduction.
-        grads = _mean_grads(grads)
+        grads = _mean_grads(grads, _checks_vma(task))
         # per-shard (per-tower) BN stats, averaged to keep state replicated (the
         # sequence pmean is an identity when BN already syncs over that axis, and
         # required either way so the stored stats leave the shard_map unvarying)
@@ -701,7 +892,7 @@ def _make_train_step_cached(
             mesh=mesh,
             in_specs=(P(), batch_specs),
             out_specs=(P(), P()),
-            **_hybrid_kwargs(auto_model),
+            **_hybrid_kwargs(auto_model, task),
         )
         return jax.jit(sharded, donate_argnums=(0,) if donate else ())
 
@@ -718,7 +909,7 @@ def _make_train_step_cached(
         mesh=mesh,
         in_specs=(P(), batch_specs),
         out_specs=(P(), P(), P()),
-        **_hybrid_kwargs(auto_model),
+        **_hybrid_kwargs(auto_model, task),
     )
 
     def zero_step(state: TrainState, batch: Dict[str, jax.Array]):
@@ -820,12 +1011,14 @@ def make_eval_step(
     return _make_eval_step_cached(mesh, task, spatial, with_valid, auto_model)
 
 
-def _hybrid_kwargs(auto_model: bool) -> dict:
+def _hybrid_kwargs(auto_model: bool, task=None) -> dict:
     """shard_map kwargs for hybrid mode: (batch, sequence) manual, model auto
-    (see make_train_step's ``auto_model``)."""
-    if not auto_model:
-        return {}
-    return {"axis_names": frozenset({BATCH_AXIS, SEQUENCE_AXIS})}
+    (see make_train_step's ``auto_model``); and the vma check off for a task
+    that asks (``_checks_vma``)."""
+    kwargs = {} if task is None or _checks_vma(task) else {"check_vma": False}
+    if auto_model:
+        kwargs["axis_names"] = frozenset({BATCH_AXIS, SEQUENCE_AXIS})
+    return kwargs
 
 
 @functools.lru_cache(maxsize=None)
@@ -835,9 +1028,11 @@ def _make_eval_step_cached(
     def step(state: TrainState, batch: Dict[str, jax.Array]) -> Metrics:
         outputs = state.apply_fn(
             {"params": state.params, "batch_stats": state.batch_stats},
-            batch["images"],
+            _model_input(task, batch),
             train=False,
         )
+        if hasattr(task, "metric_deltas"):
+            return _psum_metrics(task.metric_deltas(outputs, batch))
         # per-example losses so the optional batch["valid"] mask (wrap-around padding
         # of the final eval batch — data/pipeline.py eval_batches) weights correctly
         loss = task.loss_per_example(outputs, batch)
@@ -852,7 +1047,7 @@ def _make_eval_step_cached(
         mesh=mesh,
         in_specs=(P(), _batch_in_specs(spatial, keys)),
         out_specs=P(),
-        **_hybrid_kwargs(auto_model),
+        **_hybrid_kwargs(auto_model, task),
     )
     return jax.jit(sharded)
 

@@ -1,0 +1,379 @@
+"""The decoder family (``backbone="decoder"``) at a tiny size on the CPU:
+program against the plain reference for every share and the uncut model, the
+share test, the attention kernel in interpret mode against the masked
+reference, no token dropped under any imbalance, the rotary constants against
+hand-computed values, the packer, and ``fit`` end to end."""
+
+import json
+import math
+import os
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from perfbench import weights
+from perfbench.reference import mellum_decoder as reference
+from perfbench.tests import tiny_lm
+from tensorflowdistributedlearning_tpu.config import DecoderConfig, ModelConfig, TrainConfig
+from tensorflowdistributedlearning_tpu.data import tokens as tokens_lib
+from tensorflowdistributedlearning_tpu.models import build_model, decoder as decoder_lib
+from tensorflowdistributedlearning_tpu.ops import blocked_attention as attn_lib
+from tensorflowdistributedlearning_tpu.parallel import expert as expert_lib
+
+STREAM = tokens_lib.TokenStreamConfig(**tiny_lm.TINY_STREAM)
+# the uncut tiny model: 8 heads on 4 key-value heads, 8 experts, 256 ids
+FULL = dict(num_attention_heads=8, num_key_value_heads=4, num_experts=8, vocab_size=256)
+
+
+def _cfg(n: int, s: int) -> dict:
+    return tiny_lm.tiny_config(n, s, **{k: v // n for k, v in FULL.items()})
+
+
+def _model_config(cfg: dict) -> ModelConfig:
+    decoder = DecoderConfig.from_published(
+        cfg, share_count=cfg["share"]["n"], share_index=cfg["share"]["s"],
+        sequence_length=cfg["sequence_length"])
+    return ModelConfig(backbone="decoder", dtype="float32", decoder=decoder)
+
+
+@pytest.fixture(scope="module")
+def full_weights():
+    cfg = _cfg(1, 0)
+    spec = reference.param_spec(cfg)
+    key = jax.random.key(0)
+    return {
+        name: (1.0 if len(shape) == 1 else 0.0) + 0.2 * jax.random.normal(
+            jax.random.fold_in(key, i), shape)
+        for i, (name, (shape, _)) in enumerate(sorted(spec.items()))
+    }
+
+
+def _batch(vocab: int, seed: int = 3):
+    return {k: jnp.asarray(v) for k, v in next(tokens_lib.packed_token_batches(
+        2, 64, vocab, STREAM, seed=seed)).items()}
+
+
+@pytest.mark.parametrize("n,s", [(4, 0), (4, 1), (4, 2), (4, 3), (1, 0)])
+def test_program_matches_reference(full_weights, n, s):
+    """Loss, routed counts and every gradient leaf, for the four shares and
+    the uncut model, on seeded weights."""
+    cfg = _cfg(n, s)
+    flat = reference.share_of(full_weights, _cfg(1, 0), n, s)
+    model = build_model(_model_config(cfg))
+    batch = _batch(cfg["vocab_size"])
+    template = model.init(jax.random.key(1), np.zeros((1, 8), np.int32))["params"]
+    params = weights.unflatten_like(template, flat)
+
+    def loss(p):
+        out = model.apply({"params": p}, batch, train=True)
+        return out["loss_sum"] / out["n_targets"], out
+
+    (got, out), grads = jax.jit(jax.value_and_grad(loss, has_aux=True))(params)
+    want, want_grads, want_counts = jax.jit(
+        lambda p: reference.batch_loss_and_grad(cfg, p, batch))(flat)
+    assert float(got) == pytest.approx(float(want), rel=1e-5)
+    np.testing.assert_array_equal(np.asarray(out["expert_tokens"]), np.asarray(want_counts))
+    assert float(out["pairs_dropped"]) == 0.0
+    flat_grads = weights.flatten(grads)
+    assert set(flat_grads) == set(want_grads)
+    for name, g in want_grads.items():
+        gap = float(jnp.linalg.norm(flat_grads[name] - g) / (jnp.linalg.norm(g) + 1e-30))
+        assert gap < 1e-4, (name, gap)
+    logits, _ = reference.sequence_logits(cfg, flat, batch["tokens"][0], batch["segment_ids"][0],
+                                          batch["positions"][0])
+    hidden = model.apply({"params": params}, {k: v[:1] for k, v in batch.items() if k != "targets"})
+    got_logits = hidden["hidden"][0] @ flat["head/kernel"]
+    np.testing.assert_allclose(np.asarray(got_logits), np.asarray(logits), atol=2e-4)
+
+
+def test_shares_add_up_to_the_uncut_layer(full_weights):
+    """The share test: the four shares' attention and expert partial sums add
+    up to what the uncut reference gives for the whole layer, for a sliding
+    and for a full layer."""
+    cfg1 = _cfg(1, 0)
+    batch = _batch(64)
+    seg, pos = batch["segment_ids"][0], batch["positions"][0]
+    u = jax.random.normal(jax.random.key(5), (64, cfg1["hidden_size"]))
+    for layer in (0, 3):
+        whole_attn = reference.attention_part(cfg1, full_weights, layer, u, seg, pos)
+        whole_moe = reference.moe_part(cfg1, full_weights, layer, u)
+        attn = moe = 0.0
+        for s in range(4):
+            cfg = _cfg(4, s)
+            flat = reference.share_of(full_weights, cfg1, 4, s)
+            attn = attn + reference.attention_part(cfg, flat, layer, u, seg, pos)
+            moe = moe + reference.moe_part(cfg, flat, layer, u)
+            # the program's layer gives the same parts
+            dcfg = _model_config(cfg).decoder
+            got = decoder_lib.DecoderMoE(dcfg, jnp.float32).apply(
+                {"params": {k.split("/")[-1]: v for k, v in flat.items()
+                            if k.startswith(f"layers_{layer}/moe/")}}, u[None])[0][0]
+            np.testing.assert_allclose(
+                np.asarray(got), np.asarray(reference.moe_part(cfg, flat, layer, u)), atol=1e-4)
+        np.testing.assert_allclose(np.asarray(attn), np.asarray(whole_attn), atol=1e-4)
+        np.testing.assert_allclose(np.asarray(moe), np.asarray(whole_moe), atol=1e-4)
+
+
+@pytest.mark.parametrize("window", [None, 64])
+@pytest.mark.parametrize("documents", [False, True])
+@pytest.mark.parametrize("group", [1, 4])
+def test_attention_kernel_against_masked_reference(window, documents, group):
+    """The Pallas kernel (interpret mode) against the XLA path, forward and
+    gradient, over window x documents x grouped heads (causal throughout)."""
+    key = jax.random.key(2)
+    b, t, hkv, hd = 2, 256, 2, 128
+    q = jax.random.normal(jax.random.fold_in(key, 1), (b, t, hkv * group, hd))
+    k = jax.random.normal(jax.random.fold_in(key, 2), (b, t, hkv, hd))
+    v = jax.random.normal(jax.random.fold_in(key, 3), (b, t, hkv, hd))
+    seg = np.zeros((b, t), np.int32)
+    if documents:
+        seg[0, 100:] = 1
+        seg[1, 30:200] = 1
+        seg[1, 200:] = 2
+    seg = jnp.asarray(seg)
+
+    def through(fn, **kw):
+        return lambda q, k, v: jnp.sum(jnp.sin(fn(q, k, v, seg, window=window, **kw)))
+
+    want = attn_lib.masked_attention_reference(q, k, v, seg, window=window)
+    got = attn_lib.splash_attention(q, k, v, seg, window=window, interpret=True)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=2e-5)
+    g_want = jax.grad(through(attn_lib.masked_attention_reference), (0, 1, 2))(q, k, v)
+    g_got = jax.grad(through(attn_lib.splash_attention, interpret=True), (0, 1, 2))(q, k, v)
+    for a, w in zip(g_got, g_want):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(w), atol=1e-4)
+
+
+def test_masked_reference_is_the_explicit_mask():
+    """The XLA path against scores written out whole with the mask spelt out."""
+    key = jax.random.key(4)
+    b, t, hq, hkv, hd, window = 1, 32, 4, 2, 16, 8
+    q = jax.random.normal(jax.random.fold_in(key, 1), (b, t, hq, hd))
+    k = jax.random.normal(jax.random.fold_in(key, 2), (b, t, hkv, hd))
+    v = jax.random.normal(jax.random.fold_in(key, 3), (b, t, hkv, hd))
+    seg = jnp.asarray(np.repeat([[0, 1]], 16, axis=1).reshape(1, 32))
+    i, j = np.arange(t)[:, None], np.arange(t)[None, :]
+    seen = (j <= i) & (i - j < window) & (np.asarray(seg[0])[:, None] == np.asarray(seg[0])[None, :])
+    kk, vv = jnp.repeat(k, 2, axis=2), jnp.repeat(v, 2, axis=2)
+    scores = jnp.einsum("bqhd,bkhd->bhqk", q, kk) / math.sqrt(hd)
+    probs = jax.nn.softmax(jnp.where(seen[None, None], scores, -jnp.inf), axis=-1)
+    want = jnp.einsum("bhqk,bkhd->bqhd", probs, vv)
+    got = attn_lib.masked_attention_reference(q, k, v, seg, window=window)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=1e-5)
+
+
+@pytest.mark.parametrize("case", ["all_to_one_held", "none_held", "even"])
+def test_no_token_is_dropped_under_imbalance(case):
+    t, d, f, held, total, k = 48, 16, 8, 4, 8, 2
+    key = jax.random.key(7)
+    x = jax.random.normal(key, (t, d))
+    w_gate = jax.random.normal(jax.random.fold_in(key, 1), (held, d, f))
+    w_up = jax.random.normal(jax.random.fold_in(key, 2), (held, d, f))
+    w_down = jax.random.normal(jax.random.fold_in(key, 3), (held, f, d))
+    first = 4  # the second share of two holds experts 4..7
+    if case == "all_to_one_held":
+        experts = jnp.tile(jnp.asarray([[5, 0]], jnp.int32), (t, 1))
+    elif case == "none_held":
+        experts = jnp.tile(jnp.asarray([[0, 3]], jnp.int32), (t, 1))
+    else:
+        experts = jnp.stack([jnp.arange(t) % total, (jnp.arange(t) + 3) % total], 1).astype(jnp.int32)
+    weights_ = jnp.full((t, k), 0.5)
+    out, counts, dropped = expert_lib.dropless_experts(
+        x, weights_, experts, w_gate, w_up, w_down, num_experts_total=total, first_expert=first)
+    want = jnp.zeros((t, d))
+    for e in range(held):
+        y = (jax.nn.silu(x @ w_gate[e]) * (x @ w_up[e])) @ w_down[e]
+        want = want + y * jnp.sum(jnp.where(experts == first + e, weights_, 0.0), 1)[:, None]
+    np.testing.assert_allclose(np.asarray(out), np.asarray(want), atol=1e-4)
+    assert int(dropped) == 0
+    expected = {"all_to_one_held": [0, t, 0, 0], "none_held": [0, 0, 0, 0]}.get(case)
+    if expected is not None:
+        assert np.asarray(counts).tolist() == expected
+    assert int(counts.sum()) == int(((experts >= first) & (experts < first + held)).sum())
+
+
+def test_the_drop_counter_reads_what_the_products_wrote(monkeypatch):
+    """The count is not the routing's algebra: rows of a held expert that the
+    grouped product passes over are counted."""
+    t, d, f, held = 32, 16, 8, 4
+    key = jax.random.key(11)
+    x = jax.random.normal(key, (t, d))
+    mats = [jax.random.normal(jax.random.fold_in(key, i), shape)
+            for i, shape in enumerate([(held, d, f), (held, d, f), (held, f, d)])]
+    experts = jnp.tile(jnp.asarray([[5, 7]], jnp.int32), (t, 1))  # every pair is held here
+    real = expert_lib.grouped_matmul
+    # a product that leaves the sorted buffer's last 7 rows (expert 7's) undone
+    monkeypatch.setattr(expert_lib, "grouped_matmul",
+                        lambda *a, **k: real(*a, **k).at[-7:].set(0.0))
+    _, counts, dropped = expert_lib.dropless_experts(
+        x, jnp.full((t, 2), 0.5), experts, *mats, num_experts_total=8, first_expert=4)
+    assert np.asarray(counts).tolist() == [0, t, 0, t] and int(dropped) == 7
+
+
+def test_window_means_are_worked_out_on_the_host(monkeypatch):
+    """compute_metrics touches no jax.numpy: a window's emission dispatches
+    and compiles nothing, whatever the first window of a run is."""
+    from tensorflowdistributedlearning_tpu.ops.metrics import Mean
+    from tensorflowdistributedlearning_tpu.train import step as step_lib
+
+    acc = {"loss": Mean(jnp.asarray(6.0), jnp.asarray(4.0)),
+           "empty": Mean(np.float32(0.0), np.float32(0.0)),
+           "moe/expert_tokens": Mean(jnp.asarray([[2.0, 4.0]]), jnp.asarray(2.0))}
+    monkeypatch.setattr(step_lib, "jnp", None)
+    got = step_lib.compute_metrics(acc)
+    assert got == {"loss": 1.5, "empty": 0.0, "moe/expert_tokens": [[1.0, 2.0]]}
+    assert step_lib.split_scalars(got) == (
+        {"loss": 1.5, "empty": 0.0}, {"moe/expert_tokens": [[1.0, 2.0]]})
+
+
+def test_top_k_routing_renormalises_over_the_chosen():
+    logits = jax.random.normal(jax.random.key(1), (10, 8))
+    w, e = expert_lib.top_k_routing(logits, 3)
+    probs = np.asarray(jax.nn.softmax(logits, axis=-1))
+    np.testing.assert_allclose(np.asarray(w).sum(1), 1.0, rtol=1e-6)
+    for row in range(10):
+        top = np.argsort(-probs[row])[:3]
+        assert set(top) == set(np.asarray(e[row]).tolist())
+        np.testing.assert_allclose(
+            np.sort(np.asarray(w[row])), np.sort(probs[row, top] / probs[row, top].sum()), rtol=1e-5)
+    w_raw, _ = expert_lib.top_k_routing(logits, 3, renormalise=False)
+    assert np.all(np.asarray(w_raw).sum(1) < 1.0)
+
+
+def test_yarn_constants_against_hand_values():
+    """head_dim 128, theta 500,000, factor 16, original 8,192, beta 32 / 1."""
+    cfg = DecoderConfig()
+    plain, one = decoder_lib.rope_constants(cfg, "sliding_attention")
+    assert one == 1.0
+    np.testing.assert_allclose(plain, 500000.0 ** (-np.arange(64) / 64.0), rtol=1e-6)
+    yarn, factor = decoder_lib.rope_constants(cfg, "full_attention")
+    assert factor == 1.2772588722239782 == pytest.approx(0.1 * math.log(16) + 1)
+    # low = floor(64 ln(8192 / (32 * 2 pi)) / ln theta) = 18, high = ceil(64 ln(8192 / (2 pi)) / ln theta) = 35
+    assert math.floor(64 * math.log(8192 / (32 * 2 * math.pi)) / math.log(5e5)) == 18
+    assert math.ceil(64 * math.log(8192 / (2 * math.pi)) / math.log(5e5)) == 35
+    np.testing.assert_allclose(yarn[:19], plain[:19], rtol=1e-6)  # fast dimensions kept
+    np.testing.assert_allclose(yarn[35:], plain[35:] / 16.0, rtol=1e-6)  # slow ones interpolated
+    i = 26  # halfway up the ramp: (26 - 18) / 17 interpolated
+    r = (i - 18) / 17.0
+    assert yarn[i] == pytest.approx(plain[i] / 16 * r + plain[i] * (1 - r), rel=1e-6)
+    ref_inv, ref_factor = reference.rope_parameters(tiny_lm.tiny_config(), "full_attention")
+    assert ref_factor == factor
+    small = DecoderConfig(head_dim=16)
+    np.testing.assert_array_equal(
+        np.asarray(ref_inv), decoder_lib.rope_constants(small, "full_attention")[0])
+
+
+def test_packer():
+    stream = tokens_lib.TokenStreamConfig(median_length=40.0, min_length=8, max_length=100)
+    a = list(tokens_lib.packed_token_batches(3, 256, 500, stream, seed=9, steps=4))
+    b = list(tokens_lib.packed_token_batches(3, 256, 500, stream, seed=9, steps=2, start_index=2))
+    for x, y in zip(a[2:], b):  # same seed, same stream; resumable by index
+        for key in x:
+            np.testing.assert_array_equal(x[key], y[key])
+    other = next(tokens_lib.packed_token_batches(3, 256, 500, stream, seed=10))
+    assert not np.array_equal(other["tokens"], a[0]["tokens"])
+    for batch in a:
+        assert {k: v.shape for k, v in batch.items()} == {
+            k: (3, 256) for k in ("tokens", "segment_ids", "positions", "targets")}
+        assert batch["tokens"].min() >= 0 and batch["tokens"].max() < 500
+        for tok, seg, pos, tgt in zip(*(batch[k] for k in
+                                        ("tokens", "segment_ids", "positions", "targets"))):
+            assert seg[0] == 0 and np.all(np.diff(seg) >= 0) and np.all(np.diff(seg) <= 1)
+            lengths = np.bincount(seg)
+            assert np.all(lengths[:-1] >= 8) and np.all(lengths <= 100)  # the last is cut
+            starts = np.flatnonzero(np.diff(seg, prepend=-1))
+            assert np.all(pos[starts] == 0)  # positions restart
+            assert np.all(np.diff(pos)[np.diff(seg) == 0] == 1)
+            last = np.append(np.diff(seg) == 1, True)  # a document's last position
+            assert np.all(tgt[last] == tokens_lib.NO_TARGET)  # never across a boundary
+            assert np.all(tgt[:-1][~last[:-1]] == tok[1:][~last[:-1]])
+    counts = np.bincount(np.concatenate([x["tokens"].ravel() for x in a]), minlength=500)
+    assert counts[0] > counts[10] > counts[200]  # Zipf: id 0 the commonest
+
+
+def _tiny_trainer(model_dir, steps_cfg=None):
+    from tensorflowdistributedlearning_tpu.train.fit import ClassifierTrainer
+
+    cfg = tiny_lm.tiny_config()
+    trainer = ClassifierTrainer(
+        str(model_dir), None, _model_config(cfg),
+        TrainConfig(optimizer="adam", lr=3e-3, weight_decay=0.1, augmentation="none",
+                    train_log_every_steps=5, checkpoint_every_steps=25, n_devices=1,
+                    token_stream=STREAM))
+    return trainer
+
+
+def _windows(model_dir):
+    with open(os.path.join(model_dir, "telemetry.jsonl"), encoding="utf-8") as f:
+        events = [json.loads(line) for line in f if line.strip()]
+    return events, [e for e in events if e.get("event") == "step_window"]
+
+
+def test_fit_trains_the_decoder_and_resumes(tmp_path):
+    """Through ClassifierTrainer.fit with make_train_step: the loss falls
+    over 50 steps, the windows count tokens and routed pairs with none
+    dropped, and a save/restore round-trips the state."""
+    result = _tiny_trainer(tmp_path).fit(batch_size=4, steps=50)
+    assert result.steps == 50 and np.isfinite(result.final_metrics["loss"])
+    events, windows = _windows(tmp_path)
+    losses = [w["scalars"]["loss"] for w in windows]
+    # untrained, the logits are even: ln(128) = 4.85; the ids are Zipf
+    assert len(losses) == 10 and losses[0] > 4.3, losses
+    assert np.mean(losses[-3:]) < losses[0] - 0.3 and np.mean(losses[-3:]) < 4.2, losses
+    header = next(e for e in events if e.get("event") == "run_header")
+    assert header["task"] == "next_token"
+    assert header["decoder"] == {"share": [1, 2], "sequence_length": 64,
+                                 "layer_types": ["sliding_attention"] * 3 + ["full_attention"]}
+    for w in windows:
+        assert w["moe_pairs_dropped"] == 0
+        assert 0 < w["tokens"] <= w["steps"] * 4 * 64
+        assert w["moe_pairs"] == sum(map(sum, w["moe_expert_tokens"]))
+        assert len(w["moe_expert_tokens"]) == 4 and len(w["moe_expert_tokens"][0]) == 4
+        assert 1.0 <= w["moe_load_max_over_mean"] <= 4.0
+        keys = w["attn_keys_per_query"]
+        assert 1.0 <= keys["sliding_attention"] <= 8.0 <= keys["full_attention"]
+        if w.get("images_per_sec"):
+            assert w["tokens_per_sec"] == pytest.approx(w["images_per_sec"] * 64, rel=1e-3)
+    # a second trainer on the same directory restores step 50 and trains on
+    again = _tiny_trainer(tmp_path)
+    state = again._checkpointer().restore_latest(again._init_state())
+    assert int(state.step) == 50
+    more = again.fit(batch_size=4, steps=60)
+    assert more.steps == 60
+    events, _ = _windows(tmp_path)
+    assert any(e.get("event") == "resumed" and e["step"] == 50 for e in events)
+
+
+def test_the_preset_is_the_published_widths_and_plans():
+    from tensorflowdistributedlearning_tpu.configs import get_preset
+    from tensorflowdistributedlearning_tpu.parallel import planner
+
+    preset = get_preset("mellum2_12b_a2p5b_share4")
+    d = preset.model.decoder
+    assert (d.hidden_size, d.head_dim, d.moe_intermediate_size, d.num_experts_per_tok,
+            d.sliding_window) == (2304, 128, 896, 8, 1024)
+    assert (d.num_attention_heads, d.num_key_value_heads, d.num_experts, d.vocab_size,
+            d.share_count) == (8, 1, 16, 24576, 4)
+    import dataclasses
+
+    one_chip = dataclasses.replace(preset.train, n_devices=1)
+    plan = planner.validate_config(preset.model, one_chip, preset.global_batch)
+    assert planner.profile_model(preset.model, preset.train).param_count == 531_452_160
+    assert plan.header()
+
+
+def test_decoder_refuses_what_it_does_not_build(tmp_path):
+    from tensorflowdistributedlearning_tpu.train.fit import ClassifierTrainer
+
+    cfg = _model_config(tiny_lm.tiny_config())
+    with pytest.raises(ValueError, match="data-parallel only"):
+        ClassifierTrainer(str(tmp_path), None, cfg,
+                          TrainConfig(expert_parallel=2, n_devices=2, augmentation="none"))
+    with pytest.raises(ValueError, match="fed as packed"):
+        ClassifierTrainer(str(tmp_path), None, cfg, TrainConfig(n_devices=1))
+    with pytest.raises(NotImplementedError, match="yields a"):
+        _tiny_trainer(tmp_path).serving_fn()
+    with pytest.raises(ValueError, match="go together"):
+        ModelConfig(backbone="decoder")

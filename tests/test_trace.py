@@ -10,6 +10,7 @@ field."""
 
 import json
 import threading
+import time
 import urllib.error
 import urllib.request
 
@@ -140,13 +141,24 @@ def traced_server(serve_fn, tmp_path):
 
 
 def _trace_events(workdir, server=None):
-    if server is not None:
-        # trace events are buffered (no flush per span); push them to disk
-        # before reading a LIVE server's ledger
-        server.telemetry.flush()
-    return [
-        e for e in obs.read_ledger(workdir) if e.get("event") == "trace"
-    ]
+    deadline = time.monotonic() + 5.0
+    while True:
+        if server is not None:
+            # trace events are buffered (no flush per span); push them to disk
+            # before reading a LIVE server's ledger
+            server.telemetry.flush()
+        events = [
+            e for e in obs.read_ledger(workdir) if e.get("event") == "trace"
+        ]
+        # the handler closes its "request" span AFTER the response has gone
+        # out: a client that reads at once can be ahead of it on a busy host
+        if (
+            server is None
+            or any(e["name"] == "request" for e in events)
+            or time.monotonic() > deadline
+        ):
+            return events
+        time.sleep(0.01)
 
 
 def test_request_trace_links_queue_pad_compute_to_batch(traced_server):
@@ -305,7 +317,7 @@ def test_chrome_export_from_serve_trace(traced_server, tmp_path):
     server, workdir = traced_server
     x = np.ones((3, FEATURES), np.float32)
     _post(server.url + "/v1/predict", {"instances": x.tolist()})
-    server.telemetry.flush()
+    _trace_events(workdir, server)  # flushed, the request's own span included
     out = str(tmp_path / "trace.json")
     n = trace_lib.write_chrome_trace(workdir, out)
     with open(out) as f:

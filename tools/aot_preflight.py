@@ -79,7 +79,7 @@ def preflight_train_step(topo, preset_name: str, chips: int, batch) -> None:
     from jax.sharding import NamedSharding, PartitionSpec as P
 
     from tensorflowdistributedlearning_tpu.configs import get_preset
-    from tensorflowdistributedlearning_tpu.models import build_model
+    from tensorflowdistributedlearning_tpu.models import build_model, sample_input
     from tensorflowdistributedlearning_tpu.parallel import mesh as mesh_lib
     from tensorflowdistributedlearning_tpu.train import step as step_lib
     from tensorflowdistributedlearning_tpu.train.state import create_train_state
@@ -88,11 +88,10 @@ def preflight_train_step(topo, preset_name: str, chips: int, batch) -> None:
     cfg = preset.model
     global_batch = batch or preset.global_batch
     mesh = mesh_lib.make_mesh(devices=list(topo.devices[:chips]))
-    h, w = cfg.input_shape
-    segmentation = cfg.num_classes is None
+    segmentation = cfg.num_classes is None and cfg.decoder is None
     task = (
         step_lib.SegmentationTask() if segmentation
-        else step_lib.ClassificationTask()
+        else step_lib.fit_task(cfg, preset.train)
     )
     replicated = NamedSharding(mesh, P())
     state = jax.eval_shape(
@@ -100,7 +99,7 @@ def preflight_train_step(topo, preset_name: str, chips: int, batch) -> None:
             build_model(cfg),
             step_lib.make_optimizer(preset.train),
             jax.random.PRNGKey(0),
-            np.zeros((1, h, w, cfg.input_channels), np.float32),
+            sample_input(cfg),
         )
     )
     state = jax.tree.map(
@@ -113,14 +112,24 @@ def preflight_train_step(topo, preset_name: str, chips: int, batch) -> None:
             shape, dtype, sharding=mesh_lib.batch_sharding(mesh, len(shape))
         )
 
-    images = batch_spec((global_batch, h, w, cfg.input_channels), np.float32)
-    labels = (
-        batch_spec((global_batch, h, w, 1), np.float32) if segmentation
-        else batch_spec((global_batch,), np.int32)
-    )
-    step = step_lib.make_train_step(mesh, task, donate=False)
+    stream = getattr(task, "batches", None)
+    if stream is not None:  # a task with a stream of its own: one batch of it
+        example = next(stream(global_batch, seed=0, steps=1))
+        batch = {k: batch_spec(v.shape, v.dtype) for k, v in example.items()}
+    else:
+        h, w = cfg.input_shape
+        batch = {
+            "images": batch_spec((global_batch, h, w, cfg.input_channels), np.float32),
+            "labels": (
+                batch_spec((global_batch, h, w, 1), np.float32) if segmentation
+                else batch_spec((global_batch,), np.int32)
+            ),
+        }
+    # the state donated, as the trainers run it: the updated state reuses
+    # its buffers
+    step = step_lib.make_train_step(mesh, task, donate=True)
     t0 = time.perf_counter()
-    compiled = step.lower(state, {"images": images, "labels": labels}).compile()
+    compiled = step.lower(state, batch).compile()
     row = {
         "preset": preset_name,
         "chips": chips,
@@ -135,6 +144,12 @@ def preflight_train_step(topo, preset_name: str, chips: int, batch) -> None:
         )
         row["argument_gib_per_chip"] = round(
             memory.argument_size_in_bytes / (1 << 30), 2
+        )
+        row["output_gib_per_chip"] = round(
+            memory.output_size_in_bytes / (1 << 30), 2
+        )
+        row["alias_gib_per_chip"] = round(
+            memory.alias_size_in_bytes / (1 << 30), 2
         )
     print(json.dumps(row), flush=True)
 
