@@ -5,8 +5,16 @@ design, not accident:
 
 - The reference looped over images with ``tf.map_fn`` (core/losses.py:27-34) and pinned
   the whole loss to CPU:0 (model.py:391-394), forcing a device->host round trip every
-  step. Here the per-image loss is ``vmap``-ed and the descending sort is
-  ``lax.top_k`` — everything stays on the TPU and fuses into the step.
+  step. Here the per-image loss is ``vmap``-ed and everything stays on the TPU.
+- The descending sort carries what it orders. A sort that returns a permutation
+  (``lax.top_k``, ``argsort``) makes the labels go through it as an indexed gather and
+  the backward pass come back through it as a scatter: one scalar access a pixel, which
+  on a v5e cost 25 + 17 ms of a 147 ms step at batch 256 against 1.7 ms for the sort
+  itself (PERF.md §6, PR 27). ``lovasz_hinge_flat`` instead sorts (error, label, valid,
+  position) together with one multi-operand ``lax.sort``, un-permutes the Lovász
+  weights with a second sort keyed on the positions, and takes the dot product in the
+  pixels' own order — so nothing differentiates through a sort and the gradient with
+  respect to the logits is elementwise.
 - The reference handled void pixels with dynamic-shape ``boolean_mask`` + ``tf.cond``
   (core/losses.py:59-64, 77-80), which cannot be jitted with static shapes. Here void
   pixels are handled with fixed-shape mask arithmetic: invalid errors are pushed to the
@@ -61,11 +69,19 @@ def lovasz_hinge_flat(
     if valid is not None:
         valid = valid.astype(logits.dtype)
         errors = jnp.where(valid > 0, errors, _VOID_ERROR)
-    errors_sorted, perm = lax.top_k(errors, errors.shape[0])
-    gt_sorted = jnp.take(labels, perm)
-    valid_sorted = None if valid is None else jnp.take(valid, perm)
-    grad = lovasz_grad(gt_sorted, valid_sorted)
-    return jnp.dot(jax.nn.relu(errors_sorted), lax.stop_gradient(grad))
+    # Descending by error, equal errors lower index first (stable on the negated key),
+    # with the labels, the mask and each pixel's position riding along.
+    carried = (labels,) if valid is None else (labels, valid)
+    _, order, *carried_sorted = lax.sort(
+        (-lax.stop_gradient(errors), lax.iota(jnp.int32, errors.shape[0]), *carried),
+        num_keys=1,
+        is_stable=True,
+    )
+    weights_sorted = lovasz_grad(*carried_sorted)
+    # Sorting (position, weight) on the position puts each weight back on its pixel;
+    # positions are distinct, so this sort needs no tie-break.
+    _, weights = lax.sort((order, weights_sorted), num_keys=1, is_stable=False)
+    return jnp.dot(jax.nn.relu(errors), lax.stop_gradient(weights))
 
 
 def lovasz_hinge(
