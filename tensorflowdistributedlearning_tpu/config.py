@@ -406,8 +406,8 @@ class TrainConfig:
     # degree (Adam slots are ~2x params; +1x more with ema_decay) at
     # neutral step time; numerics match the replicated update
     # (tests/test_zero1.py pins step-for-step equivalence). Composes with
-    # grad_accum_steps, sequence_parallel, sync_batch_norm, the multi-step
-    # scan, and model_parallel (slots shard over (model, batch) jointly);
+    # grad_accum_steps, sequence_parallel, sync_batch_norm and
+    # model_parallel (slots shard over (model, batch) jointly);
     # mutually exclusive with pipeline_parallel, whose stage runner owns its
     # own update placement.
     weight_update_sharding: bool = False

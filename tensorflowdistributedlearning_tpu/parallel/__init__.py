@@ -12,7 +12,6 @@ from tensorflowdistributedlearning_tpu.parallel.mesh import (
     replicate,
     replicated_sharding,
     shard_batch,
-    shard_batch_stacked,
 )
 from tensorflowdistributedlearning_tpu.parallel.planner import (
     Layout,
@@ -100,7 +99,6 @@ __all__ = [
     "replicate",
     "replicated_sharding",
     "shard_batch",
-    "shard_batch_stacked",
     "pmean_tree",
     "psum_tree",
 ]

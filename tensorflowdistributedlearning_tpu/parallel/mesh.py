@@ -109,26 +109,6 @@ def shard_batch(tree: Any, mesh: Mesh) -> Any:
     return jax.tree.map(_put, tree)
 
 
-def shard_batch_stacked(tree: Any, mesh: Mesh) -> Any:
-    """Place K batches stacked on a leading axis: axis 0 is the scan (step)
-    axis — replicated — and axis 1 is the example axis, sharded over
-    ``batch``. This is the input contract of ``train.step.make_multi_train_step``
-    (the device-side K-step loop); each leaf is ``[K, B, ...]`` where the same
-    leaf fed per-step would be ``[B, ...]``."""
-
-    def _put(x):
-        x = np.asarray(x)
-        if x.ndim < 2:
-            raise ValueError(
-                "shard_batch_stacked needs [K, B, ...] leaves (a scan axis "
-                f"plus the example axis); got shape {x.shape}"
-            )
-        spec = P(None, BATCH_AXIS, *([None] * (x.ndim - 2)))
-        return jax.device_put(x, NamedSharding(mesh, spec))
-
-    return jax.tree.map(_put, tree)
-
-
 def shard_batch_spatial(tree: Any, mesh: Mesh) -> Any:
     """Place a batch for sequence-parallel training: ``images`` sharded (batch,
     sequence) — axis 0 over data-parallel shards, axis 1 (H) over the sequence

@@ -6,7 +6,6 @@ from tensorflowdistributedlearning_tpu.train.step import (
     make_eval_step,
     make_optimizer,
     make_predict_step,
-    make_multi_train_step,
     make_train_step,
 )
 
@@ -19,6 +18,5 @@ __all__ = [
     "make_eval_step",
     "make_optimizer",
     "make_predict_step",
-    "make_multi_train_step",
     "make_train_step",
 ]

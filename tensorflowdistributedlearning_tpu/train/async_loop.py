@@ -138,7 +138,6 @@ class PendingWindow:
     lr: float
     images_per_sec: Optional[float] = None
     dirty: bool = False
-    extra: Dict[str, Any] = dataclasses.field(default_factory=dict)
     samples: Optional[Dict[str, list]] = None
 
 

@@ -34,10 +34,8 @@ from tensorflowdistributedlearning_tpu.data import synthetic as synthetic_lib
 from tensorflowdistributedlearning_tpu.models import build_model, sample_input
 from tensorflowdistributedlearning_tpu.parallel import mesh as mesh_lib
 from tensorflowdistributedlearning_tpu.parallel import multihost
-from tensorflowdistributedlearning_tpu.resilience import faults as faults_lib
-from tensorflowdistributedlearning_tpu.resilience import preempt as preempt_lib
 from tensorflowdistributedlearning_tpu.train import async_loop
-from tensorflowdistributedlearning_tpu.train import state as state_lib
+from tensorflowdistributedlearning_tpu.train import loop as loop_lib
 from tensorflowdistributedlearning_tpu.train import step as step_lib
 from tensorflowdistributedlearning_tpu.train.checkpoint import CheckpointManager
 from tensorflowdistributedlearning_tpu.train.state import TrainState, create_train_state
@@ -465,87 +463,32 @@ class ClassifierTrainer:
         eval_every = (
             eval_every_steps or tcfg.eval_every_steps or tcfg.checkpoint_every_steps
         )
-        # built before anything else, so that the start-up phases are spans
-        # and the compile listener hears the whole start (the K-fold
-        # trainer's names, for the phases this one has); the header waits
-        # for the plan (finish_header)
-        tel = self._telemetry = obs_lib.Telemetry(
-            self.model_dir,
-            enabled=tcfg.telemetry,
-            memory_every_windows=tcfg.telemetry_memory_every_windows,
-            # sampled per-step/eval/checkpoint traces (obs/trace.py) and the
-            # online health monitors (obs/health.py) ride the window stream
-            trace_sample_rate=tcfg.trace_sample_rate,
-            health=obs_lib.HealthMonitor.from_train_config(tcfg),
-            hold_header=True,
-            run_info={
-                "task": self.task.name,
-                **self.task.run_header(),
-                "steps": steps,
-                "global_batch": batch_size,
-                "mesh": {
-                    name: int(size)
-                    for name, size in zip(
-                        self.mesh.axis_names, self.mesh.devices.shape
-                    )
-                },
-                "model_config": dataclasses.asdict(self.model_config),
-                "train_config": dataclasses.asdict(tcfg),
-            },
-        )
-        # time cross-process sync points as this run's barrier_wait span —
-        # per-host barrier asymmetry is the fleet report's straggler signal
-        multihost.instrument(self._telemetry)
-        try:
+        with loop_lib.telemetry_run(
+            self, steps, batch_size, cleanup=self._close_data_service
+        ) as tel:
             with tel.span("startup/load_dataset"):
                 # fail fast on data-layout problems EVERY split will hit,
                 # before any training happens (e.g. fewer val record shards
                 # than processes would otherwise only surface at the first
                 # eval, potentially hours in)
                 self._open_records("val")
-            with tel.span("startup/plan"):
-                if self._plan is None and tcfg.telemetry:
-                    # direct-construction path (no fit_preset): describe the
-                    # explicit layout through the planner so the run header
-                    # carries the plan (predicted bytes/chip) like every
-                    # other run. Best-effort — the mesh already validated
-                    # divisibility in __init__, so a planner hiccup here is
-                    # telemetry loss, not a training error. Skipped when
-                    # telemetry is off: the plan's only consumer here is the
-                    # run header.
-                    try:
-                        from tensorflowdistributedlearning_tpu.parallel import (
-                            planner as planner_lib,
-                        )
-
-                        self._plan = planner_lib.validate_config(
-                            self.model_config, tcfg, batch_size
-                        ).header()
-                    except Exception as e:  # noqa: BLE001 — plan is telemetry here
-                        logger.warning("parallelism plan unavailable: %s", e)
-            # the parallelism plan (chosen layout + predicted bytes/chip):
-            # telemetry-report renders it, obs/compare hashes its layout, and
-            # the watermark events' measured-vs-predicted deltas are judged
-            # against its prediction
-            tel.finish_header(**({"plan": self._plan} if self._plan else {}))
+            # direct-construction path (no fit_preset): the header describes
+            # the explicit layout through the planner like every other run
+            loop_lib.finish_header(self, batch_size)
             return self._fit_instrumented(batch_size, steps, eval_every)
-        finally:
-            # idempotent: the success path already closed with final metrics;
-            # an exceptional exit reaches this close first and is recorded as
-            # interrupted (and the compile listener never leaks either way)
-            if self._data_service is not None:
-                self._data_service.close()
-                self._data_service = None
-            self._restored_data_state = None
-            multihost.uninstrument(self._telemetry)
-            self._telemetry.close(interrupted=True)
-            self._telemetry = obs_lib.NULL_TELEMETRY
+
+    def _close_data_service(self) -> None:
+        if self._data_service is not None:
+            self._data_service.close()
+            self._data_service = None
+        self._restored_data_state = None
 
     def _fit_instrumented(
         self, batch_size: int, steps: int, eval_every: int
     ) -> FitResult:
-        """The training loop proper, running under ``self._telemetry``
-        (constructed and torn down by ``fit``)."""
+        """The run's start-up phases and input stream, then the loop
+        (train/loop.py), under ``self._telemetry`` (constructed and torn down
+        by ``fit``)."""
         tcfg = self.train_config
         tel = self._telemetry
         with tel.span("startup/init_state"):
@@ -553,49 +496,7 @@ class ClassifierTrainer:
         # `restore` holds the wait for the restored step number too: the
         # first value the host needs off the device
         with tel.span("startup/restore"):
-            # post-init: the params/optimizer footprint, with exact
-            # per-device opt-state accounting (1/dp of it under
-            # weight_update_sharding)
-            tel.memory_event(
-                params_bytes_per_device=state_lib.tree_bytes_per_device(
-                    state.params
-                ),
-                opt_state_bytes_per_device=state_lib.tree_bytes_per_device(
-                    state.opt_state
-                ),
-                weight_update_sharding=tcfg.weight_update_sharding,
-            )
-            if tel.enabled:
-                # MFU pricing: the planner's dense-proxy FLOPs against the
-                # window's wall per step turn every step_window into an MFU
-                # point — where the proxy holds (planner.dense_proxy_flops);
-                # elsewhere the windows omit `mfu`
-                from tensorflowdistributedlearning_tpu.parallel import (
-                    planner as planner_lib,
-                )
-
-                step_flops = planner_lib.dense_proxy_flops(
-                    self.model_config, self.params, batch_size
-                )
-                if step_flops is not None:
-                    n_dev = self.mesh.devices.size
-                    tel.set_step_flops(
-                        step_flops,
-                        n_devices=n_dev,
-                        # dominant steady-state collective: the gradient
-                        # all-reduce, ~2x params bytes on-wire per step
-                        # (ring); only priced when there is a wire to cross
-                        collective_bytes_per_step=(
-                            2.0 * float(
-                                state_lib.tree_bytes_per_device(state.params)
-                            ) if n_dev > 1 else None
-                        ),
-                    )
-                # continuous profiling: windowed/triggered jax.profiler
-                # captures, the per-op roofline ledgered (obs/profiler.py)
-                tel.set_profiler(obs_lib.ContinuousProfiler(
-                    tel, every_windows=tcfg.profile_every_windows
-                ))
+            loop_lib.record_footprint(self, state, batch_size)
             ckpt = self._checkpointer()
             state = ckpt.restore_latest(state)
             start_step = int(jax.device_get(state.step))
@@ -660,169 +561,30 @@ class ClassifierTrainer:
                 ),
             )
             prepare = self._make_prepare_train()
-        step_no = start_step
-        last_eval_step = -1
-        final_metrics: Dict[str, float] = {}
-        window_t0 = time.perf_counter()
-        window_start = step_no
-        # first window contains the compile; eval/save windows are not training
-        # time either — dirty windows skip their throughput point
-        window_dirty = True
-        # host-side schedule mirror: the lr log line must not dispatch device
-        # work (the whole point of the deferred-fetch loop is a full queue)
-        lr_sched = step_lib.make_host_lr_schedule(tcfg)
 
-        def emit_window(rec: async_loop.PendingWindow, scalars) -> None:
-            scalars, vectors = step_lib.split_scalars(scalars)
-            if tb_train is not None:
-                tb_train.scalars(scalars, rec.step)
-            tel.window_event(
-                rec.step,
-                steps=rec.steps,
-                images_per_sec=rec.images_per_sec,
-                scalars=scalars,
-                dirty=rec.dirty,
-                samples=rec.samples,
-                **self.task.window_fields(
-                    rec.steps * batch_size, scalars, vectors, rec.images_per_sec
-                ),
-                # cost accounting (obs/capacity.py): examples THIS PROCESS's
-                # chips handled this window — the meter counts local devices,
-                # so a multi-host run must price the per-process batch share,
-                # not the global batch (which would inflate per-chip
-                # throughput by the process count)
-                examples=rec.steps * multihost.per_process_batch_size(batch_size),
-            )
-
-        # dispatch-ahead + deferred window fetch (train/async_loop.py);
-        # dispatch_ahead_steps=0 is the synchronous legacy loop
-        overlap = async_loop.HostOverlap(
-            tel, dispatch_ahead=tcfg.dispatch_ahead_steps, emit=emit_window
-        )
-
-        def save_data_sidecar(step: int) -> None:
-            # the input stream's resume state rides every checkpoint
-            # (process 0 writes; the validated fields — seed, batch_index —
-            # are identical on every host by construction)
-            if self._data_service is not None and is_main:
-                ckpt.save_data_state(
-                    step, self._data_service.state(step).to_json()
-                )
-
-        batches_it = iter(batches)
-        _end = object()
-        # the last start-up phase: until the tracker retires the first step
-        tel.begin_first_step()
-        while True:
-            # host blocked on the loader (prefetch underrun) vs dispatching
-            # compute: the split the ledger's step windows record
-            with tel.span(obs_lib.SPAN_DATA_WAIT):
-                raw = next(batches_it, _end)
-            if raw is _end:
-                break
-            with tel.span(obs_lib.SPAN_STEP):
-                with tel.span(obs_lib.SPAN_DISPATCH_PREPARE):
-                    batch = prepare(jax.numpy.asarray(step_no), raw)
-                with tel.span(obs_lib.SPAN_DISPATCH_STEP):
-                    state, metrics = train_step(state, batch)
-            step_no += 1
-            # bounded dispatch-ahead: block (as fetch_wait) once more than
-            # dispatch_ahead_steps steps are in flight; the step that wait
-            # retires gets its completion time
-            overlap.track(metrics, step_no)
-            # resilience boundary: injected faults fire here (a SIGTERM lands
-            # in the preemption handler below within the same boundary), and a
-            # pending preemption turns into a final checkpoint + distinct exit
-            faults_lib.fire(faults_lib.SITE_STEP, step_no)
-            if preempt_lib.requested():
-                # the deferred window reaches the ledger BEFORE the preemption
-                # checkpoint/events — resilience reporting stays complete.
-                # Preemption outranks a health abort surfacing from this
-                # flush: the alert is already ledgered, and the supervisor
-                # contract (final checkpoint + EXIT_PREEMPTED) must hold.
-                try:
-                    overlap.flush()
-                except obs_lib.HealthAbortError:
-                    pass
-                with tel.span(obs_lib.SPAN_CHECKPOINT):
-                    ckpt.save(state, force=True)
-                save_data_sidecar(step_no)
-                tel.checkpoint_event(step_no, preempted=True)
-                tel.event(
-                    "preempted", step=step_no, reason=preempt_lib.reason()
-                )
-                raise preempt_lib.PreemptedError(step_no)
-            if tb_train is not None and step_no % tcfg.train_log_every_steps == 0:
-                now = time.perf_counter()
-                images_per_sec = None
-                if not window_dirty and step_no > window_start:
-                    images_per_sec = (
-                        (step_no - window_start) * batch_size / (now - window_t0)
-                    )
-                # sync mode fetches+emits here; async mode emits the PREVIOUS
-                # window and defers this one while the device keeps running.
-                # rec.lr is the lr the NEXT update will use — exact, the
-                # schedule is step-driven (observability the reference's TB
-                # summaries never had)
-                overlap.window(
-                    async_loop.PendingWindow(
-                        step=step_no,
-                        metrics=metrics,
-                        steps=step_no - window_start,
-                        lr=lr_sched(step_no),
-                        images_per_sec=images_per_sec,
-                        dirty=window_dirty,
-                    )
-                )
-                window_t0, window_start, window_dirty = now, step_no, False
-                # train-side executables exist now: further train compiles
-                # are recompiles (the first eval marks its own phase warm)
-                tel.mark_warm(obs_lib.SPAN_STEP, obs_lib.SPAN_DATA_WAIT)
-            # the checkpoint span is a trace boundary (sampled runs show
-            # checkpoint spans in --export-trace timelines), not a window
-            # span; opened only on the manager's own save cadence so
-            # off-cadence steps stay span-free
-            saved = False
-            if ckpt.is_save_step(step_no):
-                with tel.span(obs_lib.SPAN_CHECKPOINT):
-                    saved = ckpt.maybe_save(state, step=step_no)
-            if saved:
-                overlap.flush()
-                window_dirty = True
-                save_data_sidecar(step_no)
-                tel.checkpoint_event(step_no)
-            if step_no % eval_every == 0:
-                overlap.flush()
-                last_eval_step = step_no
-                final_metrics = self._evaluate(state, batch_size, step_no=step_no)
-                if tb_eval is not None:
-                    tb_eval.scalars(final_metrics, step_no)
-                    tb_eval.flush()
-                # best-export stores the eval view: EMA params when tracked
-                ckpt.export_best(
-                    step_lib.with_ema_params(state), final_metrics
-                )
-                window_dirty = True
-        # an abort surfacing from the end-of-run flush must not skip the
-        # final checkpoint — write it, then re-raise (abort means "stop at a
-        # recorded boundary", not "discard the run's last steps")
-        abort_err: Optional[BaseException] = None
-        try:
-            overlap.flush()
-        except obs_lib.HealthAbortError as e:
-            abort_err = e
-        with tel.span(obs_lib.SPAN_CHECKPOINT):
-            ckpt.save(state, force=True)
-        save_data_sidecar(step_no)
-        tel.checkpoint_event(step_no, final=True)
-        if abort_err is not None:
-            raise abort_err
-        if last_eval_step != step_no:
-            final_metrics = self._evaluate(state, batch_size, step_no=step_no)
+        def evaluate(state: TrainState, step_no: int) -> Dict[str, float]:
+            metrics = self._evaluate(state, batch_size, step_no=step_no)
             if tb_eval is not None:
-                tb_eval.scalars(final_metrics, step_no)
+                tb_eval.scalars(metrics, step_no)
                 tb_eval.flush()
-            ckpt.export_best(step_lib.with_ema_params(state), final_metrics)
+            return metrics
+
+        _, step_no, final_metrics = loop_lib.train_loop(
+            tel,
+            tcfg,
+            self.task,
+            state=state,
+            start_step=start_step,
+            batch_size=batch_size,
+            batches=batches,
+            prepare=prepare,
+            train_step=train_step,
+            ckpt=ckpt,
+            evaluate=evaluate,
+            eval_due=lambda step_no, saved: step_no % eval_every == 0,
+            tb_train=tb_train,
+            data_service=self._data_service,
+        )
         if tb_train is not None:
             tb_train.close()
         if tb_eval is not None:

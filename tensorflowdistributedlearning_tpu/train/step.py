@@ -295,6 +295,14 @@ class SegmentationTask:
 
     threshold: float = 0.5
 
+    name = "segmentation"
+
+    def run_header(self) -> Dict[str, Any]:
+        return {}
+
+    def window_fields(self, examples: int, scalars, vectors, images_per_sec) -> Dict[str, Any]:
+        return {}
+
     def loss(self, logits: jax.Array, batch: Dict[str, jax.Array]) -> jax.Array:
         return losses_lib.lovasz_loss(batch["labels"], logits, "NHWC")
 
@@ -622,15 +630,14 @@ def merge_metrics(acc: Optional[Metrics], new: Metrics) -> Metrics:
 
 def _merge_stacked_metrics(stacked: Metrics) -> Metrics:
     """Merge metric pytrees stacked on a leading axis (a scan's per-iteration
-    outputs — the accumulation microbatch loop and the multi-step loop both
-    produce one) into a single stream by summing over that axis.
+    outputs — the accumulation microbatch loop produces one) into a single
+    stream by summing over that axis.
 
     Summation IS the K-way merge only because every leaf is a ``Mean`` state
     (``Mean.merge`` is addition of total/count). A non-additive metric leaf
     slipping into a scanned step would be silently mis-merged by a blind
     ``jnp.sum`` — fail loudly instead, naming the offender, so whoever adds
-    such a metric also adds its merge path here (the ONE place both scan
-    paths share)."""
+    such a metric also adds its merge path here."""
     for name, leaf in stacked.items():
         if not isinstance(leaf, metrics_lib.Mean):
             raise TypeError(
@@ -743,7 +750,7 @@ def make_train_step(
     Adam/LARS/EMA slots; the parameter all-gather falls out of constraining
     the updated params back to replicated. Pass state placed with
     ``parallel.zero.shard_state_weight_update``. Composes with ``accum``,
-    ``spatial``, the multi-step scan, and ``auto_model`` tensor parallelism
+    ``spatial`` and ``auto_model`` tensor parallelism
     (slots shard over (model, batch) jointly).
     """
     return _make_train_step_cached(
@@ -922,78 +929,6 @@ def _make_train_step_cached(
         return new_state, metrics
 
     return jax.jit(zero_step, donate_argnums=(0,) if donate else ())
-
-
-def make_multi_train_step(
-    mesh: Mesh,
-    task,
-    *,
-    n_steps: int,
-    weight_decay: float = 0.0,
-    apply_weight_decay: bool = False,
-    spatial: bool = False,
-    accum: int = 1,
-    seed: int = 0,
-    auto_model: bool = False,
-    weight_update_sharding: bool = False,
-) -> Callable[[TrainState, Dict[str, jax.Array]], Any]:
-    """Device-side training loop: ONE dispatch runs ``n_steps`` train steps
-    under ``lax.scan``, the way the reference's Estimator ran many steps per
-    ``session.run`` (model.py:164-172 — the host never re-entered the graph
-    between steps). Measured on a v5e chip (2026-08-01,
-    bf16 flagship, K=8): 0.993x vs back-to-back single steps — jax's ASYNC
-    DISPATCH already pipelines the single-step loop, so this buys nothing
-    when the host keeps up; it exists for orchestration regimes where the
-    host cannot (slow drivers, per-step callbacks, very short steps) and as
-    the steps-per-loop parity point with the reference.
-
-    Semantics are those of K sequential ``make_train_step`` calls — the scan
-    body IS the single step (same builder, same PRNG fold-in on
-    ``state.step``, same BN/metric math). Numerically equivalent, NOT
-    bitwise: scan inlining lets XLA fuse differently (Lovász tie-order
-    shifts bound the drift at ~1e-4 scale after 3 steps); pinned with a
-    reversed-order discriminator by
-    ``tests/test_train_step.py::test_multi_step_matches_sequential``.
-
-    Input contract: every batch leaf carries a leading ``[n_steps]`` axis —
-    place with ``mesh.shard_batch_stacked``. Returns ``(state, metrics)``
-    where metrics are the merged streaming Means over all K steps (Mean
-    merge = addition of total/count)."""
-    if spatial:
-        # shard_batch_stacked has no spatial variant yet: stacked images would
-        # arrive sequence-replicated while the inner shard_map demands
-        # (batch, sequence) sharding, so GSPMD would reshard around the scan —
-        # exactly the overhead this function exists to avoid
-        raise NotImplementedError(
-            "spatial multi-step needs a stacked-spatial batch placement; "
-            "use make_train_step per step under sequence parallelism"
-        )
-    single = make_train_step(
-        mesh,
-        task,
-        weight_decay=weight_decay,
-        apply_weight_decay=apply_weight_decay,
-        donate=False,  # scan carries the state; donation happens at the outer jit
-        spatial=spatial,
-        accum=accum,
-        seed=seed,
-        auto_model=auto_model,
-        # the zero step's sharding constraints ride inside the scan body, so
-        # the carried opt_state stays data-axis sharded across all n_steps
-        weight_update_sharding=weight_update_sharding,
-    )
-    return _make_multi_train_step_cached(single, n_steps)
-
-
-@functools.lru_cache(maxsize=None)
-def _make_multi_train_step_cached(single, n_steps: int):
-    def multi(state: TrainState, batches: Dict[str, jax.Array]):
-        # `single` already has scan's (carry, x) -> (carry, y) signature
-        final, stacked = jax.lax.scan(single, state, batches, length=n_steps)
-        # stacked Mean states carry a leading [n_steps] dim
-        return final, _merge_stacked_metrics(stacked)
-
-    return jax.jit(multi, donate_argnums=(0,))
 
 
 def make_eval_step(

@@ -44,10 +44,8 @@ from tensorflowdistributedlearning_tpu.data import pipeline as pipeline_lib
 from tensorflowdistributedlearning_tpu.models import build_model
 from tensorflowdistributedlearning_tpu.parallel import mesh as mesh_lib
 from tensorflowdistributedlearning_tpu.parallel import multihost
-from tensorflowdistributedlearning_tpu.resilience import faults as faults_lib
-from tensorflowdistributedlearning_tpu.resilience import preempt as preempt_lib
 from tensorflowdistributedlearning_tpu.train import async_loop
-from tensorflowdistributedlearning_tpu.train import state as state_lib
+from tensorflowdistributedlearning_tpu.train import loop as loop_lib
 from tensorflowdistributedlearning_tpu.train import step as step_lib
 from tensorflowdistributedlearning_tpu.train.checkpoint import CheckpointManager
 from tensorflowdistributedlearning_tpu.train.state import TrainState, create_train_state
@@ -303,38 +301,10 @@ class Trainer:
         mesh_lib.check_accum_divisibility(
             batch_size, self.mesh, tcfg.grad_accum_steps
         )
-        # one ledger for the whole K-fold run; events carry their fold. Built
-        # before anything else, so that the start-up phases below are spans
-        # and the compile listener hears the whole start; the header waits
-        # for the plan (finish_header)
-        tel = self._telemetry = obs_lib.Telemetry(
-            self.model_dir,
-            enabled=tcfg.telemetry,
-            memory_every_windows=tcfg.telemetry_memory_every_windows,
-            # sampled per-step/eval/checkpoint traces (obs/trace.py) and the
-            # online health monitors (obs/health.py) ride the window stream
-            trace_sample_rate=tcfg.trace_sample_rate,
-            health=obs_lib.HealthMonitor.from_train_config(tcfg),
-            hold_header=True,
-            run_info={
-                "task": "segmentation",
-                "steps": steps,
-                "global_batch": batch_size,
-                "n_folds": tcfg.n_folds,
-                "mesh": {
-                    name: int(size)
-                    for name, size in zip(
-                        self.mesh.axis_names, self.mesh.devices.shape
-                    )
-                },
-                "model_config": dataclasses.asdict(self.model_config),
-                "train_config": dataclasses.asdict(tcfg),
-            },
-        )
-        # time cross-process sync points as this run's barrier_wait span —
-        # per-host barrier asymmetry is the fleet report's straggler signal
-        multihost.instrument(self._telemetry)
-        try:
+        # one ledger for the whole K-fold run; events carry their fold
+        with loop_lib.telemetry_run(
+            self, steps, batch_size, run_info={"n_folds": tcfg.n_folds}
+        ) as tel:
             with tel.span("startup/load_dataset"):
                 dataset = pipeline_lib.InMemoryDataset.from_directory(
                     self.data_directory, ids=list(X)
@@ -348,29 +318,9 @@ class Trainer:
                     self.model_dir, list(X), list(np.asarray(y)), tcfg.n_folds,
                     tcfg.seed,
                 )
-            # describe this run's layout through the parallelism planner so
-            # the run header carries the plan (predicted bytes/chip);
-            # best-effort — the mesh already validated divisibility in
-            # __init__, so a planner hiccup here is telemetry loss, not a
-            # training error (the CLI's --parallelism auto resolves its plan
-            # BEFORE this trainer exists)
-            run_plan = self._plan
-            with tel.span("startup/plan"):
-                if run_plan is None and tcfg.telemetry:
-                    # the plan's only consumer here is the run header
-                    try:
-                        from tensorflowdistributedlearning_tpu.parallel import (
-                            planner as planner_lib,
-                        )
-
-                        run_plan = planner_lib.validate_config(
-                            self.model_config, tcfg, batch_size
-                        ).header()
-                    except Exception as e:  # noqa: BLE001 — plan is telemetry here
-                        logger.warning("parallelism plan unavailable: %s", e)
-            # chosen layout + predicted bytes/chip (parallel/planner.py):
-            # rendered by telemetry-report, hashed by obs/compare
-            tel.finish_header(**({"plan": run_plan} if run_plan else {}))
+            # the CLI's --parallelism auto resolves its plan BEFORE this
+            # trainer exists; otherwise the header describes the explicit one
+            loop_lib.finish_header(self, batch_size)
             results = []
             for fold, manifest in enumerate(manifests):
                 logger.info("Processing fold %d", fold)  # reference: model.py:162
@@ -378,19 +328,13 @@ class Trainer:
                     self._train_fold(fold, dataset, manifest, batch_size, steps)
                 )
                 logger.info("Finished training fold %d", fold)  # reference: model.py:225
-            self._telemetry.close(
+            tel.close(
                 folds=len(results),
                 final_metrics={
                     k: float(v) for k, v in (results[-1] if results else {}).items()
                 },
             )
             return results
-        finally:
-            # idempotent; an exceptional exit reaches this close first and is
-            # recorded as interrupted
-            multihost.uninstrument(self._telemetry)
-            self._telemetry.close(interrupted=True)
-            self._telemetry = obs_lib.NULL_TELEMETRY
 
     def _train_fold(
         self,
@@ -400,6 +344,8 @@ class Trainer:
         batch_size: int,
         steps: int,
     ) -> Dict[str, float]:
+        """One fold's start-up phases and input stream, then the loop
+        (train/loop.py)."""
         tcfg = self.train_config
         tel = self._telemetry
         tel.fold = fold
@@ -427,45 +373,7 @@ class Trainer:
             state = self._init_state()
         with tel.span("startup/restore"):
             state = ckpt.restore_latest(state)
-            # post-init params/optimizer footprint, with exact per-device
-            # opt-state accounting (1/dp of it under weight_update_sharding)
-            tel.memory_event(
-                params_bytes_per_device=state_lib.tree_bytes_per_device(
-                    state.params
-                ),
-                opt_state_bytes_per_device=state_lib.tree_bytes_per_device(
-                    state.opt_state
-                ),
-                weight_update_sharding=tcfg.weight_update_sharding,
-            )
-            if tel.enabled:
-                # MFU pricing where the planner's dense proxy holds
-                # (planner.dense_proxy_flops): not for this trainer's
-                # convolutional backbones, whose windows omit `mfu`
-                from tensorflowdistributedlearning_tpu.parallel import (
-                    planner as planner_lib,
-                )
-
-                step_flops = planner_lib.dense_proxy_flops(
-                    self.model_config, self.params, batch_size
-                )
-                if step_flops is not None:
-                    n_dev = self.mesh.devices.size
-                    tel.set_step_flops(
-                        step_flops,
-                        n_devices=n_dev,
-                        collective_bytes_per_step=(
-                            2.0 * float(
-                                state_lib.tree_bytes_per_device(state.params)
-                            ) if n_dev > 1 else None
-                        ),
-                    )
-                # continuous profiling: windowed/triggered jax.profiler
-                # captures, the per-op roofline ledgered (obs/profiler.py)
-                if tel.profiler is None:
-                    tel.set_profiler(obs_lib.ContinuousProfiler(
-                        tel, every_windows=tcfg.profile_every_windows,
-                    ))
+            loop_lib.record_footprint(self, state, batch_size)
             start_step = int(jax.device_get(state.step))
         if start_step >= steps:
             logger.info("fold %d already trained to step %d", fold, start_step)
@@ -496,8 +404,11 @@ class Trainer:
             is_main = jax.process_index() == 0
             tb_train = SummaryWriter(os.path.join(self._fold_dir(fold), "train")) if is_main else None
             tb_eval = SummaryWriter(os.path.join(self._fold_dir(fold), "eval")) if is_main else None
-            last_eval_time = 0.0
-            final_metrics: Dict[str, float] = {}
+            # the gauges are drained per log window; a run that never writes
+            # windows (telemetry off, or a non-main host with no TB writer)
+            # must not record into them — the samples would accumulate for the
+            # life of the run with nothing reading them
+            registry = tel.registry if tel.enabled and is_main else None
 
             data_service = None
             if tcfg.data_service_workers > 0:
@@ -511,7 +422,7 @@ class Trainer:
                     service as service_lib,
                 )
 
-                svc = service_lib.StreamingDataService(
+                data_service = service_lib.StreamingDataService(
                     service_lib.ArrayBatchSource(
                         {"images": train_ds.images, "masks": train_ds.masks},
                         # the fold arrays were host-sharded for THIS world size:
@@ -526,22 +437,18 @@ class Trainer:
                     seed=tcfg.seed + fold,
                     workers=tcfg.data_service_workers,
                     start_batch=start_step,
-                    registry=(
-                        self._telemetry.registry
-                        if self._telemetry.enabled and tb_train is not None
-                        else None
-                    ),
+                    registry=registry,
                     resume_state=(
                         ckpt.restore_data_state(start_step)
                         if start_step > 0 else None
                     ),
                 )
-                data_service = svc
-                if svc.redeal is not None:
-                    self._telemetry.event(
-                        "data_redeal", step=start_step, fold=fold, **svc.redeal
+                if data_service.redeal is not None:
+                    tel.event(
+                        "data_redeal", step=start_step, fold=fold,
+                        **data_service.redeal,
                     )
-                batches = svc.batches(steps=steps - start_step)
+                batches = data_service.batches(steps=steps - start_step)
             else:
                 batches = pipeline_lib.train_batches(
                     train_ds,
@@ -558,193 +465,55 @@ class Trainer:
                     b, self.mesh, spatial=self._spatial
                 ),
                 depth=tcfg.prefetch_depth,
-                # the gauge is drained per log window; a run that never writes
-                # windows (telemetry off, or a non-main host with no TB writer)
-                # must not record into it — the samples would accumulate for the
-                # life of the run with nothing reading them
-                registry=(
-                    self._telemetry.registry
-                    if self._telemetry.enabled and tb_train is not None
-                    else None
-                ),
+                registry=registry,
             )
-        step_no = start_step
-        last_eval_step = -1
-        window_t0 = time.perf_counter()
-        window_start = step_no
-        # the first window contains the train-step compile; windows containing
-        # an eval pass or a synchronous checkpoint save are likewise not
-        # training time — mark them dirty and skip their throughput point
-        window_dirty = True
-        # host-side schedule mirror: the lr log line adds zero device work
-        lr_sched = step_lib.make_host_lr_schedule(tcfg)
+        last_eval_time = 0.0
 
-        def emit_window(rec: async_loop.PendingWindow, scalars) -> None:
-            if tb_train is not None:
-                tb_train.scalars(scalars, rec.step)
-            tel.window_event(
-                rec.step,
-                steps=rec.steps,
-                images_per_sec=rec.images_per_sec,
-                scalars=scalars,
-                dirty=rec.dirty,
-                samples=rec.samples,
-                # cost accounting (obs/capacity.py): examples THIS PROCESS's
-                # chips handled this window — the meter counts local devices,
-                # so a multi-host run must price the per-process batch share,
-                # not the global batch
-                examples=rec.steps * multihost.per_process_batch_size(batch_size),
-                **rec.extra,
-            )
-
-        # dispatch-ahead + deferred window fetch (train/async_loop.py);
-        # dispatch_ahead_steps=0 is the synchronous legacy loop
-        overlap = async_loop.HostOverlap(
-            tel, dispatch_ahead=tcfg.dispatch_ahead_steps, emit=emit_window
-        )
-
-        def save_data_sidecar(step: int) -> None:
-            # the fold stream's resume state rides every checkpoint (process
-            # 0 writes; seed/batch_index are identical on every host) — the
-            # durable half of the service resume contract, like fit()'s
-            if data_service is not None and is_main:
-                ckpt.save_data_state(
-                    step, data_service.state(step).to_json()
-                )
-
-        batches_it = iter(batches)
-        _end = object()
-        # the last start-up phase: until the tracker retires this fold's
-        # first step
-        tel.begin_first_step()
-        while True:
-            # host blocked on the loader vs dispatching compute: the split
-            # the ledger's step windows record
-            with tel.span(obs_lib.SPAN_DATA_WAIT):
-                raw = next(batches_it, _end)
-            if raw is _end:
-                break
-            with tel.span(obs_lib.SPAN_STEP):
-                with tel.span(obs_lib.SPAN_DISPATCH_PREPARE):
-                    batch = prepare(jnp.asarray(step_no), raw)
-                with tel.span(obs_lib.SPAN_DISPATCH_STEP):
-                    state, metrics = train_step(state, batch)
-            step_no += 1
-            # bounded dispatch-ahead: block (as fetch_wait) once more than
-            # dispatch_ahead_steps steps are in flight; the step that wait
-            # retires gets its completion time
-            overlap.track(metrics, step_no)
-            # resilience boundary: injected faults fire here (a SIGTERM lands
-            # in the preemption handler below within the same boundary), and a
-            # pending preemption turns into a final checkpoint + distinct exit
-            faults_lib.fire(faults_lib.SITE_STEP, step_no)
-            if preempt_lib.requested():
-                # the deferred window reaches the ledger BEFORE the preemption
-                # checkpoint/events — resilience reporting stays complete
-                # preemption outranks a health abort surfacing from this
-                # flush: the alert is already ledgered, and the supervisor
-                # contract (final checkpoint + EXIT_PREEMPTED) must hold
-                try:
-                    overlap.flush()
-                except obs_lib.HealthAbortError:
-                    pass
-                with tel.span(obs_lib.SPAN_CHECKPOINT):
-                    ckpt.save(state, force=True)
-                save_data_sidecar(step_no)
-                tel.checkpoint_event(step_no, fold=fold, preempted=True)
-                tel.event(
-                    "preempted",
-                    step=step_no,
-                    fold=fold,
-                    reason=preempt_lib.reason(),
-                )
-                raise preempt_lib.PreemptedError(step_no)
-            if tb_train is not None and step_no % tcfg.train_log_every_steps == 0:
-                now = time.perf_counter()
-                images_per_sec = None
-                if not window_dirty and step_no > window_start:
-                    images_per_sec = (
-                        (step_no - window_start) * batch_size / (now - window_t0)
-                    )
-                # sync mode fetches+emits here; async mode emits the PREVIOUS
-                # window and defers this one while the device keeps running.
-                # rec.lr is the exact lr of the next update (host-side
-                # schedule eval)
-                overlap.window(
-                    async_loop.PendingWindow(
-                        step=step_no,
-                        metrics=metrics,
-                        steps=step_no - window_start,
-                        lr=lr_sched(step_no),
-                        images_per_sec=images_per_sec,
-                        dirty=window_dirty,
-                        extra={"fold": fold},
-                    )
-                )
-                window_t0, window_start, window_dirty = now, step_no, False
-                tel.mark_warm(obs_lib.SPAN_STEP, obs_lib.SPAN_DATA_WAIT)
-                # train-phase image grids every train_log_every_steps — the
-                # reference's SummarySaverHook wrote input/label/probability/
-                # prediction to fold{i}/train every 20 steps (model.py:470-481);
-                # one extra inference-mode forward per log interval
-                if jax.process_count() == 1:
-                    self._write_image_summaries(tb_train, state, batch, step_no)
-            # checkpoint span = trace boundary (obs/trace.py), opened only on
-            # the manager's own save cadence so off-cadence steps stay
-            # span-free
-            saved = False
-            if ckpt.is_save_step(step_no):
-                with tel.span(obs_lib.SPAN_CHECKPOINT):
-                    saved = ckpt.maybe_save(state, step=step_no)
-            if saved:
-                overlap.flush()
-                window_dirty = True
-                save_data_sidecar(step_no)
-                tel.checkpoint_event(step_no, fold=fold)
-            # eval cadence: an explicit eval_every_steps knob decouples eval from
-            # checkpointing AND bypasses the time throttle (explicit user intent,
-            # same semantics as fit()); the default preserves the reference's
-            # train_and_evaluate shape — eval when a checkpoint lands and the
-            # >=eval_throttle_secs window passed (reference: model.py:214)
-            if tcfg.eval_every_steps:
-                due = step_no % tcfg.eval_every_steps == 0
-            else:
-                due = saved and time.time() - last_eval_time >= tcfg.eval_throttle_secs
-            if due:
-                overlap.flush()
-                last_eval_time = time.time()
-                last_eval_step = step_no
-                final_metrics = self._evaluate(
-                    state, eval_ds, batch_size, fold, writer=tb_eval,
-                    global_n=eval_global_n, step_no=step_no,
-                )
-                # best-export stores the eval view: EMA params when tracked
-                ckpt.export_best(
-                    step_lib.with_ema_params(state), final_metrics
-                )
-                window_dirty = True
-        # end of training: final checkpoint + eval + export (train_and_evaluate's
-        # final-eval contract) — skipped when the last loop iteration already
-        # checkpointed and evaluated at this exact step
-        # an abort from the end-of-fold flush must not skip the final
-        # checkpoint — write it, then re-raise
-        abort_err = None
-        try:
-            overlap.flush()
-        except obs_lib.HealthAbortError as e:
-            abort_err = e
-        with tel.span(obs_lib.SPAN_CHECKPOINT):
-            ckpt.save(state, force=True)
-        save_data_sidecar(step_no)
-        tel.checkpoint_event(step_no, fold=fold, final=True)
-        if abort_err is not None:
-            raise abort_err
-        if last_eval_step != step_no:
-            final_metrics = self._evaluate(
+        def evaluate(state: TrainState, step_no: int) -> Dict[str, float]:
+            nonlocal last_eval_time
+            last_eval_time = time.time()
+            return self._evaluate(
                 state, eval_ds, batch_size, fold, writer=tb_eval,
                 global_n=eval_global_n, step_no=step_no,
             )
-            ckpt.export_best(step_lib.with_ema_params(state), final_metrics)
+
+        def eval_due(step_no: int, saved: bool) -> bool:
+            # an explicit eval_every_steps knob decouples eval from
+            # checkpointing AND bypasses the time throttle (explicit user
+            # intent, same semantics as fit()); the default preserves the
+            # reference's train_and_evaluate shape — eval when a checkpoint
+            # lands and the >=eval_throttle_secs window passed (reference:
+            # model.py:214)
+            if tcfg.eval_every_steps:
+                return step_no % tcfg.eval_every_steps == 0
+            return saved and time.time() - last_eval_time >= tcfg.eval_throttle_secs
+
+        _, _, final_metrics = loop_lib.train_loop(
+            tel,
+            tcfg,
+            self.task,
+            state=state,
+            start_step=start_step,
+            batch_size=batch_size,
+            batches=batches,
+            prepare=prepare,
+            train_step=train_step,
+            ckpt=ckpt,
+            evaluate=evaluate,
+            eval_due=eval_due,
+            tb_train=tb_train,
+            data_service=data_service,
+            # train-phase image grids every train_log_every_steps — the
+            # reference's SummarySaverHook wrote input/label/probability/
+            # prediction to fold{i}/train every 20 steps (model.py:470-481);
+            # one extra inference-mode forward per log interval, and only
+            # where the batches are fully addressable
+            after_window=(
+                functools.partial(self._write_image_summaries, tb_train)
+                if jax.process_count() == 1 else None
+            ),
+            event_fields={"fold": fold},
+        )
         if tb_train is not None:
             tb_train.close()
         if tb_eval is not None:

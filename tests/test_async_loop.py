@@ -420,41 +420,6 @@ def test_eval_pass_single_host_transfer(tmp_path, monkeypatch):
     assert all(n == 4 for n in batch_counts)
 
 
-def test_preemption_mid_window_flushes_deferred_window(tmp_path, monkeypatch):
-    """A preemption landing while a window is deferred must flush it to the
-    ledger BEFORE the preemption checkpoint/events (resilience reporting
-    depends on ledger completeness at that boundary)."""
-    steps_seen = [0]
-
-    def fake_requested():
-        # True at the step AFTER the first log window (log_every=2): window@2
-        # is deferred in async mode when the preemption lands at step 3
-        return steps_seen[0] >= 3
-
-    def fake_fire(site, step=None, **kw):
-        if site == "step":
-            steps_seen[0] = step
-
-    from tensorflowdistributedlearning_tpu.resilience import faults
-
-    monkeypatch.setattr(faults, "fire", fake_fire)
-    monkeypatch.setattr(preempt, "requested", fake_requested)
-    monkeypatch.setattr(preempt, "reason", lambda: "test:forced")
-    trainer = ClassifierTrainer(
-        str(tmp_path), None, ModelConfig(**TINY), _tiny_tcfg(2)
-    )
-    with pytest.raises(preempt.PreemptedError):
-        trainer.fit(batch_size=8, steps=8)
-    events = obs.read_ledger(str(tmp_path))
-    kinds = [e["event"] for e in events]
-    assert "preempted" in kinds
-    window_steps = [e["step"] for e in events if e["event"] == "step_window"]
-    assert window_steps == [2]
-    # ordering: the flushed window precedes the preemption checkpoint + event
-    assert kinds.index("step_window") < kinds.index("checkpoint")
-    assert kinds.index("checkpoint") < kinds.index("preempted")
-
-
 # -- telemetry-report surfacing ------------------------------------------------
 
 

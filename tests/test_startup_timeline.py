@@ -434,7 +434,14 @@ def test_the_seams_the_benchmark_reaches_through(tmp_path, monkeypatch):
 
         return step
 
-    # looked up on the module when the fold starts, not bound at import
+    # looked up on the module when the fold starts, not bound at import: the
+    # loop's module is long imported by now, and holds no name of its own for
+    # the factory that a swap on `train.step` would miss
+    import sys
+
+    loop_lib = sys.modules["tensorflowdistributedlearning_tpu.train.loop"]
+    assert loop_lib.step_lib is step_lib
+    assert not hasattr(loop_lib, "make_train_step")
     monkeypatch.setattr(step_lib, "make_train_step", factory)
     Observed(
         str(tmp_path / "model"), data,

@@ -10,8 +10,8 @@ What must hold, on the forced 8-device CPU mesh:
 - placement: Adam moments AND the EMA tracker land sharded (1/dp per-chip
   bytes), params stay replicated;
 - equivalence: a sharded-update run matches the replicated-update run
-  STEP-FOR-STEP within tolerance — with donation on, through the multi-step
-  scan, and through gradient accumulation (acceptance criteria of ISSUE 4);
+  STEP-FOR-STEP within tolerance — with donation on and through gradient
+  accumulation (acceptance criteria of ISSUE 4);
 - checkpoints: a sharded run's checkpoint restores into a replicated template
   and vice versa (the resume-across-modes contract), with values intact and
   the target placement honored.
@@ -47,7 +47,6 @@ from tensorflowdistributedlearning_tpu.parallel.mesh import (
     make_mesh,
     replicate,
     shard_batch,
-    shard_batch_stacked,
 )
 from tensorflowdistributedlearning_tpu.train import step as step_lib
 from tensorflowdistributedlearning_tpu.train.state import (
@@ -82,8 +81,8 @@ def _state(tcfg, mesh=None, cfg=TINY_VIT, zero=False):
     state = create_train_state(
         model, tx, jax.random.key(0), jnp.ones(shape, jnp.float32)
     )
-    # plain-dict batch_stats: flax's mutable apply returns dicts, and the
-    # multi-step scan needs one stable carry pytree type (the same
+    # plain-dict batch_stats: flax's mutable apply returns dicts, and a
+    # donated step wants one stable pytree type in and out (the same
     # normalization bench.py's ViT section applies)
     state = state.replace(batch_stats=unfreeze(state.batch_stats))
     if mesh is None:
@@ -258,38 +257,6 @@ def test_sharded_update_matches_replicated_sgd_tight():
         rep, _ = rep_step(rep, batch)
         zero, _ = zero_step(zero, batch)
         _assert_states_close(rep, zero, atol=1e-5)
-
-
-def test_multi_step_scan_with_sharded_update():
-    """The device-side K-step loop (make_multi_train_step) composes: one
-    dispatch runs 2 zero-mode steps under lax.scan with donation, matching
-    2 sequential replicated steps within the scan's reassociation tolerance
-    (same bound family as test_multi_step_matches_sequential)."""
-    mesh = make_mesh(8)
-    task = step_lib.ClassificationTask()
-    raws = _batches(2, seed=3)
-    stacked = shard_batch_stacked(
-        {k: np.stack([b[k] for b in raws]) for k in raws[0]}, mesh
-    )
-    multi_zero = step_lib.make_multi_train_step(
-        mesh, task, n_steps=2, weight_update_sharding=True
-    )
-    zero_final, m_multi = multi_zero(_state(FULL_CHAIN, mesh, zero=True), stacked)
-
-    rep_step = step_lib.make_train_step(mesh, task, donate=False)
-    rep = _state(FULL_CHAIN, mesh)
-    m_seq = None
-    for raw in raws:
-        rep, m = rep_step(rep, shard_batch(raw, mesh))
-        m_seq = step_lib.merge_metrics(m_seq, jax.device_get(m))
-    assert int(jax.device_get(zero_final.step)) == 2
-    _assert_states_close(rep, zero_final, atol=2e-3)
-    assert step_lib.compute_metrics(jax.device_get(m_multi))[
-        "loss"
-    ] == pytest.approx(step_lib.compute_metrics(m_seq)["loss"], rel=1e-4)
-    # opt_state leaves still sharded in the scan-carried result
-    flat = jax.tree_util.tree_leaves_with_path(zero_final.opt_state)
-    assert sum(1 for _, leaf in flat if leaf.sharding.spec != P()) > 0.8 * len(flat)
 
 
 def test_grad_accum_with_sharded_update():
