@@ -167,7 +167,8 @@ class DecoderMoE(nn.Module):
                 w_gate.astype(self.dtype), w_up.astype(self.dtype), w_down.astype(self.dtype),
                 num_experts_total=total, first_expert=cfg.share_index * held,
             )
-        return out.reshape(u.shape), counts, dropped
+        buffer_rows = expert_lib.pair_buffer_rows(counts, experts.size, total)
+        return out.reshape(u.shape), counts, dropped, buffer_rows
 
 
 class DecoderLayer(nn.Module):
@@ -181,10 +182,10 @@ class DecoderLayer(nn.Module):
         h = x + DecoderAttention(self.cfg, self.layer_type, self.dtype, name="attn")(
             RMSNorm(eps, name="attn_norm")(x), segment_ids, positions
         )
-        out, counts, dropped = DecoderMoE(self.cfg, self.dtype, name="moe")(
+        out, *counters = DecoderMoE(self.cfg, self.dtype, name="moe")(
             RMSNorm(eps, name="moe_norm")(h)
         )
-        return h + out, (counts, dropped)
+        return h + out, counters
 
 
 class HeadLoss(nn.Module):
@@ -254,12 +255,13 @@ class MoEDecoder(nn.Module):
         b, t = tokens.shape
         x = nn.Embed(cfg.vocab_size, cfg.hidden_size, embedding_init=_INIT, name="embed")(tokens)
         layer_cls = nn.remat(DecoderLayer) if b * t >= REMAT_MIN_TOKENS else DecoderLayer
-        counts, dropped = [], jnp.zeros((), jnp.int32)
+        counts, buffer_rows, dropped = [], [], jnp.zeros((), jnp.int32)
         for i in range(cfg.num_hidden_layers):
-            x, (c, d) = layer_cls(cfg, cfg.layer_types[i], dtype, name=f"layers_{i}")(
+            x, (c, d, r) = layer_cls(cfg, cfg.layer_types[i], dtype, name=f"layers_{i}")(
                 x, segment_ids, positions
             )
             counts.append(c)
+            buffer_rows.append(r)
             dropped = dropped + d
         x = RMSNorm(cfg.rms_norm_eps, name="final_norm")(x)
         if "targets" not in inputs:
@@ -271,6 +273,7 @@ class MoEDecoder(nn.Module):
         out.update(
             expert_tokens=jnp.stack(counts).astype(jnp.float32),
             pairs_dropped=dropped.astype(jnp.float32),
+            buffer_rows=jnp.stack(buffer_rows).astype(jnp.float32),
             attn_keys_full=jnp.sum(seen),
             attn_keys_sliding=jnp.sum(jnp.minimum(seen, float(cfg.sliding_window))),
             n_sequences=jnp.asarray(b, jnp.float32),

@@ -24,18 +24,27 @@ both all-to-alls (their transpose is the reverse all-to-all).
 The second half of the file is the decoder family's expert layer
 (models/decoder.py): ``top_k_routing`` (k experts a token, weights
 renormalised over the chosen) and ``dropless_experts``, which drops no token
-whatever the imbalance — the token-expert pairs are sorted by expert and the
-experts' matrices applied as grouped products over the sorted rows
+whatever the imbalance. The layer is told which experts it holds
+(``first_expert`` and the leading size of its matrices) and routes over all
+of them. The token-expert pairs are sorted so that the held experts' come
+first, grouped by expert, and the layer works over that sorted buffer a
+segment at a time — a segment is 1.25 times the pairs expected here
+(``_segment_rows``) — in a loop over as many segments as hold the held pairs,
+a number the device reads off the routing's counts. So the rows the layer
+gathers, multiplies and sums back follow what this chip holds (one segment
+where the routing is even), a routing that sends everything here is served
+by more turns of the same loop, and the program holds the layer once. The
+experts' matrices are applied as grouped products over the buffer's rows
 (``grouped_matmul``: the Pallas ``megablox`` kernel on a TPU,
-``lax.ragged_dot`` elsewhere). The layer is told which experts it holds
-(``first_expert`` and the leading size of its matrices), routes over all of
-them and computes its own experts' part; the ViT family keeps the top-1
+``lax.ragged_dot`` elsewhere), and the rows are summed into their tokens by
+one more (``_sum_rows_into_tokens``). The ViT family keeps the top-1
 capacity path above.
 """
 
 from __future__ import annotations
 
 import functools
+import math
 from typing import Any, Callable, Tuple
 
 import jax
@@ -75,8 +84,6 @@ def _dispatch_buffers(
     Returns ``(buffer [E, C, D], flat_idx, keep, prob)``: the dense per-expert
     capacity buffer, each token's slot index, its keep mask, and its gate
     probability. Capacity is the documented ``C = ceil(tokens/E * factor)``."""
-    import math
-
     t, d = x.shape
     capacity = max(1, math.ceil(t * capacity_factor / n_experts))
     expert, slot, keep, prob = top1_dispatch(gate_logits, capacity)
@@ -194,6 +201,10 @@ def load_balance_loss(gate_logits: jax.Array) -> jax.Array:
 
 # rows of a megablox tile; the sorted pair buffer has to be a multiple of it
 _GMM_TILE_M = 512
+# a segment of the sorted pair buffer, over the pairs expected here
+_SEGMENT_OVER_EXPECTED = 1.25
+# tokens a group when a buffer's rows are summed into their tokens
+_TOKEN_BLOCK = 256
 # leading lanes of an output row that say whether the row was computed
 _WRITTEN_LANES = 128
 
@@ -231,68 +242,164 @@ def gmm_kernel_serves(rows: int, k: int, n: int) -> bool:
     return rows % _GMM_TILE_M == 0 and k % 128 == 0 and n % 128 == 0
 
 
-def grouped_matmul(
-    lhs: jax.Array, rhs: jax.Array, group_sizes: jax.Array, first_group: int = 0
-) -> jax.Array:
-    """``lhs`` [R, K] holds rows sorted by group over ``len(group_sizes)``
-    groups; ``rhs`` [G, K, N] holds the matrices of groups ``first_group`` to
-    ``first_group + G``. Row r of group g gives ``lhs[r] @ rhs[g -
-    first_group]``; rows of the groups not held give 0. Float32 accumulation,
-    the result in ``lhs``'s dtype."""
+def grouped_matmul(lhs: jax.Array, rhs: jax.Array, group_sizes: jax.Array) -> jax.Array:
+    """``lhs`` [R, K] holds rows sorted by group; ``rhs`` [G, K, N] holds the
+    matrices of the first G of the ``len(group_sizes)`` groups. Row r of group
+    g gives ``lhs[r] @ rhs[g]``; rows of the groups past G, which no matrix
+    serves, give 0. Float32 accumulation, the result in ``lhs``'s dtype."""
     rows, k = lhs.shape
     held, n = rhs.shape[0], rhs.shape[2]
+    sizes = group_sizes.astype(jnp.int32)
     from tensorflowdistributedlearning_tpu.ops import pallas_kernels
 
     if pallas_kernels.pallas_platform_ok() and gmm_kernel_serves(rows, k, n):
         from jax.experimental.pallas.ops.tpu.megablox import gmm
 
-        return gmm(
-            lhs, rhs, group_sizes.astype(jnp.int32), lhs.dtype, _gmm_tiling,
-            jnp.asarray(first_group, jnp.int32),
-        )
-    # ragged_dot wants a matrix for every group: the rows before and after
-    # the held groups go to a zero matrix each
-    sizes = group_sizes.astype(jnp.int32)
-    before = jnp.sum(sizes[:first_group])
-    mine = sizes[first_group : first_group + held]
-    after = rows - before - jnp.sum(mine)
-    zero = jnp.zeros((1,) + rhs.shape[1:], rhs.dtype)
+        return gmm(lhs, rhs, sizes, lhs.dtype, _gmm_tiling)
+    # ragged_dot wants a matrix for every group: a zero one for the rest
+    rest = sizes.shape[0] - held
+    zero = jnp.zeros((rest,) + rhs.shape[1:], rhs.dtype)
     return lax.ragged_dot(
-        lhs,
-        jnp.concatenate([zero, rhs, zero]),
-        jnp.concatenate([before[None], mine, after[None]]),
-        preferred_element_type=jnp.float32,
+        lhs, jnp.concatenate([rhs, zero]), sizes, preferred_element_type=jnp.float32
     ).astype(lhs.dtype)
 
 
-@jax.custom_vjp
-def _permute_rows(x: jax.Array, perm: jax.Array, inverse: jax.Array) -> jax.Array:
-    """``x[perm]`` for a permutation whose inverse is at hand: the backward
-    pass is a gather too (``g[inverse]``), where autodiff would scatter."""
-    return x[perm]
+def _segment_rows(pairs: int, held: int, total: int) -> int:
+    """Rows of one segment of the sorted pair buffer: the pairs expected here
+    (``pairs * held / total``) times ``_SEGMENT_OVER_EXPECTED`` in whole
+    tiles, at most all ``pairs``."""
+    expected = pairs * held / total
+    return min(pairs, -(-math.ceil(expected * _SEGMENT_OVER_EXPECTED) // _GMM_TILE_M) * _GMM_TILE_M)
 
 
-_permute_rows.defvjp(
-    lambda x, perm, inverse: (x[perm], (perm, inverse)),
-    lambda res, g: (g[res[1]], None, None),
+def _segments(n_held: jax.Array, rows: int) -> jax.Array:
+    """Segments of ``rows`` rows that hold ``n_held`` pairs."""
+    return -(-n_held // rows)
+
+
+def pair_buffer_rows(counts: jax.Array, pairs: int, num_experts_total: int) -> jax.Array:
+    """Rows of the buffer ``dropless_experts`` works over when ``counts``
+    tokens go to each held expert, of ``pairs`` routed over all experts."""
+    rows = _segment_rows(pairs, counts.shape[0], num_experts_total)
+    return _segments(jnp.sum(counts), rows) * rows
+
+
+def _sum_rows_on_mxu(rows, tok, t: int, block: int, interpret: bool = False) -> jax.Array:
+    """``_sum_rows_into_tokens`` as one transposed grouped product: the rows
+    sorted by token, the tokens in blocks of ``block`` as its groups, and a
+    left side that says which token of its block a row belongs to — 0 or 1,
+    so every product is exact and a token's sum is float32."""
+    from jax.experimental.pallas.ops.tpu.megablox.gmm import tgmm
+
+    b, d = rows.shape
+    by_token, perm = lax.sort((tok, jnp.arange(b, dtype=jnp.int32)), num_keys=1)
+    blocks = jnp.arange(t // block, dtype=jnp.int32)
+    sizes = jnp.sum(by_token[:, None] // block == blocks, axis=0, dtype=jnp.int32)
+    which = (by_token % block == jnp.arange(block, dtype=jnp.int32)[:, None]).astype(rows.dtype)
+    out = tgmm(which, rows[perm], sizes, jnp.float32, _gmm_tiling, interpret=interpret)
+    return out.reshape(t, d)  # from [t / block, block, D]
+
+
+def _sum_rows_into_tokens(rows: jax.Array, tok: jax.Array, t: int) -> jax.Array:
+    """[B, D] rows -> [t, D] float32: token ``tok[r]`` receives ``rows[r]``.
+    On the MXU where the Pallas kernel serves the shapes; a row scatter-add
+    elsewhere (which the chip runs five times slower)."""
+    from tensorflowdistributedlearning_tpu.ops import pallas_kernels
+
+    block = math.gcd(t, _TOKEN_BLOCK)
+    if pallas_kernels.pallas_platform_ok() and gmm_kernel_serves(rows.shape[0], block, rows.shape[1]):
+        return _sum_rows_on_mxu(rows, tok, t, block)
+    return jax.ops.segment_sum(rows.astype(jnp.float32), tok, num_segments=t)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(2,))
+def _rows_to_tokens(rows: jax.Array, tok: jax.Array, t: int) -> jax.Array:
+    """``_sum_rows_into_tokens``; backward, each row takes its token's gradient."""
+    return _sum_rows_into_tokens(rows, tok, t)
+
+
+_rows_to_tokens.defvjp(  # the empty slice carries the rows' dtype
+    lambda rows, tok, t: (_sum_rows_into_tokens(rows, tok, t), (tok, rows[:0])),
+    lambda t, res, g: (g.astype(res[1].dtype)[res[0]], None),
 )
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
-def _rows_of_pairs(x: jax.Array, order: jax.Array, inverse: jax.Array, k: int):
-    """[T, D] tokens -> [T * k, D]: row r is the token of sorted pair r
-    (pair p = token p // k). Backward: un-sort, then sum a token's k pairs."""
-    return x[order // k]
+@jax.custom_vjp
+def _tokens_to_rows(x: jax.Array, tok: jax.Array) -> jax.Array:
+    """[T, D] tokens -> [B, D]: row r is token ``tok[r]``. Backward, the rows
+    summed into their tokens (``_rows_to_tokens``' other half)."""
+    return x[tok]
 
 
-def _rows_of_pairs_bwd(k, res, g):
-    inverse = res
-    back = g[inverse]
-    return back.reshape(-1, k, back.shape[-1]).sum(axis=1), None, None
+_tokens_to_rows.defvjp(  # the empty slice carries the number of tokens
+    lambda x, tok: (x[tok], (tok, x[:, :0])),
+    lambda res, g: (_sum_rows_into_tokens(g, res[0], res[1].shape[0]).astype(g.dtype), None),
+)
 
 
-_rows_of_pairs.defvjp(
-    lambda x, order, inverse, k: (x[order // k], inverse), _rows_of_pairs_bwd
+def _over_segment(rows, k, start, x, weights, w_gate, w_up, w_down, order, counts):
+    """The layer over the sorted pairs ``start`` to ``start + rows``: (their
+    part of the weighted sum [T, D] float32, held pairs among them not
+    computed)."""
+    # the held groups as far as they lie inside, then the rows no matrix serves
+    ends = jnp.clip(jnp.cumsum(counts) - start, 0, rows)
+    kept = ends[-1]
+    sizes = jnp.concatenate([jnp.diff(ends, prepend=0), (rows - kept)[None]])
+    pairs = lax.dynamic_slice(order, (start,), (rows,))
+    tok = pairs // k
+    live = jnp.arange(rows, dtype=jnp.int32) < kept
+    w_rows = jnp.where(live, weights.reshape(-1)[pairs], 0.0)
+    x_rows = _tokens_to_rows(x, tok)
+    gate = grouped_matmul(x_rows, w_gate, sizes)
+    up = grouped_matmul(x_rows, w_up, sizes)
+    # a pair's weight goes in here, over the experts' width, so that what the
+    # tokens receive is a plain sum of rows
+    hidden = jax.nn.silu(gate.astype(jnp.float32)) * up.astype(jnp.float32) * w_rows[:, None]
+    out_rows = grouped_matmul(hidden.astype(x.dtype), w_down, sizes)
+    out = _rows_to_tokens(out_rows, tok, x.shape[0])
+    written = jnp.any(out_rows[:, :_WRITTEN_LANES] != 0, axis=-1)
+    return out, jnp.sum(live & ~written, dtype=jnp.int32)
+
+
+def _loop_over_segments(rows, k, x, weights, w_gate, w_up, w_down, order, counts):
+    """``_over_segment`` over as many segments of ``rows`` sorted pairs as
+    hold the held ones — a loop whose length the device reads off ``counts``:
+    (the weighted sum, held pairs not computed)."""
+    n_held = jnp.sum(counts)
+    segments = _segments(n_held, rows)
+
+    def one(i, carry):
+        out, dropped = _over_segment(
+            rows, k, i * rows, x, weights, w_gate, w_up, w_down, order, counts
+        )
+        return carry[0] + out, carry[1] + dropped
+
+    start = jnp.zeros(x.shape, jnp.float32), jnp.zeros((), jnp.int32)
+    out, dropped = lax.fori_loop(0, segments, one, start)
+    return out, dropped + jnp.maximum(n_held - segments * rows, 0)
+
+
+def _loop_over_segments_bwd(rows, k, args, g):
+    *inputs, order, counts = args
+
+    def one(i, grads):
+        _, pull = jax.vjp(
+            lambda *d: _over_segment(rows, k, i * rows, *d, order, counts)[0], *inputs
+        )
+        return jax.tree.map(jnp.add, grads, pull(g[0]))
+
+    grads = lax.fori_loop(
+        0, _segments(jnp.sum(counts), rows), one, tuple(jnp.zeros_like(d) for d in inputs)
+    )
+    return (*grads, None, None)
+
+
+# differentiated as a whole: the backward pass is the same loop over the
+# segments' own backward passes, and keeps nothing but the inputs
+_experts_by_segment = jax.custom_vjp(_loop_over_segments, nondiff_argnums=(0, 1))
+_experts_by_segment.defvjp(
+    lambda rows, k, *args: (_loop_over_segments(rows, k, *args), args),
+    _loop_over_segments_bwd,
 )
 
 
@@ -316,39 +423,34 @@ def dropless_experts(
     of the weighted sum [T, D] float32, tokens routed to each held expert [E],
     pairs routed to a held expert that the grouped products did not compute).
 
-    That last count is read off the products' own output: a row of the sorted
-    buffer was computed if the down projection wrote something other than 0
-    into its first lanes (a row the kernel passes over is left 0), and the
-    rows that should have been are those of the held experts by the routing's
-    counts. 0 as long as the buffer holds every pair and the kernel visits
-    every group it is handed.
+    The T * k pairs are sorted on ``(expert - first_expert) mod
+    num_experts_total`` (stable, so a token's order inside an expert is its
+    arrival order): the held experts' pairs come first, grouped by expert.
+    The gather of their tokens, the three grouped products, the activation
+    and the sum back into the tokens run over one segment of
+    ``_segment_rows`` sorted rows at a time, for as many segments as hold the
+    held pairs (``_experts_by_segment``: a loop whose length the device reads
+    off the counts, so whatever the routing sends here is computed). In a
+    segment the rows past the held pairs form a last group that no matrix
+    serves and read 0.
 
-    All T * k pairs are sorted by expert (stable, so a token's order inside
-    an expert is its arrival order); the grouped products compute the rows of
-    the held experts and give 0 for the rest."""
+    A pair's weight is multiplied in before the down projection, so that the
+    tokens receive a plain sum of rows (``_sum_rows_into_tokens``).
+
+    The last count returned is read off the products' own output: a row of
+    the buffer was computed if the down projection wrote something other than
+    0 into its first lanes (a row the kernel passes over is left 0; so would a
+    pair of weight 0 be, which the softmax does not give), the rows that
+    should have been are the held pairs' by the routing's counts, and held
+    pairs past the last segment's end count too. 0 as long as the segments
+    hold every held pair and the kernel visits every group it is handed."""
     t, k = experts.shape
     held = w_gate.shape[0]
-    flat = experts.reshape(-1)
-    order = jnp.argsort(flat, stable=True).astype(jnp.int32)
-    inverse = jnp.zeros_like(order).at[order].set(
-        jnp.arange(order.shape[0], dtype=jnp.int32), unique_indices=True
-    )
-    group_sizes = jnp.bincount(flat, length=num_experts_total).astype(jnp.int32)
-    rows = _rows_of_pairs(x, order, inverse, k)  # [T*k, D]
-    gate = grouped_matmul(rows, w_gate, group_sizes, first_expert)
-    up = grouped_matmul(rows, w_up, group_sizes, first_expert)
-    hidden = (jax.nn.silu(gate.astype(jnp.float32)) * up.astype(jnp.float32)).astype(x.dtype)
-    out_rows = grouped_matmul(hidden, w_down, group_sizes, first_expert)
-    by_pair = _permute_rows(out_rows, inverse, order).reshape(t, k, -1)
-    local = experts - first_expert
-    mine = (local >= 0) & (local < held)
-    out = jnp.sum(
-        by_pair.astype(jnp.float32) * jnp.where(mine, weights, 0.0)[..., None], axis=1
-    )
-    counts = group_sizes[first_expert : first_expert + held]
-    start = jnp.sum(group_sizes[:first_expert])
-    row = jnp.arange(out_rows.shape[0], dtype=jnp.int32)
-    due = (row >= start) & (row < start + jnp.sum(counts))
-    written = jnp.any(out_rows[:, :_WRITTEN_LANES] != 0, axis=-1)
-    dropped = jnp.sum(due & ~written)
+    key = jnp.mod(experts.reshape(-1) - first_expert, num_experts_total)
+    order = jnp.argsort(key, stable=True).astype(jnp.int32)
+    counts = jnp.sum(key[:, None] == jnp.arange(held, dtype=key.dtype), axis=0, dtype=jnp.int32)
+    rows = _segment_rows(t * k, held, num_experts_total)
+    # whole segments, so that the last one's slice stays inside
+    order = jnp.pad(order, (0, -(t * k) % rows))
+    out, dropped = _experts_by_segment(rows, k, x, weights, w_gate, w_up, w_down, order, counts)
     return out, counts, dropped

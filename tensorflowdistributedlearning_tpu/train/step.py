@@ -469,6 +469,10 @@ class SequenceTask:
                 (per_expert.max(axis=1) / np.maximum(per_expert.mean(axis=1), 1e-9)).max()
             ), 4),
             "moe_expert_tokens": [[int(round(x)) for x in row] for row in per_expert],
+            # the sorted pair buffer, layer by layer: its rows (a step's mean)
+            # and the share of them that held pairs filled
+            "moe_buffer_rows": [round(x, 1) for x in vectors["moe/buffer_rows"]],
+            "moe_buffer_fill": [round(x, 4) for x in vectors["moe/buffer_fill"]],
             "attn_keys_per_query": {
                 "full_attention": round(scalars["attn/keys_per_query_full"], 2),
                 "sliding_attention": round(scalars["attn/keys_per_query_sliding"], 2),
@@ -491,16 +495,21 @@ class SequenceTask:
         """Mean states whose totals are the step's counters. Per target
         position: ``loss``, ``metrics/top1``. Per sequence: ``tokens`` (target
         positions), ``moe/expert_tokens`` ([layers, experts held]: tokens
-        routed to each), ``moe/pairs_dropped``. Per position:
+        routed to each), ``moe/pairs_dropped``. Per step, by layer:
+        ``moe/buffer_rows`` (rows of the sorted pair buffer); per buffer row,
+        by layer: ``moe/buffer_fill`` (held pairs). Per position:
         ``attn/keys_per_query_*`` by layer type."""
         mean = metrics_lib.Mean
         targets, rows = outputs["n_targets"], outputs["n_sequences"]
+        buffer_rows = outputs["buffer_rows"]
         return {
             "loss": mean(outputs["loss_sum"], targets),
             "metrics/top1": mean(outputs["n_correct"], targets),
             "tokens": mean(targets, rows),
             "moe/expert_tokens": mean(outputs["expert_tokens"], rows),
             "moe/pairs_dropped": mean(outputs["pairs_dropped"], rows),
+            "moe/buffer_rows": mean(buffer_rows, jnp.ones((), jnp.float32)),
+            "moe/buffer_fill": mean(jnp.sum(outputs["expert_tokens"], axis=1), buffer_rows),
             "attn/keys_per_query_full": mean(outputs["attn_keys_full"], outputs["n_positions"]),
             "attn/keys_per_query_sliding": mean(
                 outputs["attn_keys_sliding"], outputs["n_positions"]

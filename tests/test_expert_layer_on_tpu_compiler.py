@@ -1,0 +1,74 @@
+"""The decoder's expert layer at the cell's widths, compiled for a described
+TPU v5e (no chip attached): the compiler takes the grouped products and the
+row sum at those shapes, and a step that fits the first buffer size moves no
+tensor of all ``tokens x experts per token`` rows by the hidden or the
+experts' width, in one segment's program. One file, the topology inside a fixture: only the worker
+that is given this file loads the TPU's library."""
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from tensorflowdistributedlearning_tpu.ops import pallas_kernels
+from tensorflowdistributedlearning_tpu.parallel import expert as expert_lib
+
+T, K, D, F, HELD, TOTAL = 16384, 8, 2304, 896, 16, 64  # mellum2_12b_a2p5b_share4, 2 x 8,192 tokens
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    from jax.experimental import topologies
+
+    try:
+        topo = topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 - whatever keeps the compiler away
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture
+def as_on_a_tpu(monkeypatch):
+    monkeypatch.setattr(pallas_kernels, "pallas_platform_ok", lambda: True)
+    cache = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)  # a TPU entry cannot be read back here
+    yield
+    jax.config.update("jax_enable_compilation_cache", cache)
+
+
+def _compiled_text(fn, one_chip, *shapes):
+    args = [jax.ShapeDtypeStruct(s, d, sharding=one_chip) for s, d in shapes]
+    return jax.jit(fn).lower(*args).compile().as_text()
+
+
+def _kernels(text: str) -> int:
+    return text.count('custom_call_target="tpu_custom_call"')
+
+
+def test_the_row_sum_is_one_grouped_product(one_chip, as_on_a_tpu):
+    rows = expert_lib._segment_rows(T * K, HELD, TOTAL)
+    assert rows == 40960
+    text = _compiled_text(
+        lambda r, tok: expert_lib._sum_rows_into_tokens(r, tok, T), one_chip,
+        ((rows, D), jnp.bfloat16), ((rows,), jnp.int32))
+    # the one kernel, and no float32 copy of the rows as a scatter-add would take
+    assert _kernels(text) == 1 and f"f32[{rows},{D}]" not in text
+
+
+def test_a_segment_moves_no_full_size_rows(one_chip, as_on_a_tpu):
+    rows = expert_lib._segment_rows(T * K, HELD, TOTAL)
+
+    def loss(x, weights, w_gate, w_up, w_down, order, counts):
+        out, _ = expert_lib._over_segment(
+            rows, K, 0, x, weights, w_gate, w_up, w_down, order, counts)
+        return jnp.sum(out)
+
+    bf16, f32, s32 = jnp.bfloat16, jnp.float32, jnp.int32
+    text = _compiled_text(
+        jax.value_and_grad(loss, argnums=range(5)), one_chip,
+        ((T, D), bf16), ((T, K), f32), ((HELD, D, F), bf16), ((HELD, D, F), bf16),
+        ((HELD, F, D), bf16), ((T * K,), s32), ((HELD,), s32))
+    # 3 products forward, 3 + 3 backward, the row sum forward and backward
+    assert _kernels(text) == 11
+    for full in (f"[{T * K},{D}]", f"[{T * K},{F}]"):
+        assert full not in text, full

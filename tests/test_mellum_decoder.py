@@ -164,34 +164,125 @@ def test_masked_reference_is_the_explicit_mask():
     np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=1e-5)
 
 
-@pytest.mark.parametrize("case", ["all_to_one_held", "none_held", "even"])
-def test_no_token_is_dropped_under_imbalance(case):
-    t, d, f, held, total, k = 48, 16, 8, 4, 8, 2
-    key = jax.random.key(7)
-    x = jax.random.normal(key, (t, d))
-    w_gate = jax.random.normal(jax.random.fold_in(key, 1), (held, d, f))
-    w_up = jax.random.normal(jax.random.fold_in(key, 2), (held, d, f))
-    w_down = jax.random.normal(jax.random.fold_in(key, 3), (held, f, d))
-    first = 4  # the second share of two holds experts 4..7
-    if case == "all_to_one_held":
-        experts = jnp.tile(jnp.asarray([[5, 0]], jnp.int32), (t, 1))
-    elif case == "none_held":
-        experts = jnp.tile(jnp.asarray([[0, 3]], jnp.int32), (t, 1))
-    else:
-        experts = jnp.stack([jnp.arange(t) % total, (jnp.arange(t) + 3) % total], 1).astype(jnp.int32)
-    weights_ = jnp.full((t, k), 0.5)
-    out, counts, dropped = expert_lib.dropless_experts(
-        x, weights_, experts, w_gate, w_up, w_down, num_experts_total=total, first_expert=first)
-    want = jnp.zeros((t, d))
-    for e in range(held):
+def _dense_experts(x, weights_, experts, w_gate, w_up, w_down, first):
+    """Every held expert over every token, weighted by the routing."""
+    want = jnp.zeros(x.shape)
+    for e in range(w_gate.shape[0]):
         y = (jax.nn.silu(x @ w_gate[e]) * (x @ w_up[e])) @ w_down[e]
         want = want + y * jnp.sum(jnp.where(experts == first + e, weights_, 0.0), 1)[:, None]
+    return want
+
+
+# (tokens, experts held, of, the buffer's rows expected, the routing). The
+# first three have 96 pairs, under a tile: one segment of them all. The last
+# three route 8,192 pairs in segments of 2,560 rows and take one, two and four
+# of them: the expected share (2,048 held), 1.5 times it, everything to one
+# held expert.
+_IMBALANCE = {
+    "all_to_one_held": (48, 4, 8, 96, lambda t: jnp.tile(jnp.asarray([[5, 0]]), (t, 1))),
+    "none_held": (48, 4, 8, 0, lambda t: jnp.tile(jnp.asarray([[0, 3]]), (t, 1))),
+    "even": (48, 4, 8, 96,
+             lambda t: jnp.stack([jnp.arange(t) % 8, (jnp.arange(t) + 3) % 8], 1)),
+    "segments_1_expected_share": (4096, 2, 8, 2560, lambda t: jnp.where(
+        (jnp.arange(t) < 2048)[:, None], jnp.stack([4 + jnp.arange(t) % 2, jnp.arange(t) % 4], 1),
+        jnp.asarray([[0, 1]]))),
+    "segments_2_half_over": (4096, 2, 8, 5120, lambda t: jnp.where(
+        (jnp.arange(t) < 3072)[:, None], jnp.stack([jnp.arange(t) % 4, 5 - jnp.arange(t) % 2], 1),
+        jnp.asarray([[7, 2]]))),
+    "segments_4_all_to_one_held": (4096, 2, 8, 10240,
+                                   lambda t: jnp.tile(jnp.asarray([[5, 5]]), (t, 1))),
+}
+
+
+@pytest.mark.parametrize("case", sorted(_IMBALANCE))
+def test_no_token_is_dropped_under_imbalance(case):
+    """Output, counts, the drop counter and every gradient against the dense
+    per-expert reference, over one and over several segments of the sorted
+    pair buffer."""
+    t, held, total, buffer_rows, routing = _IMBALANCE[case]
+    d, f, k, first = 16, 8, 2, 4  # the second share holds experts 4..
+    key = jax.random.key(7)
+    x = jax.random.normal(key, (t, d))
+    mats = [jax.random.normal(jax.random.fold_in(key, i), shape) / math.sqrt(shape[1])
+            for i, shape in enumerate([(held, d, f), (held, d, f), (held, f, d)], 1)]
+    weights_ = jax.random.uniform(jax.random.fold_in(key, 4), (t, k), minval=0.2, maxval=0.8)
+    probe = jax.random.normal(jax.random.fold_in(key, 5), (t, d))
+    experts = routing(t).astype(jnp.int32)
+
+    def program(x, weights_, *mats):
+        out, counts, dropped = expert_lib.dropless_experts(
+            x, weights_, experts, *mats, num_experts_total=total, first_expert=first)
+        return jnp.sum(out * probe), (out, counts, dropped)
+
+    def dense(x, weights_, *mats):
+        return jnp.sum(_dense_experts(x, weights_, experts, *mats, first) * probe)
+
+    (_, (out, counts, dropped)), grads = jax.jit(
+        jax.value_and_grad(program, argnums=range(5), has_aux=True))(x, weights_, *mats)
+    want = _dense_experts(x, weights_, experts, *mats, first)
     np.testing.assert_allclose(np.asarray(out), np.asarray(want), atol=1e-4)
     assert int(dropped) == 0
-    expected = {"all_to_one_held": [0, t, 0, 0], "none_held": [0, 0, 0, 0]}.get(case)
-    if expected is not None:
-        assert np.asarray(counts).tolist() == expected
-    assert int(counts.sum()) == int(((experts >= first) & (experts < first + held)).sum())
+    mine = (experts >= first) & (experts < first + held)
+    assert np.asarray(counts).tolist() == [
+        int((experts == first + e).sum()) for e in range(held)]
+    assert int(expert_lib.pair_buffer_rows(counts, t * k, total)) == buffer_rows
+    assert int(mine.sum()) <= buffer_rows
+    want_grads = jax.grad(dense, argnums=range(5))(x, weights_, *mats)
+    for name, got, ref in zip(["x", "weights", "w_gate", "w_up", "w_down"], grads, want_grads):
+        scale = max(float(jnp.max(jnp.abs(ref))), 1e-6)
+        np.testing.assert_allclose(
+            np.asarray(got) / scale, np.asarray(ref) / scale, atol=2e-5, err_msg=name)
+
+
+def test_rows_are_summed_into_tokens_as_a_grouped_product():
+    """The transposed grouped product that sums a buffer's rows into their
+    tokens on a TPU (interpret mode here) against the scatter-add: a crowded
+    token, tokens with no row, an empty last block of tokens."""
+    t, b, d, block = 1024, 1536, 256, 256
+    key = jax.random.key(5)
+    rows = jax.random.normal(key, (b, d), jnp.bfloat16)
+    tok = jax.random.randint(jax.random.fold_in(key, 1), (b,), 0, t)
+    tok = jnp.where(tok % 7 == 0, 5, tok)
+    tok = jnp.where(tok >= 768, tok - 768 + 100, tok)
+    got = expert_lib._sum_rows_on_mxu(rows, tok, t, block, interpret=True)
+    want = jax.ops.segment_sum(rows.astype(jnp.float32), tok, num_segments=t)
+    assert got.dtype == jnp.float32 and float(jnp.max(jnp.abs(want[768:]))) == 0.0
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want), rtol=1e-6, atol=1e-5)
+
+
+def test_the_segment_is_the_expected_share_and_a_quarter():
+    """1.25 times the pairs expected here in whole tiles, at most all of them
+    (where every expert is held, or the pairs are under a tile), and as many
+    segments as hold the held pairs."""
+    assert expert_lib._segment_rows(16384 * 8, 16, 64) == 40960
+    assert expert_lib._segment_rows(16384 * 8, 64, 64) == 131072
+    assert expert_lib._segment_rows(8192, 2, 8) == 2560
+    assert expert_lib._segment_rows(96, 4, 8) == 96
+    rows = [int(expert_lib.pair_buffer_rows(jnp.asarray([n, 0]), 8192, 8))
+            for n in (0, 1, 2560, 2561, 5120, 5121, 8192)]
+    assert rows == [0, 2560, 2560, 5120, 5120, 7680, 10240]
+
+
+def test_a_buffer_too_small_counts_what_it_left_out(monkeypatch):
+    """Held pairs past the last segment's end are counted as dropped, and the
+    pairs the segments do hold are computed."""
+    t, d, f, held = 32, 16, 8, 4
+    key = jax.random.key(13)
+    x = jax.random.normal(key, (t, d))
+    mats = [jax.random.normal(jax.random.fold_in(key, i), shape)
+            for i, shape in enumerate([(held, d, f), (held, d, f), (held, f, d)])]
+    experts = jnp.tile(jnp.asarray([[5, 7]], jnp.int32), (t, 1))  # every pair is held here
+    weights_ = jnp.full((t, 2), 0.5)
+    # one segment of 40 rows where 64 pairs are held
+    monkeypatch.setattr(expert_lib, "_segment_rows", lambda pairs, held, total: 40)
+    monkeypatch.setattr(expert_lib, "_segments", lambda n_held, rows: jnp.minimum(n_held, 1))
+    out, counts, dropped = expert_lib.dropless_experts(
+        x, weights_, experts, *mats, num_experts_total=8, first_expert=4)
+    assert np.asarray(counts).tolist() == [0, t, 0, t] and int(dropped) == 2 * t - 40
+    # expert 5's 32 pairs and expert 7's first 8 fit
+    fit = jnp.where((jnp.arange(t) < 8)[:, None], experts, jnp.asarray([[5, -1]]))
+    want = _dense_experts(x, weights_, fit, *mats, 4)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(want), atol=1e-4)
 
 
 def test_the_drop_counter_reads_what_the_products_wrote(monkeypatch):
@@ -210,6 +301,32 @@ def test_the_drop_counter_reads_what_the_products_wrote(monkeypatch):
     _, counts, dropped = expert_lib.dropless_experts(
         x, jnp.full((t, 2), 0.5), experts, *mats, num_experts_total=8, first_expert=4)
     assert np.asarray(counts).tolist() == [0, t, 0, t] and int(dropped) == 7
+
+
+def test_the_windows_say_how_full_the_pair_buffer_was():
+    """``moe_buffer_rows`` is a step's mean of the rows worked over and
+    ``moe_buffer_fill`` the held pairs over those rows, layer by layer: two
+    steps of two layers, the second step taking a longer buffer on layer 1."""
+    from tensorflowdistributedlearning_tpu.train import step as step_lib
+
+    task = step_lib.SequenceTask(_model_config(_cfg(2, 1)).decoder, STREAM)
+    acc = None
+    for tokens_, rows_ in [([[900, 1100], [500, 1500]], [2560, 2560]),
+                           ([[1000, 1000], [2000, 1000]], [2560, 4096])]:
+        outputs = {k: jnp.asarray(1.0) for k in (
+            "loss_sum", "n_targets", "n_correct", "pairs_dropped", "attn_keys_full",
+            "attn_keys_sliding", "n_positions")}
+        outputs.update(n_sequences=jnp.asarray(2.0),
+                       expert_tokens=jnp.asarray(tokens_, jnp.float32),
+                       buffer_rows=jnp.asarray(rows_, jnp.float32))
+        deltas = task.metric_deltas(outputs, None)
+        acc = deltas if acc is None else {k: acc[k].merge(v) for k, v in deltas.items()}
+    scalars, vectors = step_lib.split_scalars(step_lib.compute_metrics(acc))
+    fields = task.window_fields(4, scalars, vectors, None)
+    assert fields["moe_buffer_rows"] == [2560.0, 3328.0]
+    assert fields["moe_buffer_fill"] == [round(4000 / 5120, 4), round(5000 / 6656, 4)]
+    assert all(0 < f <= 1 for f in fields["moe_buffer_fill"])
+    assert fields["moe_pairs"] == 9000
 
 
 def test_window_means_are_worked_out_on_the_host(monkeypatch):
@@ -332,6 +449,12 @@ def test_fit_trains_the_decoder_and_resumes(tmp_path):
         assert w["moe_pairs"] == sum(map(sum, w["moe_expert_tokens"]))
         assert len(w["moe_expert_tokens"]) == 4 and len(w["moe_expert_tokens"][0]) == 4
         assert 1.0 <= w["moe_load_max_over_mean"] <= 4.0
+        # 4 x 64 tokens x 2 experts a step, under a tile: one segment of them
+        # all, or none in a step that routes nothing to this share
+        assert all(0 <= r <= 512 for r in w["moe_buffer_rows"]) and max(w["moe_buffer_rows"]) == 512
+        assert [round(f * r * w["steps"]) for f, r in zip(
+            w["moe_buffer_fill"], w["moe_buffer_rows"])] == [
+            sum(row) for row in w["moe_expert_tokens"]]
         keys = w["attn_keys_per_query"]
         assert 1.0 <= keys["sliding_attention"] <= 8.0 <= keys["full_attention"]
         if w.get("images_per_sec"):
