@@ -13,6 +13,9 @@ import dataclasses
 from typing import Optional, Tuple
 
 
+DECODER_LAYER_TYPES = ("sliding_attention", "full_attention", "sparse_attention")
+
+
 @dataclasses.dataclass(frozen=True)
 class DecoderConfig:
     """A causal mixture-of-experts decoder (``backbone="decoder"``,
@@ -25,20 +28,32 @@ class DecoderConfig:
     ``num_key_value_heads`` key-value heads, ``num_experts`` experts and
     ``vocab_size`` rows of the vocabulary — the counts HELD HERE, a
     ``share_count``-th of the published ones — and is share ``share_index`` of
-    them. The router keeps its published width (``num_experts *
-    share_count`` outputs), its ``num_experts_per_tok`` and its
-    renormalisation over all chosen experts, held or not; the layer computes
-    the part of the attention and expert sums that its own heads and experts
-    give. That partial result is what the layer returns: the all-reduce over
-    the shares that completes it is the exchange, and on one chip the layer
-    runs without it (``share_count=1`` is the whole model and needs none)."""
+    them. One exception: where the published key-value heads are fewer than
+    the shares (4 heads over 8 chips), a key-value head is held by several
+    shares, each with its own part of that head's query heads, and
+    ``num_key_value_heads`` held is 1. The router keeps its published width
+    (``num_experts * share_count`` outputs), its ``num_experts_per_tok`` and
+    its renormalisation over all chosen experts, held or not; the layer
+    computes the part of the attention and expert sums that its own heads and
+    experts give. That partial result is what the layer returns: the
+    all-reduce over the shares that completes it is the exchange, and on one
+    chip the layer runs without it (``share_count=1`` is the whole model and
+    needs none). What every share holds whole — norms, the router, a
+    ``sparse_attention`` layer's indexer — is computed alike on each.
+
+    **Layer types.** ``sliding_attention`` (plain RoPE, a window),
+    ``full_attention`` (every earlier key of the document) and
+    ``sparse_attention``: a query reads only the ``sa_config["topk"]`` keys a
+    learned indexer scores highest (ops/sparse_attention.py), and the indexer
+    is trained by a loss of its own (train/step.py:SequenceTask)."""
 
     hidden_size: int = 2304
     head_dim: int = 128
     num_attention_heads: int = 32
     num_key_value_heads: int = 4
     num_hidden_layers: int = 28
-    # one entry per layer: "sliding_attention" | "full_attention"
+    # one entry per layer: "sliding_attention" | "full_attention" |
+    # "sparse_attention"
     layer_types: Tuple[str, ...] = (
         ("sliding_attention",) * 3 + ("full_attention",)
     ) * 7
@@ -60,6 +75,12 @@ class DecoderConfig:
         )),
         ("sliding_attention", (("rope_theta", 500000), ("rope_type", "default"))),
     )
+    # the indexer of "sparse_attention" layers, under the published keys
+    # (indexer_num_heads, indexer_head_dim, indexer_num_kv_heads, topk; chunk
+    # sizes are read as tiles and change no result): sorted (key, value) pairs
+    sa_config: Tuple[Tuple[str, int], ...] = ()
+    # per-head RMS normalisation of q and k with a learned [head_dim] scale
+    use_qk_norm: bool = False
     share_count: int = 1
     share_index: int = 0
     # tokens of one packed training sequence (data/tokens.py)
@@ -71,9 +92,16 @@ class DecoderConfig:
                 f"layer_types names {len(self.layer_types)} layers, "
                 f"num_hidden_layers is {self.num_hidden_layers}"
             )
-        unknown = set(self.layer_types) - {"sliding_attention", "full_attention"}
+        unknown = set(self.layer_types) - set(DECODER_LAYER_TYPES)
         if unknown:
             raise ValueError(f"Unknown layer types {sorted(unknown)}")
+        if "sparse_attention" in self.layer_types:
+            sa = self.indexer
+            missing = {"indexer_num_heads", "indexer_head_dim", "topk"} - set(sa)
+            if missing:
+                raise ValueError(f"sparse_attention layers need sa_config keys {sorted(missing)}")
+            if sa.get("indexer_num_kv_heads", 1) != 1:
+                raise ValueError("the indexer has one key head (indexer_num_kv_heads 1)")
         if self.num_attention_heads % self.num_key_value_heads:
             raise ValueError("num_attention_heads must be a multiple of num_key_value_heads")
         if not 0 <= self.share_index < self.share_count:
@@ -89,17 +117,31 @@ class DecoderConfig:
         passed over); ``share`` gives share_count, share_index,
         sequence_length."""
         names = {f.name for f in dataclasses.fields(cls)}
-        kept = {k: v for k, v in config.items() if k in names}
+        # a published null (Keye's sliding_window) says nothing: the default stays
+        kept = {k: v for k, v in config.items() if k in names and v is not None}
         kept["layer_types"] = tuple(config["layer_types"])
+        rope = config.get("rope_parameters")
+        if rope is None:
+            # the older keys: one theta and one scaling for every layer
+            scaling = {k: v for k, v in (config.get("rope_scaling") or {}).items()
+                       if k not in ("type", "mrope_section")}
+            scaling.setdefault("rope_type", "default")
+            scaling["rope_theta"] = config["rope_theta"]
+            rope = {kind: scaling for kind in set(kept["layer_types"])}
         kept["rope_parameters"] = tuple(
-            (kind, tuple(sorted(params.items())))
-            for kind, params in sorted(config["rope_parameters"].items())
+            (kind, tuple(sorted(params.items()))) for kind, params in sorted(rope.items())
         )
+        if "sa_config" in config:
+            kept["sa_config"] = tuple(sorted(config["sa_config"].items()))
         kept.update(share)
         return cls(**kept)
 
     def rope(self, layer_type: str) -> dict:
         return dict(dict(self.rope_parameters)[layer_type])
+
+    @property
+    def indexer(self) -> dict:
+        return dict(self.sa_config)
 
 
 @dataclasses.dataclass(frozen=True)

@@ -12,7 +12,12 @@ from __future__ import annotations
 import dataclasses
 from typing import Dict, Tuple
 
-from tensorflowdistributedlearning_tpu.config import DecoderConfig, ModelConfig, TrainConfig
+from tensorflowdistributedlearning_tpu.config import (
+    DecoderConfig,
+    ModelConfig,
+    TokenStreamConfig,
+    TrainConfig,
+)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -283,6 +288,63 @@ PRESETS: Dict[str, Preset] = {
         "attention 3:1, YaRN), next-token training on packed 8,192-token "
         "sequences: chip 0's share of a layer divided over 4 chips, 4 of 28 "
         "layers — a partial result by design (config.py:DecoderConfig)",
+    ),
+    # Keye-VL-2.0-30B-A3B's language model (model_type KeyeVL2,
+    # https://huggingface.co/Kwai-Keye/Keye-VL-2.0-30B-A3B/blob/main/config.json;
+    # the published keys are in perfbench/configs/keye_vl2_30b_a3b_share8.json):
+    # every layer is grouped-query attention over the 2,048 keys a learned
+    # indexer picks (DeepSeek sparse attention), then 128 routed experts, 8 a
+    # token. Published widths; chip 0's share of a layer divided over 8 chips
+    # (4 of 32 query heads on 1 of 4 key-value heads — a key-value head is
+    # held by two shares — 16 of 128 experts, 18,992 of 151,936 vocabulary
+    # rows; the indexer and the router whole), 4 of the 48 alike layers —
+    # 400.4M parameters here, 6.4 GB with gradients and Adam's moments.
+    # Assumed (the config has no key): q/k RMS normalisation, the indexer's
+    # LayerNorm, rotary embedding and scales as DeepSeek's implementation has
+    # them, its alignment loss at weight 1, AdamW 3e-7 / 0.1.
+    "keye_vl2_30b_a3b_share8": Preset(
+        model=ModelConfig(
+            backbone="decoder",
+            dtype="bfloat16",
+            decoder=DecoderConfig(
+                hidden_size=2048,
+                num_hidden_layers=4,
+                layer_types=("sparse_attention",) * 48,
+                num_attention_heads=4,
+                num_key_value_heads=1,
+                num_experts=16,
+                moe_intermediate_size=768,
+                vocab_size=18992,
+                rope_parameters=(
+                    ("sparse_attention", (("rope_theta", 10000000), ("rope_type", "default"))),
+                ),
+                sa_config=(
+                    ("indexer_head_dim", 64), ("indexer_num_heads", 16),
+                    ("indexer_num_kv_heads", 1), ("kv_chunk_size", 512),
+                    ("q_chunk_size", 512), ("topk", 2048),
+                ),
+                use_qk_norm=True,
+                share_count=8,
+                share_index=0,
+                sequence_length=16384,
+            ),
+        ),
+        # the rate is small for the reason the Mellum-2 preset gives; documents
+        # are long, so that most queries see more keys than they may read
+        # a window every 10 steps: a step takes half a second
+        train=TrainConfig(
+            optimizer="adam", lr=3e-7, weight_decay=0.1, augmentation="none",
+            train_log_every_steps=10,
+            token_stream=TokenStreamConfig(
+                median_length=16384.0, sigma=1.0, min_length=1024, max_length=16384
+            ),
+        ),
+        global_batch=1,
+        description="Keye-VL-2.0-30B-A3B language model (attention over the 2,048 keys "
+        "a learned indexer picks, top-8-of-128 experts), next-token training on "
+        "packed 16,384-token sequences of long documents: chip 0's share of a "
+        "layer divided over 8 chips, 4 of 48 layers — a partial result by "
+        "design (config.py:DecoderConfig)",
     ),
 }
 

@@ -7,9 +7,16 @@ family (https://huggingface.co/JetBrains/Mellum2-12B-A2.5B-Instruct,
 over packed documents.
 
 Layer: ``h = x + Attn(RMSNorm(x))``, ``y = h + MoE(RMSNorm(h))``. Attention is
-grouped-query with a rotary embedding whose parameters go by layer type
-(``sliding_attention``: plain RoPE and a window; ``full_attention``: YaRN),
-causal and inside one document (ops/blocked_attention.py). Every MLP is
+grouped-query, causal and inside one document, with a rotary embedding whose
+parameters go by layer type (``DecoderConfig.rope_parameters``) and optionally
+a per-head RMS normalisation of q and k. What a query reads goes by layer type
+too: every earlier key (``full_attention``) or those inside a window
+(``sliding_attention``), both ops/blocked_attention.py; or the ``topk`` keys a
+learned indexer scores highest (``sparse_attention``, ops/sparse_attention.py:
+the Keye-VL-2.0 family's layer,
+https://huggingface.co/Kwai-Keye/Keye-VL-2.0-30B-A3B), whose indexer reads the
+layer's normalised input cut from the graph and is trained by a loss of its
+own, returned beside the cross-entropy. Every MLP is
 sparse: a float32 router over all experts, ``num_experts_per_tok`` a token
 with renormalised weights, SiLU-gated experts, no token dropped
 (parallel/expert.py:dropless_experts). The head is untied; with targets the
@@ -42,6 +49,7 @@ import numpy as np
 from jax import lax
 
 from tensorflowdistributedlearning_tpu.config import DecoderConfig, ModelConfig
+from tensorflowdistributedlearning_tpu.ops import sparse_attention as sparse_lib
 from tensorflowdistributedlearning_tpu.ops.blocked_attention import blocked_attention
 from tensorflowdistributedlearning_tpu.parallel import expert as expert_lib
 
@@ -55,15 +63,16 @@ INIT_TOKENS = 8
 _INIT = nn.initializers.normal(0.02)
 
 
-def rope_constants(cfg: DecoderConfig, layer_type: str) -> Tuple[np.ndarray, float]:
-    """(inv_freq [head_dim / 2] float32, the factor on cos and sin) of a layer
-    type, as ``transformers`` computes them: ``default`` is
+def rope_constants(cfg: DecoderConfig, layer_type: str, dim: int = 0) -> Tuple[np.ndarray, float]:
+    """(inv_freq [dim / 2] float32, the factor on cos and sin) of a layer
+    type over ``dim`` rotated dimensions (the head's by default), as
+    ``transformers`` computes them: ``default`` is
     theta^(-2i/d); ``yarn`` keeps the fast-rotating dimensions, divides the
     slow ones by ``factor``, blends linearly between the correction
     dimensions of ``beta_fast`` and ``beta_slow``, and scales cos and sin by
     ``attention_factor``."""
     rp = cfg.rope(layer_type)
-    dim, theta = cfg.head_dim, float(rp["rope_theta"])
+    dim, theta = dim or cfg.head_dim, float(rp["rope_theta"])
     inv = theta ** (-np.arange(0, dim, 2, dtype=np.float64) / dim)
     if rp["rope_type"] == "default":
         return inv.astype(np.float32), 1.0
@@ -104,20 +113,72 @@ class RMSNorm(nn.Module):
 
 
 class Projection(nn.Module):
-    """``x @ kernel`` with no bias: operands in ``dtype``, float32 out."""
+    """``x @ kernel`` with no bias: operands in ``dtype``, float32 out;
+    ``precise``: float32 operands at the highest precision, like the router."""
 
     features: int
     dtype: Any
+    precise: bool = False
 
     @nn.compact
     def __call__(self, x: jax.Array) -> jax.Array:
         kernel = self.param("kernel", _INIT, (x.shape[-1], self.features), jnp.float32)
+        if self.precise:
+            return jnp.dot(x.astype(jnp.float32), kernel, precision=lax.Precision.HIGHEST)
         return jnp.dot(
             x.astype(self.dtype), kernel.astype(self.dtype), preferred_element_type=jnp.float32
         )
 
 
+class LayerNorm(nn.Module):
+    eps: float
+
+    @nn.compact
+    def __call__(self, x: jax.Array) -> jax.Array:
+        scale = self.param("scale", nn.initializers.ones, (x.shape[-1],), jnp.float32)
+        bias = self.param("bias", nn.initializers.zeros, (x.shape[-1],), jnp.float32)
+        x = x.astype(jnp.float32)
+        centred = x - jnp.mean(x, axis=-1, keepdims=True)
+        var = jnp.mean(jnp.square(centred), axis=-1, keepdims=True)
+        return centred * lax.rsqrt(var + self.eps) * scale + bias
+
+
+class Indexer(nn.Module):
+    """What scores a ``sparse_attention`` layer's keys: per position
+    ``indexer_num_heads`` small queries, one small key (LayerNorm, rotated
+    like the queries, over all its dimensions) and a weight a head, from the
+    layer's normalised input cut from the graph. Products in ``dtype``; the
+    weights' in float32 like the router's."""
+
+    cfg: DecoderConfig
+    layer_type: str
+    dtype: Any
+
+    @nn.compact
+    def __call__(self, u, positions):
+        sa = self.cfg.indexer
+        heads, dim = sa["indexer_num_heads"], sa["indexer_head_dim"]
+        b, t, _ = u.shape
+        u = lax.stop_gradient(u)
+        qi = Projection(heads * dim, self.dtype, name="wq")(u).reshape(b, t, heads, dim)
+        ki = LayerNorm(1e-6, name="k_norm")(Projection(dim, self.dtype, name="wk")(u))
+        inv_freq, scale = rope_constants(self.cfg, self.layer_type, dim)
+        qi = apply_rope(qi, positions, inv_freq, scale).astype(self.dtype)
+        ki = apply_rope(ki[:, :, None, :], positions, inv_freq, scale)[:, :, 0].astype(self.dtype)
+        wi = Projection(heads, jnp.float32, precise=True, name="w")(u) * (heads**-0.5 * dim**-0.5)
+        return qi, ki, wi
+
+
+_SCOPES = {"sliding_attention": "decoder/attn_sliding", "full_attention": "decoder/attn_full",
+           "sparse_attention": "decoder/attn_sparse"}
+
+
 class DecoderAttention(nn.Module):
+    """``(attention's part of the residual update, extras)``: ``extras`` is
+    empty but on a ``sparse_attention`` layer, which adds ``align`` (the
+    indexer's loss summed over the positions) and ``reads`` (how many queries
+    read each key position [T])."""
+
     cfg: DecoderConfig
     layer_type: str
     dtype: Any
@@ -127,19 +188,31 @@ class DecoderAttention(nn.Module):
         cfg = self.cfg
         b, t, _ = u.shape
         hq, hkv, hd = cfg.num_attention_heads, cfg.num_key_value_heads, cfg.head_dim
-        sliding = self.layer_type == "sliding_attention"
-        with jax.named_scope("decoder/attn_sliding" if sliding else "decoder/attn_full"):
+        with jax.named_scope(_SCOPES[self.layer_type]):
             q = Projection(hq * hd, self.dtype, name="wq")(u).reshape(b, t, hq, hd)
             k = Projection(hkv * hd, self.dtype, name="wk")(u).reshape(b, t, hkv, hd)
             v = Projection(hkv * hd, self.dtype, name="wv")(u).reshape(b, t, hkv, hd)
+            if cfg.use_qk_norm:
+                q = RMSNorm(cfg.rms_norm_eps, name="q_norm")(q)
+                k = RMSNorm(cfg.rms_norm_eps, name="k_norm")(k)
             inv_freq, scale = rope_constants(cfg, self.layer_type)
             q = apply_rope(q, positions, inv_freq, scale).astype(self.dtype)
             k = apply_rope(k, positions, inv_freq, scale).astype(self.dtype)
-            out = blocked_attention(
-                q, k, v.astype(self.dtype), segment_ids,
-                window=cfg.sliding_window if sliding else None,
-            )
-            return Projection(cfg.hidden_size, self.dtype, name="wo")(out.reshape(b, t, hq * hd))
+            extras = {}
+            if self.layer_type == "sparse_attention":
+                qi, ki, wi = Indexer(cfg, self.layer_type, self.dtype, name="indexer")(
+                    u, positions)
+                out, extras["align"], extras["reads"] = sparse_lib.sparse_attention(
+                    q, k, v.astype(self.dtype), qi, ki, wi, segment_ids,
+                    topk=cfg.indexer["topk"],
+                )
+            else:
+                out = blocked_attention(
+                    q, k, v.astype(self.dtype), segment_ids,
+                    window=cfg.sliding_window if self.layer_type == "sliding_attention" else None,
+                )
+            out = Projection(cfg.hidden_size, self.dtype, name="wo")(out.reshape(b, t, hq * hd))
+            return out, extras
 
 
 class DecoderMoE(nn.Module):
@@ -179,13 +252,14 @@ class DecoderLayer(nn.Module):
     @nn.compact
     def __call__(self, x, segment_ids, positions):
         eps = self.cfg.rms_norm_eps
-        h = x + DecoderAttention(self.cfg, self.layer_type, self.dtype, name="attn")(
+        attended, extras = DecoderAttention(self.cfg, self.layer_type, self.dtype, name="attn")(
             RMSNorm(eps, name="attn_norm")(x), segment_ids, positions
         )
+        h = x + attended
         out, *counters = DecoderMoE(self.cfg, self.dtype, name="moe")(
             RMSNorm(eps, name="moe_norm")(h)
         )
-        return h + out, counters
+        return h + out, (counters, extras)
 
 
 class HeadLoss(nn.Module):
@@ -254,29 +328,53 @@ class MoEDecoder(nn.Module):
         )
         b, t = tokens.shape
         x = nn.Embed(cfg.vocab_size, cfg.hidden_size, embedding_init=_INIT, name="embed")(tokens)
-        layer_cls = nn.remat(DecoderLayer) if b * t >= REMAT_MIN_TOKENS else DecoderLayer
+        kinds = cfg.layer_types[: cfg.num_hidden_layers]
+        layer_cls = DecoderLayer
+        if b * t >= REMAT_MIN_TOKENS:
+            # a recomputed sparse layer keeps its selection's thresholds
+            policy = (jax.checkpoint_policies.save_only_these_names(sparse_lib.SELECT_NAME)
+                      if "sparse_attention" in kinds else None)
+            layer_cls = nn.remat(DecoderLayer, policy=policy)
         counts, buffer_rows, dropped = [], [], jnp.zeros((), jnp.int32)
-        for i in range(cfg.num_hidden_layers):
-            x, (c, d, r) = layer_cls(cfg, cfg.layer_types[i], dtype, name=f"layers_{i}")(
+        align, reads = jnp.zeros((), jnp.float32), []
+        for i, kind in enumerate(kinds):
+            x, ((c, d, r), extras) = layer_cls(cfg, kind, dtype, name=f"layers_{i}")(
                 x, segment_ids, positions
             )
             counts.append(c)
             buffer_rows.append(r)
             dropped = dropped + d
+            if extras:
+                align = align + extras["align"]
+                reads.append(extras["reads"])
         x = RMSNorm(cfg.rms_norm_eps, name="final_norm")(x)
         if "targets" not in inputs:
             return {"hidden": x}
         out = HeadLoss(cfg.vocab_size, dtype, name="head")(x, inputs["targets"])
-        # keys a query sees, by layer type: its document's earlier positions
-        # and itself, inside the window on sliding layers
+        # keys a query reads, summed over the positions, by the layer types
+        # the model has: its document's earlier positions and itself; inside
+        # the window on sliding layers; the selection on sparse layers (a
+        # layer's mean)
         seen = positions.astype(jnp.float32) + 1.0
+        attn_keys = {
+            "full_attention": lambda: jnp.sum(seen),
+            "sliding_attention": lambda: jnp.sum(jnp.minimum(seen, float(cfg.sliding_window))),
+            "sparse_attention": lambda: jnp.sum(jnp.stack(reads)) / len(reads),
+        }
         out.update(
             expert_tokens=jnp.stack(counts).astype(jnp.float32),
             pairs_dropped=dropped.astype(jnp.float32),
             buffer_rows=jnp.stack(buffer_rows).astype(jnp.float32),
-            attn_keys_full=jnp.sum(seen),
-            attn_keys_sliding=jnp.sum(jnp.minimum(seen, float(cfg.sliding_window))),
+            **{f"attn_keys_{kind.split('_')[0]}": attn_keys[kind]() for kind in sorted(set(kinds))},
             n_sequences=jnp.asarray(b, jnp.float32),
             n_positions=jnp.asarray(b * t, jnp.float32),
         )
+        if "sparse_attention" in kinds:
+            # the indexer's loss, the pairs its layers scored, and how many
+            # queries read each key position, by sparse layer [layers, T]
+            out.update(
+                align_sum=align,
+                sparse_pairs_scored=jnp.sum(seen) * len(reads),
+                sparse_key_reads=jnp.stack(reads),
+            )
         return out

@@ -474,10 +474,17 @@ class SequenceTask:
             "moe_buffer_rows": [round(x, 1) for x in vectors["moe/buffer_rows"]],
             "moe_buffer_fill": [round(x, 4) for x in vectors["moe/buffer_fill"]],
             "attn_keys_per_query": {
-                "full_attention": round(scalars["attn/keys_per_query_full"], 2),
-                "sliding_attention": round(scalars["attn/keys_per_query_sliding"], 2),
+                kind: round(scalars[f"attn/keys_per_query_{kind.split('_')[0]}"], 2)
+                for kind in sorted(set(self.decoder.layer_types[: self.decoder.num_hidden_layers]))
             },
         }
+        if "align_loss" in scalars:
+            # the indexer's own loss, and a window's (query, key) pairs its
+            # layers scored (the visible ones) and selected
+            fields["align_loss"] = round(scalars["align_loss"], 6)
+            fields["sparse_pairs_scored"] = int(round(scalars["sparse/pairs_scored"] * sequences))
+            fields["sparse_pairs_selected"] = int(round(
+                float(np.sum(vectors["sparse/key_reads"])) * sequences))
         if images_per_sec is not None:
             fields["tokens_per_sec"] = round(images_per_sec * self.decoder.sequence_length, 2)
         return fields
@@ -489,7 +496,13 @@ class SequenceTask:
         # the mean over the GLOBAL batch's target positions: the shards'
         # gradients are averaged afterwards, so each divides by the mean count
         n = jax.lax.pmean(outputs["n_targets"], BATCH_AXIS)
-        return outputs["loss_sum"] / jnp.maximum(n, 1.0)
+        loss = outputs["loss_sum"] / jnp.maximum(n, 1.0)
+        if "align_sum" in outputs:
+            # the indexer's loss, a mean over the positions, weight 1: inside
+            # the model its graph and the cross-entropy's are cut from each
+            # other, so each leaf's gradient is one loss's alone
+            loss = loss + outputs["align_sum"] / jax.lax.pmean(outputs["n_positions"], BATCH_AXIS)
+        return loss
 
     def metric_deltas(self, outputs: Dict[str, jax.Array], batch) -> Metrics:
         """Mean states whose totals are the step's counters. Per target
@@ -498,10 +511,20 @@ class SequenceTask:
         routed to each), ``moe/pairs_dropped``. Per step, by layer:
         ``moe/buffer_rows`` (rows of the sorted pair buffer); per buffer row,
         by layer: ``moe/buffer_fill`` (held pairs). Per position:
-        ``attn/keys_per_query_*`` by layer type."""
+        ``attn/keys_per_query_*`` by layer type and, where layers are sparse,
+        ``align_loss`` (the indexer's loss; ``loss`` stays the cross-entropy);
+        per sequence ``sparse/pairs_scored`` and ``sparse/key_reads`` ([sparse
+        layers, T]: queries that read each key position)."""
         mean = metrics_lib.Mean
         targets, rows = outputs["n_targets"], outputs["n_sequences"]
         buffer_rows = outputs["buffer_rows"]
+        sparse = {}
+        if "align_sum" in outputs:
+            sparse = {
+                "align_loss": mean(outputs["align_sum"], outputs["n_positions"]),
+                "sparse/pairs_scored": mean(outputs["sparse_pairs_scored"], rows),
+                "sparse/key_reads": mean(outputs["sparse_key_reads"], rows),
+            }
         return {
             "loss": mean(outputs["loss_sum"], targets),
             "metrics/top1": mean(outputs["n_correct"], targets),
@@ -510,10 +533,11 @@ class SequenceTask:
             "moe/pairs_dropped": mean(outputs["pairs_dropped"], rows),
             "moe/buffer_rows": mean(buffer_rows, jnp.ones((), jnp.float32)),
             "moe/buffer_fill": mean(jnp.sum(outputs["expert_tokens"], axis=1), buffer_rows),
-            "attn/keys_per_query_full": mean(outputs["attn_keys_full"], outputs["n_positions"]),
-            "attn/keys_per_query_sliding": mean(
-                outputs["attn_keys_sliding"], outputs["n_positions"]
-            ),
+            **{
+                "attn/keys_per_query_" + name[len("attn_keys_"):]: mean(keys, outputs["n_positions"])
+                for name, keys in outputs.items() if name.startswith("attn_keys_")
+            },
+            **sparse,
         }
 
 

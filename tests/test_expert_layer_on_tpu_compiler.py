@@ -72,3 +72,28 @@ def test_a_segment_moves_no_full_size_rows(one_chip, as_on_a_tpu):
     assert _kernels(text) == 11
     for full in (f"[{T * K},{D}]", f"[{T * K},{F}]"):
         assert full not in text, full
+
+
+def test_the_sparse_layer_is_eight_kernels_and_no_product_over_heads(one_chip, as_on_a_tpu):
+    """``ops/sparse_attention.py`` at keye_vl2_30b_a3b_share8's shapes (one
+    16,384-token sequence, 4 heads of 128 on one key-value head, an indexer of
+    16 x 64, topk 2,048): the compiler takes every kernel, forward and backward
+    (scores, threshold, attend, align; the scores' two and the attention's two
+    gradients), and nothing of ``[heads, T, T]`` stands in memory."""
+    from tensorflowdistributedlearning_tpu.ops import sparse_attention as sparse_lib
+
+    t, hq, hd, heads, dim, topk = 16384, 4, 128, 16, 64, 2048
+    assert sparse_lib.kernels_serve(t, hd, dim)
+
+    def loss(q, k, v, qi, ki, wi, seg):
+        out, align, reads = sparse_lib.sparse_attention(q, k, v, qi, ki, wi, seg, topk=topk)
+        return jnp.sum(out.astype(jnp.float32)) + align, reads
+
+    bf16, f32 = jnp.bfloat16, jnp.float32
+    text = _compiled_text(
+        jax.value_and_grad(loss, argnums=range(6), has_aux=True), one_chip,
+        ((1, t, hq, hd), bf16), ((1, t, 1, hd), bf16), ((1, t, 1, hd), bf16),
+        ((1, t, heads, dim), bf16), ((1, t, dim), bf16), ((1, t, heads), f32), ((1, t), jnp.int32))
+    assert _kernels(text) == 8
+    for product in (f"[{heads},{t},{t}]", f"[{t},{heads},{t}]", f"[{hq},{t},{t}]"):
+        assert product not in text, product

@@ -70,9 +70,18 @@ def kfold_run(tmp_path_factory):
         base_depth=8,
         width_multiplier=0.0625,
     )
-    with MonkeyPatch.context() as m:
-        m.setattr(obs_lib, "Telemetry", Kept)
-        trainer.train(ids, batch_size=8, steps=STEPS)
+    # the run has to compile: a test file before this one in the same worker
+    # may have built the same tiny programs, and the suite's disk cache serves
+    # them in under the ledger's 10 ms — then no compile event has a phase
+    jax.clear_caches()
+    disk_cache = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    try:
+        with MonkeyPatch.context() as m:
+            m.setattr(obs_lib, "Telemetry", Kept)
+            trainer.train(ids, batch_size=8, steps=STEPS)
+    finally:
+        jax.config.update("jax_enable_compilation_cache", disk_cache)
     (tel,) = built
     return model_dir, tel
 
