@@ -176,8 +176,9 @@ _SCOPES = {"sliding_attention": "decoder/attn_sliding", "full_attention": "decod
 class DecoderAttention(nn.Module):
     """``(attention's part of the residual update, extras)``: ``extras`` is
     empty but on a ``sparse_attention`` layer, which adds ``align`` (the
-    indexer's loss summed over the positions) and ``reads`` (how many queries
-    read each key position [T])."""
+    indexer's loss summed over the positions), ``reads`` (how many queries
+    read each key position [T]) and ``searched`` (the columns and the blocks
+    of rows the selection's searches ran over)."""
 
     cfg: DecoderConfig
     layer_type: str
@@ -202,10 +203,11 @@ class DecoderAttention(nn.Module):
             if self.layer_type == "sparse_attention":
                 qi, ki, wi = Indexer(cfg, self.layer_type, self.dtype, name="indexer")(
                     u, positions)
-                out, extras["align"], extras["reads"] = sparse_lib.sparse_attention(
+                out, *extra = sparse_lib.sparse_attention(
                     q, k, v.astype(self.dtype), qi, ki, wi, segment_ids,
                     topk=cfg.indexer["topk"],
                 )
+                extras = dict(zip(("align", "reads", "searched"), extra))
             else:
                 out = blocked_attention(
                     q, k, v.astype(self.dtype), segment_ids,
@@ -336,7 +338,7 @@ class MoEDecoder(nn.Module):
                       if "sparse_attention" in kinds else None)
             layer_cls = nn.remat(DecoderLayer, policy=policy)
         counts, buffer_rows, dropped = [], [], jnp.zeros((), jnp.int32)
-        align, reads = jnp.zeros((), jnp.float32), []
+        align, reads, searched = jnp.zeros((), jnp.float32), [], []
         for i, kind in enumerate(kinds):
             x, ((c, d, r), extras) = layer_cls(cfg, kind, dtype, name=f"layers_{i}")(
                 x, segment_ids, positions
@@ -347,6 +349,7 @@ class MoEDecoder(nn.Module):
             if extras:
                 align = align + extras["align"]
                 reads.append(extras["reads"])
+                searched.append(extras["searched"])
         x = RMSNorm(cfg.rms_norm_eps, name="final_norm")(x)
         if "targets" not in inputs:
             return {"hidden": x}
@@ -370,11 +373,14 @@ class MoEDecoder(nn.Module):
             n_positions=jnp.asarray(b * t, jnp.float32),
         )
         if "sparse_attention" in kinds:
-            # the indexer's loss, the pairs its layers scored, and how many
-            # queries read each key position, by sparse layer [layers, T]
+            # the indexer's loss, the pairs its layers scored, how many
+            # queries read each key position, by sparse layer [layers, T], and
+            # what the selections searched, all sparse layers together
             out.update(
                 align_sum=align,
                 sparse_pairs_scored=jnp.sum(seen) * len(reads),
                 sparse_key_reads=jnp.stack(reads),
+                sparse_select_columns=sum(x["columns"] for x in searched),
+                sparse_tie_blocks=sum(x["tie_blocks"] for x in searched),
             )
         return out

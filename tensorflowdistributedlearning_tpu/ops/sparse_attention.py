@@ -10,7 +10,13 @@ document that a small *indexer* scores highest — DeepSeek sparse attention
    by counting (32 counts over the row, no sort), and the position up to which
    keys that tie at the threshold are taken, found the same way (the earlier
    key wins). The two numbers say which keys a query reads; they pass no gradient
-   and are what a recomputed layer keeps (``SELECT_NAME``);
+   and are what a recomputed layer keeps (``SELECT_NAME``). The kernel path's
+   one kernel holds a block of rows in VMEM and runs every count over the
+   columns that block can see alone — up to its last row's own position, and
+   from the start of its first row's document where documents are packed —
+   and searches tie positions only for the groups of rows in which a row has
+   more keys at its threshold than places left: everywhere else every such
+   key is taken (position ``T - 1``), and the selection is the same;
 3. ``attend_selected``: softmax attention over the selection;
 4. ``align_loss``: ``sum_t KL(p_t || softmax_{S_t} I[t])`` with ``p_t`` the
    attention's own distribution summed over the heads held, L1-normalised and
@@ -24,8 +30,8 @@ and the loss's gradient to them stand in memory, and the selection is never
 written out — each kernel tells it from its tile of the scores and the two
 numbers a query, and the forward attention kernel counts, tile by tile, how
 many queries read each key. The attention kernels compute every visible tile
-and mask inside it (flash attention: forward, dq, dkv): a kernel that reads
-only the selected keys is the next step (ROADMAP A5). Any other shape or backend takes
+and mask inside it (flash attention: forward, dq, dkv): what reading only
+the selected keys would take is ROADMAP S8. Any other shape or backend takes
 the same mathematics in XLA, blocked over queries. The choice is by shape and
 backend, no flag.
 """
@@ -108,10 +114,12 @@ def _tie_positions(scores, tau, above, topk: int) -> jax.Array:
     taken: the ``topk - above``-th of them in position order (``above`` counts
     the row's keys above the threshold), found bit by bit by counting like the
     threshold itself; past the last position where the row has no more of them
-    than places (a row shorter than ``topk``). Searched for every row, needed
-    or not: float32 scores tie at the threshold in a few rows of nearly every
-    step, and a search that ran only then made a step's time follow the draw
-    (PERF.md section 6, PR 30)."""
+    than places (a row shorter than ``topk``). The XLA path's: searched for
+    every row, needed or not — float32 scores tie at the threshold in a few
+    rows of nearly every step, and a search over the whole ``[T, T]`` that ran
+    only then made a step's time follow the draw (PERF.md section 6, PR 30).
+    The kernel path searches the few rows that need it in VMEM
+    (``_select_kernel``), at a cost too small to show."""
     t = scores.shape[-1]
     ties, need = sortable(scores) == tau[:, None], topk - above
     idx = jnp.arange(t, dtype=jnp.int32)
@@ -197,8 +205,12 @@ def _align_xla(q, k, scores, mask):
 # ---------------------------------------------------------------------------
 
 _TILE_Q, _TILE_K = 256, 512
-# rows whose scores stand in VMEM while their thresholds are searched
-_SELECT_ROWS = 32
+# rows whose scores stand in VMEM while their thresholds are searched, the
+# chunks of columns a pass over them reads at a time where as many are left,
+# and the rows whose tie positions are searched together
+_SELECT_ROWS, _SELECT_WIDE, _TIE_ROWS = 128, 4, 8
+# what -inf is as an order-preserving integer: no score sorts below it
+_KEY_NEG_INF = np.int32(-(2**31) + 0x7FFFFF)
 _NT = (((1,), (1,)), ((), ()))  # a @ b.T
 
 
@@ -397,44 +409,143 @@ def _scores_bwd(interpret, res, g):
 _scores_kernel_path.defvjp(_scores_fwd, _scores_bwd)
 
 
-def _threshold_kernel(scores_ref, tau_ref, above_ref, keys_ref, *, topk):
-    """Rows of scores in VMEM: their thresholds (bit by bit, 32 counts), and
-    how many keys lie above each."""
-    keys_ref[...] = sortable(scores_ref[...])
-    rows = keys_ref.shape[0]
+def _counted_chunks(segment_ids, rows: int, chunk: int):
+    """Per block of ``rows`` queries the chunks of ``chunk`` key columns that
+    hold every key one of them sees, as (first, one past the last), each
+    [T / rows] int32. No query sees a key past itself; and where the ids never
+    fall (packed documents: equal ids are one run) none before the start of
+    the run its block's first query lies in. Ids that fall may come back
+    later, so they bound nothing from below."""
+    t = segment_ids.shape[0]
+    idx = jnp.arange(t, dtype=jnp.int32)
+    opens = jnp.concatenate([jnp.ones((1,), bool), segment_ids[1:] != segment_ids[:-1]])
+    run_start = lax.cummax(jnp.where(opens, idx, 0))
+    packed = jnp.all(segment_ids[1:] >= segment_ids[:-1])
+    first = jnp.where(packed, run_start[::rows] // chunk, 0)
+    return first, (idx[::rows] + rows + chunk - 1) // chunk
 
-    def count(flags):
-        return jnp.sum(flags.astype(jnp.int32), axis=1, keepdims=True)
 
-    def body(i, tau):
+def _select_kernel(first_ref, last_ref, scores_ref, tau_ref, tie_ref, keys_ref, need_ref, *,
+                   topk, chunk):
+    """A block of rows of scores in VMEM: their thresholds (bit by bit, 32
+    counts) and, where a row has more keys equal to its threshold than places
+    left for them, the position of the last one taken (bit by bit again, a
+    group of ``_TIE_ROWS`` rows at a time and only the groups with such a
+    row); ``T - 1``, every one of them, on all other rows. Every pass runs
+    over the chunks of columns the block's rows can see (``_counted_chunks``)
+    and no other: what lies outside is -inf by construction and is never read."""
+    from jax.experimental import pallas as pl
+
+    rows, t = keys_ref.shape
+    first, last = first_ref[pl.program_id(0)], last_ref[pl.program_id(0)]
+    lanes = math.gcd(chunk, 128)
+
+    def over_chunks(visit, carry):
+        """``visit(columns, carry)`` over the counted chunks, ``_SELECT_WIDE``
+        of them at a time while as many are left."""
+        def span(width):
+            return lambda c, x: visit(pl.ds(pl.multiple_of(c * chunk, chunk), width * chunk), x)
+
+        wide = min(_SELECT_WIDE, t // chunk)
+        spans = (last - first) // wide
+        carry = lax.fori_loop(0, spans, lambda n, x: span(wide)(first + n * wide, x), carry)
+        return lax.fori_loop(first + spans * wide, last, span(1), carry)
+
+    def to_keys(columns, carry):
+        keys_ref[:, columns] = sortable(scores_ref[:, columns])
+        return carry
+
+    over_chunks(to_keys, 0)
+
+    def count(flagged, group=slice(None), size=rows):
+        """Per row of the group, the counted columns that ``flagged(keys,
+        first column)`` marks."""
+        def add(columns, acc):
+            flags = flagged(keys_ref[group, columns], columns.start).astype(jnp.int32)
+            for k in range(0, columns.size, lanes):  # lanes side by side: no sum across them here
+                acc = acc + flags[:, k:k + lanes]
+            return acc
+
+        acc = over_chunks(add, jnp.zeros((size, lanes), jnp.int32))
+        return jnp.sum(acc, axis=1, keepdims=True)
+
+    def threshold_bit(i, tau):
         cand = tau + lax.shift_left(jnp.int32(1), 31 - i)
-        return jnp.where(count(keys_ref[...] >= cand) >= topk, cand, tau)
+        return jnp.where(count(lambda keys, _: keys >= cand) >= topk, cand, tau)
 
-    tau = lax.fori_loop(0, 32, body, jnp.full((rows, 1), _INT_MIN, jnp.int32))
+    tau = lax.fori_loop(0, 32, threshold_bit, jnp.full((rows, 1), _INT_MIN, jnp.int32))
+    if t >= topk:
+        # a row shorter than topk: with the columns not counted, all -inf, it has T keys
+        tau = jnp.maximum(tau, _KEY_NEG_INF)
     tau_ref[...] = tau
-    above_ref[...] = count(keys_ref[...] > tau)
+    need = topk - count(lambda keys, _: keys > tau)
+    # -inf is never taken, so a row whose threshold it is has nothing to search
+    surplus = (count(lambda keys, _: keys == tau) > need) & (tau > _KEY_NEG_INF)
+    places = jnp.where(surplus, need, 0)  # left on the rows to search; 0 on the others
+    need_ref[...] = places
+    tie_ref[...] = jnp.full((rows, 1), t - 1, jnp.int32)
+    bits = max((t - 1).bit_length(), 1)
+    size = math.gcd(rows, _TIE_ROWS)
+
+    def search_group(g, carry):
+        group = pl.ds(pl.multiple_of(g * size, size), size)
+        need = need_ref[group, :]
+
+        @pl.when(jnp.max(need) > 0)
+        def _():
+            tau = tau_ref[group, :]
+
+            def position_bit(i, pos):
+                cand = pos + lax.shift_left(jnp.int32(1), bits - 1 - i)
+
+                def earlier_ties(keys, start):
+                    cols = start + lax.broadcasted_iota(jnp.int32, keys.shape, 1)
+                    return (keys == tau) & (cols < cand)
+
+                return jnp.where(count(earlier_ties, group, size) < need, cand, pos)
+
+            pos = lax.fori_loop(0, bits, position_bit, jnp.zeros((size, 1), jnp.int32))
+            tie_ref[group, :] = jnp.where(need > 0, pos, t - 1)
+
+        return carry
+
+    @pl.when(jnp.max(places) > 0)
+    def _():
+        lax.fori_loop(0, rows // size, search_group, 0)
 
 
-def _threshold_pallas(scores, topk, interpret=False):
-    """scores [T, T] -> (threshold, keys above it), each [T]."""
+def _select_pallas(scores, segment_ids, topk, interpret=False):
+    """scores [T, T], segment_ids [T] -> (threshold [T] int32, tie position
+    [T] int32, what was searched (float32 each): ``columns``, the columns the
+    counts ran over, summed over the rows, and ``tie_blocks``, the groups of
+    ``_TIE_ROWS`` rows whose tie positions were searched)."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
     t = scores.shape[0]
-    rows = math.gcd(t, _SELECT_ROWS)
-    column = pl.BlockSpec((rows, 1), lambda i: (i, 0))
-    out = pl.pallas_call(
-        functools.partial(_threshold_kernel, topk=topk),
-        grid=(t // rows,),
-        in_specs=[pl.BlockSpec((rows, t), lambda i: (i, 0))],
-        out_specs=[column] * 2,
+    rows, chunk = math.gcd(t, _SELECT_ROWS), math.gcd(t, _TILE_K)
+    first, last = _counted_chunks(segment_ids, rows, chunk)
+    column = pl.BlockSpec((rows, 1), lambda i, first, last: (i, 0))
+    tau, tie_pos = pl.pallas_call(
+        functools.partial(_select_kernel, topk=topk, chunk=chunk),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(t // rows,),
+            in_specs=[pl.BlockSpec((rows, t), lambda i, first, last: (i, 0))],
+            out_specs=[column] * 2,
+            scratch_shapes=[pltpu.VMEM((rows, t), jnp.int32), pltpu.VMEM((rows, 1), jnp.int32)],
+        ),
         out_shape=[jax.ShapeDtypeStruct((t, 1), jnp.int32)] * 2,
-        scratch_shapes=[pltpu.VMEM((rows, t), jnp.int32)],
         compiler_params=None if interpret else _params("parallel"),
         interpret=interpret,
-        name="sparse_select_threshold",
-    )(scores)
-    return tuple(x[:, 0] for x in out)
+        name="sparse_select",
+    )(first, last, scores)
+    tau, tie_pos = tau[:, 0], tie_pos[:, 0]
+    # only a row whose ties were searched stops short of the last position
+    searched = jnp.any((tie_pos < t - 1).reshape(-1, math.gcd(rows, _TIE_ROWS)), axis=1)
+    work = {"columns": jnp.sum((last - first).astype(jnp.float32)) * (rows * chunk),
+            "tie_blocks": jnp.sum(searched.astype(jnp.float32))}
+    return tau, tie_pos, work
 
 
 def _attend_kernel(q_ref, k_ref, v_ref, s_ref, tau_ref, tie_ref, o_ref, lse_ref, lsei_ref,
@@ -730,7 +841,7 @@ _align_kernel_path.defvjp(_align_fwd, _align_bwd)
 
 def _sequence_kernels(q, k, v, qi, ki, wi, segment_ids, topk, interpret):
     """One sequence on the kernel path: (out [T, Hq, hd], the rows' KL summed,
-    times each key was selected [T])."""
+    times each key was selected [T], what the selection searched)."""
     t, hq, hd = q.shape
     hkv = k.shape[1]
     group = hq // hkv
@@ -738,8 +849,7 @@ def _sequence_kernels(q, k, v, qi, ki, wi, segment_ids, topk, interpret):
         scores = _scores_kernel_path(qi.transpose(1, 0, 2), ki, wi, segment_ids, interpret)
     with jax.named_scope("decoder/attn_sparse/select"):
         frozen = lax.stop_gradient(scores)
-        tau, above = _threshold_pallas(frozen, topk, interpret)
-        tie_pos = _tie_positions(frozen, tau, above, topk)
+        tau, tie_pos, work = _select_pallas(frozen, segment_ids, topk, interpret)
         tau, tie_pos = checkpoint_name(tau, SELECT_NAME), checkpoint_name(tie_pos, SELECT_NAME)
         tau = threshold_value(tau)
     qh = (q * (1.0 / math.sqrt(hd))).astype(q.dtype).reshape(t, hkv, group, hd)
@@ -755,7 +865,7 @@ def _sequence_kernels(q, k, v, qi, ki, wi, segment_ids, topk, interpret):
             *map(lax.stop_gradient, (qh.reshape(hq, t, hd), kh, jnp.concatenate(lse), lse_i[0])),
             scores, tau, tie_pos, interpret)
     out = jnp.stack(out).transpose(2, 0, 1, 3).reshape(t, hq, hd)
-    return out, align, reads[0]
+    return out, align, reads[0], work
 
 
 def _sequence_xla(q, k, v, qi, ki, wi, segment_ids, topk):
@@ -770,7 +880,10 @@ def _sequence_xla(q, k, v, qi, ki, wi, segment_ids, topk):
         out = _attend_xla(q, k, v, mask)
     with jax.named_scope("decoder/attn_sparse/align"):
         align = _align_xla(q, k, scores, mask)
-    return out, align, jnp.sum(mask.astype(jnp.float32), axis=0)
+    # every row's searches run over the whole row
+    t = q.shape[0]
+    work = {"columns": jnp.float32(t * t), "tie_blocks": jnp.float32(t // math.gcd(t, _TIE_ROWS))}
+    return out, align, jnp.sum(mask.astype(jnp.float32), axis=0), work
 
 
 def select(scores: jax.Array, topk: int) -> Tuple[jax.Array, jax.Array]:
@@ -791,7 +904,11 @@ def sparse_attention(q, k, v, qi, ki, wi, segment_ids, *, topk: int, interpret=N
     - the alignment loss summed over the batch's positions (float32): its
       gradient reaches qi, ki and wi only, and nothing else's gradient does;
     - how many queries selected each key position, summed over the batch
-      [T] (float32): its sum is the (query, key) pairs read.
+      [T] (float32): its sum is the (query, key) pairs read;
+    - what the selection searched, summed over the batch (float32 each):
+      ``columns``, the key columns its counts ran over, summed over the
+      queries, and ``tie_blocks``, the blocks of ``_TIE_ROWS`` queries
+      whose tie positions were searched.
 
     ``interpret``: None chooses by backend and shape; True or False forces the
     kernel path (interpreted on the CPU, for tests)."""
@@ -811,7 +928,7 @@ def sparse_attention(q, k, v, qi, ki, wi, segment_ids, *, topk: int, interpret=N
 
     args = (q, k, v, qi, ki, wi.astype(jnp.float32), segment_ids)
     if q.shape[0] == 1:  # no loop around one sequence
-        out, align, reads = one(tuple(x[0] for x in args))
-        return out[None], align, reads
-    out, align, reads = lax.map(one, args)
-    return out, jnp.sum(align), jnp.sum(reads, axis=0)
+        out, align, reads, work = one(tuple(x[0] for x in args))
+        return out[None], align, reads, work
+    out, align, reads, work = lax.map(one, args)
+    return out, jnp.sum(align), jnp.sum(reads, axis=0), jax.tree.map(jnp.sum, work)

@@ -485,6 +485,11 @@ class SequenceTask:
             fields["sparse_pairs_scored"] = int(round(scalars["sparse/pairs_scored"] * sequences))
             fields["sparse_pairs_selected"] = int(round(
                 float(np.sum(vectors["sparse/key_reads"])) * sequences))
+            # a sequence's, all sparse layers together: the key columns the
+            # selections' counts ran over, summed over the queries, and the
+            # blocks of rows whose tie positions were searched
+            fields["sparse_select_columns"] = int(round(scalars["sparse/select_columns"]))
+            fields["sparse_tie_blocks"] = round(scalars["sparse/tie_blocks"], 2)
         if images_per_sec is not None:
             fields["tokens_per_sec"] = round(images_per_sec * self.decoder.sequence_length, 2)
         return fields
@@ -513,8 +518,10 @@ class SequenceTask:
         by layer: ``moe/buffer_fill`` (held pairs). Per position:
         ``attn/keys_per_query_*`` by layer type and, where layers are sparse,
         ``align_loss`` (the indexer's loss; ``loss`` stays the cross-entropy);
-        per sequence ``sparse/pairs_scored`` and ``sparse/key_reads`` ([sparse
-        layers, T]: queries that read each key position)."""
+        per sequence ``sparse/pairs_scored``, ``sparse/key_reads`` ([sparse
+        layers, T]: queries that read each key position),
+        ``sparse/select_columns`` and ``sparse/tie_blocks`` (what the
+        selections searched, ops/sparse_attention.py)."""
         mean = metrics_lib.Mean
         targets, rows = outputs["n_targets"], outputs["n_sequences"]
         buffer_rows = outputs["buffer_rows"]
@@ -524,6 +531,8 @@ class SequenceTask:
                 "align_loss": mean(outputs["align_sum"], outputs["n_positions"]),
                 "sparse/pairs_scored": mean(outputs["sparse_pairs_scored"], rows),
                 "sparse/key_reads": mean(outputs["sparse_key_reads"], rows),
+                "sparse/select_columns": mean(outputs["sparse_select_columns"], rows),
+                "sparse/tie_blocks": mean(outputs["sparse_tie_blocks"], rows),
             }
         return {
             "loss": mean(outputs["loss_sum"], targets),

@@ -5,6 +5,8 @@ tensor of all ``tokens x experts per token`` rows by the hidden or the
 experts' width, in one segment's program. One file, the topology inside a fixture: only the worker
 that is given this file loads the TPU's library."""
 
+import re
+
 import jax
 import jax.numpy as jnp
 import pytest
@@ -79,21 +81,33 @@ def test_the_sparse_layer_is_eight_kernels_and_no_product_over_heads(one_chip, a
     16,384-token sequence, 4 heads of 128 on one key-value head, an indexer of
     16 x 64, topk 2,048): the compiler takes every kernel, forward and backward
     (scores, threshold, attend, align; the scores' two and the attention's two
-    gradients), and nothing of ``[heads, T, T]`` stands in memory."""
+    gradients), nothing of ``[heads, T, T]`` stands in memory, nothing but the
+    one kernel searches the selection, and a recomputed layer runs it once."""
     from tensorflowdistributedlearning_tpu.ops import sparse_attention as sparse_lib
 
     t, hq, hd, heads, dim, topk = 16384, 4, 128, 16, 64, 2048
     assert sparse_lib.kernels_serve(t, hd, dim)
 
     def loss(q, k, v, qi, ki, wi, seg):
-        out, align, reads = sparse_lib.sparse_attention(q, k, v, qi, ki, wi, seg, topk=topk)
-        return jnp.sum(out.astype(jnp.float32)) + align, reads
+        out, align, reads, searched = sparse_lib.sparse_attention(
+            q, k, v, qi, ki, wi, seg, topk=topk)
+        return jnp.sum(out.astype(jnp.float32)) + align, (reads, searched)
 
     bf16, f32 = jnp.bfloat16, jnp.float32
+    shapes = (((1, t, hq, hd), bf16), ((1, t, 1, hd), bf16), ((1, t, 1, hd), bf16),
+              ((1, t, heads, dim), bf16), ((1, t, dim), bf16), ((1, t, heads), f32),
+              ((1, t), jnp.int32))
     text = _compiled_text(
-        jax.value_and_grad(loss, argnums=range(6), has_aux=True), one_chip,
-        ((1, t, hq, hd), bf16), ((1, t, 1, hd), bf16), ((1, t, 1, hd), bf16),
-        ((1, t, heads, dim), bf16), ((1, t, dim), bf16), ((1, t, heads), f32), ((1, t), jnp.int32))
+        jax.value_and_grad(loss, argnums=range(6), has_aux=True), one_chip, *shapes)
     assert _kernels(text) == 8
     for product in (f"[{heads},{t},{t}]", f"[{t},{heads},{t}]", f"[{hq},{t},{t}]"):
         assert product not in text, product
+    # the selection is its kernel alone: no integers or flags a (query, key) pair beside it
+    for searched in (f"pred[{t},{t}]", f"s32[{t},{t}]"):
+        assert searched not in text, searched
+    # a recomputed layer keeps the selection's two numbers a query, so its
+    # backward pass runs scores, attention and loss again, not the search
+    kept = jax.checkpoint(
+        loss, policy=jax.checkpoint_policies.save_only_these_names(sparse_lib.SELECT_NAME))
+    text = _compiled_text(jax.value_and_grad(kept, argnums=range(6), has_aux=True), one_chip, *shapes)
+    assert _kernels(text) == 11 and len(re.findall(r"%sparse_select[.\d]* = ", text)) == 1

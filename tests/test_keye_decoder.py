@@ -160,7 +160,7 @@ def test_layer_equals_full_attention_where_topk_covers_the_documents(full_weight
     full, none = decoder_lib.DecoderAttention(full_cfg, "full_attention", jnp.float32).apply(
         {"params": {k: v for k, v in tree.items() if k != "indexer"}},
         u, batch["segment_ids"], batch["positions"])
-    assert none == {} and set(extras) == {"align", "reads"}
+    assert none == {} and set(extras) == {"align", "reads", "searched"}
     np.testing.assert_allclose(np.asarray(sparse), np.asarray(full), atol=2e-5)
     visible = np.asarray(batch["positions"]).astype(np.float64) + 1
     assert float(np.sum(extras["reads"])) == visible.sum()
@@ -200,8 +200,7 @@ def test_equal_scores_go_to_the_earlier_key():
     earlier positions are taken, as the reference's sort-based selection does."""
     rng = np.random.default_rng(0)
     t, topk = 64, 8
-    values = rng.integers(-2, 3, size=(t, t)).astype(np.float32)
-    values[values == 0] = rng.choice([0.0, -0.0], size=int((values == 0).sum()))
+    values = _tied(rng, (t, t))
     seen = np.tril(np.ones((t, t), bool))
     scores = jnp.asarray(np.where(seen, values, -np.inf))
     tau, tie = sparse_lib.select(scores, topk)
@@ -211,12 +210,131 @@ def test_equal_scores_go_to_the_earlier_key():
     for i in range(topk, t):
         order = sorted(range(i + 1), key=lambda j: (-values[i, j], j))  # stable: earlier first
         assert sorted(np.flatnonzero(mask[i])) == sorted(order[:topk])
-    # the kernel finds the same thresholds, and counts the keys around them
-    got, above = sparse_lib._threshold_pallas(scores, topk, interpret=True)
+    # the kernel finds the same thresholds and, on the rows whose ties it
+    # searched (those with more keys at the threshold than places left: by
+    # hand from the keys around the threshold), the same tie positions
+    got, got_tie, _ = sparse_lib._select_pallas(
+        scores, jnp.zeros((t,), jnp.int32), topk, interpret=True)
     np.testing.assert_array_equal(np.asarray(got), np.asarray(tau))
     value = np.asarray(sparse_lib.threshold_value(tau))[:, None]
     masked = np.where(seen, values, -np.inf)
-    np.testing.assert_array_equal(np.asarray(above)[topk:], (masked > value).sum(-1)[topk:])
+    need = topk - (masked > value).sum(-1)
+    surplus = ((masked == value).sum(-1) > need) & np.isfinite(value[:, 0])
+    assert surplus[topk:].any() and not surplus[:topk].any()
+    np.testing.assert_array_equal(np.asarray(got_tie) < t - 1, surplus)
+    np.testing.assert_array_equal(np.asarray(got_tie)[surplus], np.asarray(tie)[surplus])
+
+
+def _masked(values, seg):
+    """Scores as the indexer's kernel writes them: -inf wherever key s is not
+    visible to query t (later, or of another document)."""
+    t = len(seg)
+    seen = (np.arange(t)[None, :] <= np.arange(t)[:, None]) & (seg[:, None] == seg[None, :])
+    return np.where(seen, values, -np.inf).astype(np.float32)
+
+
+def _tied(rng, shape):
+    """Small integers, so a row has many equal scores, -0.0 beside 0.0."""
+    values = rng.integers(-2, 3, size=shape).astype(np.float32)
+    values[values == 0] = rng.choice([0.0, -0.0], size=int((values == 0).sum()))
+    return values
+
+
+def _selection_case(name):
+    """(scores the kernel is given, scores the selection is of, segment ids,
+    topk, the kernel's chunk of columns)."""
+    rng = np.random.default_rng(5)
+    if name in ("documents", "poisoned"):
+        # three documents whose ends fall on no multiple of 8, 32 or the chunk;
+        # chunks of 128 columns, so a block of rows reads its columns four
+        # chunks at a time and then singly
+        t, topk, chunk = 1024, 48, 128
+        seg = np.repeat([0, 1, 2], [301, 410, 313]).astype(np.int32)
+        scores = _masked(rng.standard_normal((t, t)), seg)
+        given = scores
+        if name == "poisoned":
+            # what the kernel must never read: +inf and NaN over every column
+            # outside the chunks a block of rows counts over
+            rows = math.gcd(t, sparse_lib._SELECT_ROWS)
+            first, last = (np.repeat(np.asarray(x), rows)[:, None] * chunk
+                           for x in sparse_lib._counted_chunks(jnp.asarray(seg), rows, chunk))
+            cols = np.arange(t)[None, :]
+            before, after = cols < first, cols >= last
+            assert before.any() and after.any() and np.isinf(scores[before | after]).all()
+            given = np.where(before | after, np.where(cols % 2 == 0, np.inf, np.nan), scores)
+        return given, scores, seg, topk, chunk
+    if name == "ties":
+        # blocks of rows with ties to leave out (the upper two documents'
+        # scores are small integers) and without (the last's are continuous)
+        t, topk = 512, 8
+        seg = np.repeat([0, 1, 2], [100, 156, 256]).astype(np.int32)
+        values = np.where(seg[:, None] < 2, _tied(rng, (t, t)), rng.standard_normal((t, t)))
+        scores = _masked(values, seg)
+        return scores, scores, seg, topk, None
+    if name == "short_rows":
+        # documents shorter than topk, one of exactly topk, and a longer one
+        t, topk = 256, 48
+        seg = np.repeat(np.arange(6), [30, 47, 48, 17, 100, 14]).astype(np.int32)
+        scores = _masked(rng.standard_normal((t, t)), seg)
+        return scores, scores, seg, topk, None
+    if name == "ids_that_fall":
+        # a document's id comes back after another's: its later rows see the
+        # sequence's first columns again, so nothing bounds a block from below
+        t, topk = 512, 16
+        seg = np.repeat([1, 0, 1], [150, 200, 162]).astype(np.int32)
+        scores = _masked(_tied(rng, (t, t)), seg)
+        return scores, scores, seg, topk, None
+    raise ValueError(name)
+
+
+@pytest.mark.parametrize(
+    "case", ["documents", "ties", "short_rows", "poisoned", "ids_that_fall"])
+def test_selection_kernel_equals_the_xla_selection(case, monkeypatch):
+    """``_select_pallas`` (interpreted) against ``select``: the thresholds
+    element for element, the tie positions on every row with more ties than
+    places (``T - 1`` on the others), the same selection on every row, and its
+    two counters against counts by hand."""
+    given, scores, seg, topk, chunk = _selection_case(case)
+    t = len(seg)
+    if chunk is not None:
+        monkeypatch.setattr(sparse_lib, "_TILE_K", chunk)
+    chunk = math.gcd(t, sparse_lib._TILE_K)
+    rows = math.gcd(t, sparse_lib._SELECT_ROWS)
+    tau, tie = sparse_lib.select(jnp.asarray(scores), topk)
+    got_tau, got_tie, work = sparse_lib._select_pallas(
+        jnp.asarray(given), jnp.asarray(seg), topk, interpret=True)
+    np.testing.assert_array_equal(np.asarray(got_tau), np.asarray(tau))
+    # by hand: a row has ties to leave out where more finite scores equal its
+    # threshold than it has places left
+    value = np.asarray(sparse_lib.threshold_value(tau))[:, None]
+    need = topk - (scores > value).sum(-1)
+    surplus = ((scores == value).sum(-1) > need) & np.isfinite(value[:, 0])
+    got_tie = np.asarray(got_tie)
+    np.testing.assert_array_equal(got_tie[surplus], np.asarray(tie)[surplus])
+    assert (got_tie[~surplus] == t - 1).all()
+    np.testing.assert_array_equal(
+        np.asarray(sparse_lib.selection_mask(jnp.asarray(scores), got_tau, jnp.asarray(got_tie))),
+        np.asarray(sparse_lib.selection_mask(jnp.asarray(scores), tau, tie)))
+    assert float(work["tie_blocks"]) == surplus.reshape(-1, 8).any(axis=1).sum()
+    # a block of rows counts from the chunk its first row's document starts in
+    # (the sequence's start where ids fall) to the chunk of its last row
+    starts = np.concatenate([[0], np.flatnonzero(np.diff(seg)) + 1])
+    columns = 0
+    for r in range(0, t, rows):
+        first = starts[starts <= r].max() // chunk if (np.diff(seg) >= 0).all() else 0
+        columns += rows * (-(-(r + rows) // chunk) - first) * chunk
+    assert float(work["columns"]) == columns
+    if case == "documents":
+        assert surplus.sum() == 0 and columns < 0.55 * t * t
+    if case == "ties":
+        blocks = surplus.reshape(-1, rows).any(axis=1)
+        assert blocks.any() and not blocks.all() and 0 < surplus.sum() < t
+    if case == "short_rows":
+        short = np.isinf(value[:, 0])
+        assert short.sum() == 30 + 47 + 47 + 17 + 47 + 14
+        assert (np.asarray(got_tau)[short] == sparse_lib._KEY_NEG_INF).all()
+    if case == "ids_that_fall":
+        assert columns > 0.55 * t * t and surplus.any()
 
 
 def test_each_loss_reaches_its_own_leaves_only(full_weights):
@@ -251,15 +369,21 @@ def test_kernels_in_interpret_mode_follow_the_xla_path():
 
     def run(interpret):
         def loss(*x):
-            out, align, reads = sparse_lib.sparse_attention(
+            out, align, reads, searched = sparse_lib.sparse_attention(
                 *x, seg, topk=topk, interpret=interpret)
-            return jnp.sum(out * jnp.cos(out)) + align, (out, align, reads)
+            return jnp.sum(out * jnp.cos(out)) + align, (out, align, reads, searched)
 
         return jax.jit(jax.value_and_grad(loss, argnums=tuple(range(6)), has_aux=True))(
             *map(jnp.asarray, arrays))
 
-    (_, (out, align, reads)), grads = run(None)
-    (_, (out_k, align_k, reads_k)), grads_k = run(True)
+    (_, (out, align, reads, searched)), grads = run(None)
+    (_, (out_k, align_k, reads_k, searched_k)), grads_k = run(True)
+    # the XLA path searches every row whole; the kernel stops a block of rows
+    # at the chunk of its last row's own position (one chunk here)
+    assert float(searched["columns"]) == t * t == float(searched_k["columns"])
+    # ... and searches ties where a row has some to leave out (the ReLU's exact
+    # zeros are the threshold of many rows here)
+    assert float(searched["tie_blocks"]) == t // 8 > float(searched_k["tie_blocks"]) > 0
     np.testing.assert_allclose(np.asarray(out_k), np.asarray(out), atol=2e-5)
     assert float(align_k) == pytest.approx(float(align), rel=1e-5)
     np.testing.assert_array_equal(np.asarray(reads_k), np.asarray(reads))
@@ -363,5 +487,8 @@ def test_fit_trains_the_sparse_decoder(tmp_path):
         assert 0 < w["attn_keys_per_query"]["sparse_attention"] <= 16
         assert 0 < w["sparse_pairs_selected"] <= w["sparse_pairs_scored"]
         assert w["moe_pairs_dropped"] == 0 and 0 < w["align_loss"] < 10
+        # a sequence's, four layers together; the XLA path (this backend's)
+        # searches every row whole: 64 columns and 64 / 8 groups of rows
+        assert w["sparse_select_columns"] == 4 * 64 * 64 and w["sparse_tie_blocks"] == 4 * 8
     header = next(e for e in events if e.get("event") == "run_header")
     assert header["decoder"]["layer_types"] == ["sparse_attention"] * 4
