@@ -24,7 +24,8 @@ class DecoderConfig:
     training sequence length.
 
     **The share.** A layer may be divided over ``share_count`` chips (tensor
-    and expert parallel): each holds ``num_attention_heads`` query heads with
+    and expert parallel): each holds ``num_attention_heads`` query heads (or,
+    layer by layer, ``num_attention_heads_per_layer``) with
     ``num_key_value_heads`` key-value heads, ``num_experts`` experts and
     ``vocab_size`` rows of the vocabulary — the counts HELD HERE, a
     ``share_count``-th of the published ones — and is share ``share_index`` of
@@ -39,17 +40,35 @@ class DecoderConfig:
     all-reduce over the shares that completes it is the exchange, and on one
     chip the layer runs without it (``share_count=1`` is the whole model and
     needs none). What every share holds whole — norms, the router, a
-    ``sparse_attention`` layer's indexer — is computed alike on each.
+    ``sparse_attention`` layer's indexer, a ``dense`` layer's MLP, the shared
+    expert — is computed alike on each.
 
     **Layer types.** ``sliding_attention`` (plain RoPE, a window),
     ``full_attention`` (every earlier key of the document) and
     ``sparse_attention``: a query reads only the ``sa_config["topk"]`` keys a
     learned indexer scores highest (ops/sparse_attention.py), and the indexer
-    is trained by a loss of its own (train/step.py:SequenceTask)."""
+    is trained by a loss of its own (train/step.py:SequenceTask). The number
+    of query heads may go by layer (``num_attention_heads_per_layer``: the
+    Laguna family gives its window layers more heads than its full ones), the
+    rotary embedding may cover the first ``partial_rotary_factor`` of a head
+    only (a key of a layer type's ``rope_parameters``), and ``gating`` puts a
+    sigmoid gate, one scalar a head, on the heads' outputs.
+
+    **MLPs.** ``mlp_layer_types`` says layer by layer ``dense`` (one SiLU-gated
+    MLP of ``intermediate_size``) or ``sparse`` (the routed experts; every
+    layer where the list is empty). The router scores by ``scoring_func``
+    (``softmax`` over all experts, or ``sigmoid`` of each logit), takes the
+    ``num_experts_per_tok`` largest, renormalises over them
+    (``norm_topk_prob``) and multiplies by ``moe_routed_scaling_factor``; a
+    shared expert of ``shared_expert_intermediate_size`` (0: none) is added to
+    the routed sum, unweighted."""
 
     hidden_size: int = 2304
     head_dim: int = 128
     num_attention_heads: int = 32
+    # query heads layer by layer, where they differ (empty: num_attention_heads
+    # on every layer)
+    num_attention_heads_per_layer: Tuple[int, ...] = ()
     num_key_value_heads: int = 4
     num_hidden_layers: int = 28
     # one entry per layer: "sliding_attention" | "full_attention" |
@@ -62,6 +81,20 @@ class DecoderConfig:
     num_experts_per_tok: int = 8
     moe_intermediate_size: int = 896
     norm_topk_prob: bool = True
+    # "softmax" | "sigmoid": what the router's top-k is taken of (DeepSeek-V3's
+    # key; a config that publishes a routed scaling factor and no score function
+    # names it among what it assumes)
+    scoring_func: str = "softmax"
+    moe_routed_scaling_factor: float = 1.0
+    # one entry per layer, "dense" | "sparse" (empty: every MLP is sparse), and
+    # the dense MLP's width
+    mlp_layer_types: Tuple[str, ...] = ()
+    intermediate_size: int = 0
+    # a shared expert beside the routed ones (0: none)
+    shared_expert_intermediate_size: int = 0
+    # a sigmoid gate on each head's output, from the layer's normalised input:
+    # False, or as published True / "per-head"
+    gating: object = False
     rms_norm_eps: float = 1e-6
     vocab_size: int = 98304
     # rope_parameters by layer type, each a sorted tuple of (key, value)
@@ -104,6 +137,29 @@ class DecoderConfig:
                 raise ValueError("the indexer has one key head (indexer_num_kv_heads 1)")
         if self.num_attention_heads % self.num_key_value_heads:
             raise ValueError("num_attention_heads must be a multiple of num_key_value_heads")
+        for name in ("num_attention_heads_per_layer", "mlp_layer_types"):
+            if getattr(self, name) and len(getattr(self, name)) < self.num_hidden_layers:
+                raise ValueError(
+                    f"{name} names {len(getattr(self, name))} layers, "
+                    f"num_hidden_layers is {self.num_hidden_layers}"
+                )
+        if any(h % self.num_key_value_heads for h in self.num_attention_heads_per_layer):
+            raise ValueError(
+                "every entry of num_attention_heads_per_layer must be a multiple of "
+                f"num_key_value_heads ({self.num_key_value_heads}): "
+                f"{sorted(set(self.num_attention_heads_per_layer))}"
+            )
+        if set(self.mlp_layer_types) - {"dense", "sparse"}:
+            raise ValueError(f"Unknown mlp layer types {sorted(set(self.mlp_layer_types))}")
+        kept_mlps = self.mlp_layer_types[: self.num_hidden_layers]
+        if "dense" in kept_mlps and not self.intermediate_size:
+            raise ValueError("a dense MLP layer needs intermediate_size")
+        if kept_mlps and "sparse" not in kept_mlps:
+            raise ValueError("a mixture-of-experts decoder keeps at least one sparse layer")
+        if self.scoring_func not in ("softmax", "sigmoid"):
+            raise ValueError(f"Unknown scoring_func {self.scoring_func!r}")
+        if self.gating not in (False, True, "per-head"):
+            raise ValueError(f"Unknown gating {self.gating!r}: a gate is one scalar a head")
         if not 0 <= self.share_index < self.share_count:
             raise ValueError(
                 f"share_index {self.share_index} is not one of {self.share_count} shares"
@@ -120,6 +176,9 @@ class DecoderConfig:
         # a published null (Keye's sliding_window) says nothing: the default stays
         kept = {k: v for k, v in config.items() if k in names and v is not None}
         kept["layer_types"] = tuple(config["layer_types"])
+        for name in ("num_attention_heads_per_layer", "mlp_layer_types"):
+            if name in kept:
+                kept[name] = tuple(kept[name])
         rope = config.get("rope_parameters")
         if rope is None:
             # the older keys: one theta and one scaling for every layer
@@ -128,8 +187,11 @@ class DecoderConfig:
             scaling.setdefault("rope_type", "default")
             scaling["rope_theta"] = config["rope_theta"]
             rope = {kind: scaling for kind in set(kept["layer_types"])}
+        # beside the layer types a published group may carry plain numbers
+        # (Laguna's original_max_position_embeddings): they are no layer type's
         kept["rope_parameters"] = tuple(
-            (kind, tuple(sorted(params.items()))) for kind, params in sorted(rope.items())
+            (kind, tuple(sorted(params.items())))
+            for kind, params in sorted(rope.items()) if isinstance(params, dict)
         )
         if "sa_config" in config:
             kept["sa_config"] = tuple(sorted(config["sa_config"].items()))
@@ -138,6 +200,18 @@ class DecoderConfig:
 
     def rope(self, layer_type: str) -> dict:
         return dict(dict(self.rope_parameters)[layer_type])
+
+    def rotary_dim(self, layer_type: str) -> int:
+        """The leading dimensions of a head that a layer type rotates."""
+        return int(self.head_dim * self.rope(layer_type).get("partial_rotary_factor", 1))
+
+    def heads(self, layer: int) -> int:
+        """Query heads held of layer ``layer``."""
+        per_layer = self.num_attention_heads_per_layer
+        return per_layer[layer] if per_layer else self.num_attention_heads
+
+    def mlp_type(self, layer: int) -> str:
+        return self.mlp_layer_types[layer] if self.mlp_layer_types else "sparse"
 
     @property
     def indexer(self) -> dict:

@@ -268,6 +268,9 @@ PRESETS: Dict[str, Preset] = {
                 num_attention_heads=8,
                 num_key_value_heads=1,
                 num_experts=16,
+                # as published: every MLP sparse, so the dense width serves no layer
+                mlp_layer_types=("sparse",) * 28,
+                intermediate_size=7168,
                 vocab_size=24576,
                 share_count=4,
                 share_index=0,
@@ -314,6 +317,7 @@ PRESETS: Dict[str, Preset] = {
                 num_key_value_heads=1,
                 num_experts=16,
                 moe_intermediate_size=768,
+                intermediate_size=6144,  # published; no layer is dense
                 vocab_size=18992,
                 rope_parameters=(
                     ("sparse_attention", (("rope_theta", 10000000), ("rope_type", "default"))),
@@ -345,6 +349,77 @@ PRESETS: Dict[str, Preset] = {
         "packed 16,384-token sequences of long documents: chip 0's share of a "
         "layer divided over 8 chips, 4 of 48 layers — a partial result by "
         "design (config.py:DecoderConfig)",
+    ),
+    # Laguna-XS.2 (poolside, model_type laguna,
+    # https://huggingface.co/poolside/Laguna-XS.2/blob/main/config.json; the
+    # published keys are in perfbench/configs/laguna_xs2_33b_a3b_share8.json):
+    # full and window layers 1:3 with head counts of their own (48 / 64 query
+    # heads on 8 key-value heads), a sigmoid gate on every head's output, YaRN
+    # over the first half of a head on full layers and plain RoPE over the
+    # whole on window layers (window 512); a leading dense MLP of 8,192, then
+    # 256 routed experts of 512, 8 a token by sigmoid scores renormalised over
+    # the chosen and scaled by 2.5, beside one shared expert. Published
+    # widths; chip 0's share of a layer divided over 8 chips (6 / 8 query
+    # heads on 1 key-value head, 32 of 256 experts, 12,544 of 100,352
+    # vocabulary rows; the router, the shared expert and layer 0's dense MLP
+    # whole), layers 0-4 of 40 — 540.6M parameters here, 8.65 GB with
+    # gradients and Adam's moments. Assumed (the config has no key): the
+    # gate's form, sigmoid scores with renormalisation, no router bias and no
+    # auxiliary loss, no q/k norm, AdamW 3e-7 / 0.1.
+    "laguna_xs2_33b_a3b_share8": Preset(
+        model=ModelConfig(
+            backbone="decoder",
+            dtype="bfloat16",
+            decoder=DecoderConfig(
+                hidden_size=2048,
+                num_hidden_layers=5,
+                layer_types=(("full_attention",) + ("sliding_attention",) * 3) * 10,
+                num_attention_heads=6,
+                num_attention_heads_per_layer=(6, 8, 8, 8) * 10,
+                num_key_value_heads=1,
+                sliding_window=512,
+                num_experts=32,
+                moe_intermediate_size=512,
+                scoring_func="sigmoid",
+                moe_routed_scaling_factor=2.5,
+                mlp_layer_types=("dense",) + ("sparse",) * 39,
+                intermediate_size=8192,
+                shared_expert_intermediate_size=512,
+                gating=True,
+                vocab_size=12544,
+                rope_parameters=(
+                    ("full_attention", (
+                        ("attention_factor", 1.4158883083359672), ("beta_fast", 64),
+                        ("beta_slow", 1), ("factor", 64),
+                        ("original_max_position_embeddings", 4096),
+                        ("partial_rotary_factor", 0.5),
+                        ("rope_theta", 500000), ("rope_type", "yarn"),
+                    )),
+                    ("sliding_attention", (
+                        ("partial_rotary_factor", 1), ("rope_theta", 10000),
+                        ("rope_type", "default"),
+                    )),
+                ),
+                share_count=8,
+                share_index=0,
+                sequence_length=16384,
+            ),
+        ),
+        # the rate is small for the reason the Mellum-2 preset gives; a window
+        # every 10 steps, as the Keye preset has it
+        train=TrainConfig(
+            optimizer="adam", lr=3e-7, weight_decay=0.1, augmentation="none",
+            train_log_every_steps=10,
+            token_stream=TokenStreamConfig(
+                median_length=8192.0, sigma=1.0, min_length=256, max_length=16384
+            ),
+        ),
+        global_batch=1,
+        description="Laguna-XS.2 decoder (window/full attention 3:1 with 64/48 query heads "
+        "under a per-head output gate, a leading dense MLP, a shared expert and "
+        "sigmoid-routed top-8-of-256 experts of 512), next-token training on packed "
+        "16,384-token sequences: chip 0's share of a layer divided over 8 chips, "
+        "layers 0-4 of 40 — a partial result by design (config.py:DecoderConfig)",
     ),
 }
 

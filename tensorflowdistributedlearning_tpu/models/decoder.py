@@ -6,26 +6,36 @@ family (https://huggingface.co/JetBrains/Mellum2-12B-A2.5B-Instruct,
 ``config.py:DecoderConfig`` takes by name. Trained as next-token prediction
 over packed documents.
 
-Layer: ``h = x + Attn(RMSNorm(x))``, ``y = h + MoE(RMSNorm(h))``. Attention is
+Layer: ``h = x + Attn(RMSNorm(x))``, ``y = h + MLP(RMSNorm(h))``. Attention is
 grouped-query, causal and inside one document, with a rotary embedding whose
-parameters go by layer type (``DecoderConfig.rope_parameters``) and optionally
-a per-head RMS normalisation of q and k. What a query reads goes by layer type
-too: every earlier key (``full_attention``) or those inside a window
-(``sliding_attention``), both ops/blocked_attention.py; or the ``topk`` keys a
-learned indexer scores highest (``sparse_attention``, ops/sparse_attention.py:
-the Keye-VL-2.0 family's layer,
+parameters go by layer type (``DecoderConfig.rope_parameters``; over the whole
+head, or over its first ``partial_rotary_factor`` with the rest passed
+through) and optionally a per-head RMS normalisation of q and k. What a query
+reads goes by layer type too: every earlier key (``full_attention``) or those
+inside a window (``sliding_attention``), both ops/blocked_attention.py; or the
+``topk`` keys a learned indexer scores highest (``sparse_attention``,
+ops/sparse_attention.py: the Keye-VL-2.0 family's layer,
 https://huggingface.co/Kwai-Keye/Keye-VL-2.0-30B-A3B), whose indexer reads the
 layer's normalised input cut from the graph and is trained by a loss of its
-own, returned beside the cross-entropy. Every MLP is
-sparse: a float32 router over all experts, ``num_experts_per_tok`` a token
-with renormalised weights, SiLU-gated experts, no token dropped
-(parallel/expert.py:dropless_experts). The head is untied; with targets the
+own, returned beside the cross-entropy. How many query heads a layer has may go
+by layer (``num_attention_heads_per_layer``), and with ``gating`` each head's
+output is multiplied by a sigmoid gate, one scalar a head and position, from
+the layer's normalised input (float32) — both the Laguna family's
+(https://huggingface.co/poolside/Laguna-XS.2, ``model_type: laguna``).
+
+The MLP goes by ``mlp_layer_types``: ``sparse`` (every layer where the list
+is empty) is a float32 router over all experts — a softmax over them or a
+sigmoid of each (``scoring_func``) — ``num_experts_per_tok`` a token with
+renormalised weights times ``moe_routed_scaling_factor``, SiLU-gated experts,
+no token dropped (parallel/expert.py:dropless_experts), and where the
+configuration has one a shared expert added unweighted; ``dense`` is one
+SiLU-gated MLP of ``intermediate_size``. The head is untied; with targets the
 model returns the summed next-token cross-entropy, computed in token chunks so
 the ``[tokens, vocabulary]`` logits never stand whole.
 
 Matrix products run in ``config.dtype`` with float32 accumulation; the
-residual stream, norms, rotary embedding, router, softmaxes and loss are
-float32; parameters are float32.
+residual stream, norms, rotary embedding, router, head gate, softmaxes and
+loss are float32; parameters are float32.
 
 The chip's share of a layer (config.py:DecoderConfig): the module holds the
 heads, experts and vocabulary rows its configuration counts, and computes
@@ -65,14 +75,15 @@ _INIT = nn.initializers.normal(0.02)
 
 def rope_constants(cfg: DecoderConfig, layer_type: str, dim: int = 0) -> Tuple[np.ndarray, float]:
     """(inv_freq [dim / 2] float32, the factor on cos and sin) of a layer
-    type over ``dim`` rotated dimensions (the head's by default), as
+    type over ``dim`` rotated dimensions (by default the part of the head its
+    ``partial_rotary_factor`` names, else the whole head), as
     ``transformers`` computes them: ``default`` is
     theta^(-2i/d); ``yarn`` keeps the fast-rotating dimensions, divides the
     slow ones by ``factor``, blends linearly between the correction
     dimensions of ``beta_fast`` and ``beta_slow``, and scales cos and sin by
     ``attention_factor``."""
     rp = cfg.rope(layer_type)
-    dim, theta = dim or cfg.head_dim, float(rp["rope_theta"])
+    dim, theta = dim or cfg.rotary_dim(layer_type), float(rp["rope_theta"])
     inv = theta ** (-np.arange(0, dim, 2, dtype=np.float64) / dim)
     if rp["rope_type"] == "default":
         return inv.astype(np.float32), 1.0
@@ -94,7 +105,12 @@ def rope_constants(cfg: DecoderConfig, layer_type: str, dim: int = 0) -> Tuple[n
 
 
 def apply_rope(x: jax.Array, positions: jax.Array, inv_freq, scale: float) -> jax.Array:
-    """x [B, T, H, hd] float32, positions [B, T]: rotate-half."""
+    """x [B, T, H, hd] float32, positions [B, T]: rotate-half over the first
+    ``2 * len(inv_freq)`` dimensions of a head; the rest pass as they are."""
+    rotated_dims = 2 * len(inv_freq)
+    if rotated_dims < x.shape[-1]:
+        first = apply_rope(x[..., :rotated_dims], positions, inv_freq, scale)
+        return jnp.concatenate([first, x[..., rotated_dims:]], axis=-1)
     angles = positions.astype(jnp.float32)[..., None] * jnp.asarray(inv_freq)
     emb = jnp.concatenate([angles, angles], axis=-1)[:, :, None, :]
     half = x.shape[-1] // 2
@@ -174,21 +190,23 @@ _SCOPES = {"sliding_attention": "decoder/attn_sliding", "full_attention": "decod
 
 
 class DecoderAttention(nn.Module):
-    """``(attention's part of the residual update, extras)``: ``extras`` is
-    empty but on a ``sparse_attention`` layer, which adds ``align`` (the
+    """``(attention's part of the residual update, extras)`` with ``heads``
+    query heads. A ``sparse_attention`` layer adds to ``extras`` ``align`` (the
     indexer's loss summed over the positions), ``reads`` (how many queries
     read each key position [T]) and ``searched`` (the columns and the blocks
-    of rows the selection's searches ran over)."""
+    of rows the selection's searches ran over); a gated layer ``gate`` (the
+    gates summed over positions and heads)."""
 
     cfg: DecoderConfig
     layer_type: str
     dtype: Any
+    heads: int = 0  # the layer's own count; 0: cfg.num_attention_heads
 
     @nn.compact
     def __call__(self, u, segment_ids, positions):
         cfg = self.cfg
         b, t, _ = u.shape
-        hq, hkv, hd = cfg.num_attention_heads, cfg.num_key_value_heads, cfg.head_dim
+        hq, hkv, hd = self.heads or cfg.num_attention_heads, cfg.num_key_value_heads, cfg.head_dim
         with jax.named_scope(_SCOPES[self.layer_type]):
             q = Projection(hq * hd, self.dtype, name="wq")(u).reshape(b, t, hq, hd)
             k = Projection(hkv * hd, self.dtype, name="wk")(u).reshape(b, t, hkv, hd)
@@ -213,8 +231,28 @@ class DecoderAttention(nn.Module):
                     q, k, v.astype(self.dtype), segment_ids,
                     window=cfg.sliding_window if self.layer_type == "sliding_attention" else None,
                 )
+            if cfg.gating:
+                with jax.named_scope("decoder/attn_gate"):
+                    gate = jax.nn.sigmoid(
+                        Projection(hq, jnp.float32, precise=True, name="head_gate")(u))
+                    out = out.astype(jnp.float32) * gate[..., None]
+                    extras["gate"] = jnp.sum(gate)
             out = Projection(cfg.hidden_size, self.dtype, name="wo")(out.reshape(b, t, hq * hd))
             return out, extras
+
+
+class GatedMLP(nn.Module):
+    """``(SiLU(u W_gate) * (u W_up)) W_down``: a ``dense`` layer's MLP and the
+    shared expert of a ``sparse`` one."""
+
+    features: int
+    dtype: Any
+
+    @nn.compact
+    def __call__(self, u):
+        gate = Projection(self.features, self.dtype, name="w_gate")(u)
+        up = Projection(self.features, self.dtype, name="w_up")(u)
+        return Projection(u.shape[-1], self.dtype, name="w_down")(jax.nn.silu(gate) * up)
 
 
 class DecoderMoE(nn.Module):
@@ -234,7 +272,8 @@ class DecoderMoE(nn.Module):
         with jax.named_scope("decoder/moe/route"):
             logits = jnp.dot(x, router, precision=lax.Precision.HIGHEST)
             weights, experts = expert_lib.top_k_routing(
-                logits, cfg.num_experts_per_tok, cfg.norm_topk_prob
+                logits, cfg.num_experts_per_tok, cfg.norm_topk_prob,
+                score=cfg.scoring_func, scale=cfg.moe_routed_scaling_factor,
             )
         with jax.named_scope("decoder/moe/experts"):
             out, counts, dropped = expert_lib.dropless_experts(
@@ -242,26 +281,39 @@ class DecoderMoE(nn.Module):
                 w_gate.astype(self.dtype), w_up.astype(self.dtype), w_down.astype(self.dtype),
                 num_experts_total=total, first_expert=cfg.share_index * held,
             )
+        out = out.reshape(u.shape)
+        if cfg.shared_expert_intermediate_size:
+            with jax.named_scope("decoder/moe/shared"):
+                out = out + GatedMLP(cfg.shared_expert_intermediate_size, self.dtype,
+                                     name="shared")(u)
         buffer_rows = expert_lib.pair_buffer_rows(counts, experts.size, total)
-        return out.reshape(u.shape), counts, dropped, buffer_rows
+        return out, counts, dropped, buffer_rows, expert_lib.row_tile_visits(counts)
 
 
 class DecoderLayer(nn.Module):
+    """Layer ``index``: ``(y, (the expert layer's counters — none on a
+    ``dense`` layer, attention's extras))``."""
+
     cfg: DecoderConfig
-    layer_type: str
+    index: int
     dtype: Any
 
     @nn.compact
     def __call__(self, x, segment_ids, positions):
-        eps = self.cfg.rms_norm_eps
-        attended, extras = DecoderAttention(self.cfg, self.layer_type, self.dtype, name="attn")(
-            RMSNorm(eps, name="attn_norm")(x), segment_ids, positions
-        )
+        cfg, eps = self.cfg, self.cfg.rms_norm_eps
+        attended, extras = DecoderAttention(
+            cfg, cfg.layer_types[self.index], self.dtype, cfg.heads(self.index), name="attn"
+        )(RMSNorm(eps, name="attn_norm")(x), segment_ids, positions)
         h = x + attended
-        out, *counters = DecoderMoE(self.cfg, self.dtype, name="moe")(
+        if cfg.mlp_type(self.index) == "dense":
+            with jax.named_scope("decoder/mlp_dense"):
+                out = GatedMLP(cfg.intermediate_size, self.dtype, name="mlp")(
+                    RMSNorm(eps, name="mlp_norm")(h))
+            return h + out, ((), extras)
+        out, *counters = DecoderMoE(cfg, self.dtype, name="moe")(
             RMSNorm(eps, name="moe_norm")(h)
         )
-        return h + out, (counters, extras)
+        return h + out, (tuple(counters), extras)
 
 
 class HeadLoss(nn.Module):
@@ -337,19 +389,29 @@ class MoEDecoder(nn.Module):
             policy = (jax.checkpoint_policies.save_only_these_names(sparse_lib.SELECT_NAME)
                       if "sparse_attention" in kinds else None)
             layer_cls = nn.remat(DecoderLayer, policy=policy)
+        # the expert layers' counters, a row a sparse layer (a dense one adds none)
         counts, buffer_rows, dropped = [], [], jnp.zeros((), jnp.int32)
+        tile_visits = jnp.zeros((), jnp.int32)
         align, reads, searched = jnp.zeros((), jnp.float32), [], []
+        gate_sums, gate_counts = {}, {}  # a gated model's, by layer type
         for i, kind in enumerate(kinds):
-            x, ((c, d, r), extras) = layer_cls(cfg, kind, dtype, name=f"layers_{i}")(
+            x, (counters, extras) = layer_cls(cfg, i, dtype, name=f"layers_{i}")(
                 x, segment_ids, positions
             )
-            counts.append(c)
-            buffer_rows.append(r)
-            dropped = dropped + d
-            if extras:
+            if counters:
+                c, d, r, v = counters
+                counts.append(c)
+                buffer_rows.append(r)
+                dropped = dropped + d
+                tile_visits = tile_visits + v
+            if "align" in extras:
                 align = align + extras["align"]
                 reads.append(extras["reads"])
                 searched.append(extras["searched"])
+            if "gate" in extras:
+                short = kind.split("_")[0]
+                gate_sums[short] = gate_sums.get(short, 0.0) + extras["gate"]
+                gate_counts[short] = gate_counts.get(short, 0) + b * t * cfg.heads(i)
         x = RMSNorm(cfg.rms_norm_eps, name="final_norm")(x)
         if "targets" not in inputs:
             return {"hidden": x}
@@ -368,7 +430,14 @@ class MoEDecoder(nn.Module):
             expert_tokens=jnp.stack(counts).astype(jnp.float32),
             pairs_dropped=dropped.astype(jnp.float32),
             buffer_rows=jnp.stack(buffer_rows).astype(jnp.float32),
+            # row tiles of the grouped products that the held experts' groups
+            # overlap, every sparse layer together
+            tile_visits=tile_visits.astype(jnp.float32),
             **{f"attn_keys_{kind.split('_')[0]}": attn_keys[kind]() for kind in sorted(set(kinds))},
+            # a gated model's gates by layer type: their sum, and how many
+            **{f"attn_gate_sum_{kind}": total for kind, total in gate_sums.items()},
+            **{f"attn_gate_n_{kind}": jnp.asarray(n, jnp.float32)
+               for kind, n in gate_counts.items()},
             n_sequences=jnp.asarray(b, jnp.float32),
             n_positions=jnp.asarray(b * t, jnp.float32),
         )

@@ -210,14 +210,22 @@ _WRITTEN_LANES = 128
 
 
 def top_k_routing(
-    router_logits: jax.Array, k: int, renormalise: bool = True
+    router_logits: jax.Array, k: int, renormalise: bool = True, *,
+    score: str = "softmax", scale: float = 1.0,
 ) -> Tuple[jax.Array, jax.Array]:
     """[T, E] logits -> (weights [T, k] float32, experts [T, k] int32): the
-    softmax over all E experts, its k largest, renormalised over the chosen."""
-    probs = jax.nn.softmax(router_logits.astype(jnp.float32), axis=-1)
-    weights, experts = lax.top_k(probs, k)
+    scores of all E experts — their softmax, or under ``score="sigmoid"`` each
+    logit's sigmoid, which no other expert's logit moves — the k largest
+    (equal scores to the lower index), renormalised over the chosen, times
+    ``scale`` (the routed scaling factor that sigmoid-routed families publish:
+    their renormalised weights sum to it, not to 1)."""
+    logits = router_logits.astype(jnp.float32)
+    scores = jax.nn.sigmoid(logits) if score == "sigmoid" else jax.nn.softmax(logits, axis=-1)
+    weights, experts = lax.top_k(scores, k)
     if renormalise:
         weights = weights / jnp.sum(weights, axis=-1, keepdims=True)
+    if scale != 1.0:
+        weights = weights * scale
     return weights, experts.astype(jnp.int32)
 
 
@@ -282,6 +290,20 @@ def pair_buffer_rows(counts: jax.Array, pairs: int, num_experts_total: int) -> j
     tokens go to each held expert, of ``pairs`` routed over all experts."""
     rows = _segment_rows(pairs, counts.shape[0], num_experts_total)
     return _segments(jnp.sum(counts), rows) * rows
+
+
+def row_tile_visits(counts: jax.Array) -> jax.Array:
+    """Row tiles of ``_GMM_TILE_M`` sorted pairs that the held experts'
+    groups overlap in ``dropless_experts``' buffer, summed over the groups
+    (segments are whole tiles, so a tile lies in one segment): what the
+    grouped products visit for the held pairs. A group of ``n`` pairs overlaps
+    at least ``ceil(n / tile)`` tiles and one more where it straddles an
+    edge, so ``sum(counts) / (visits * tile)`` says how full the visited tiles
+    are when a group is about one tile."""
+    ends = jnp.cumsum(counts.astype(jnp.int32))
+    starts = ends - counts
+    tiles = (ends - 1) // _GMM_TILE_M - starts // _GMM_TILE_M + 1
+    return jnp.sum(jnp.where(counts > 0, tiles, 0), dtype=jnp.int32)
 
 
 def _sum_rows_on_mxu(rows, tok, t: int, block: int, interpret: bool = False) -> jax.Array:
@@ -440,8 +462,10 @@ def dropless_experts(
     The last count returned is read off the products' own output: a row of
     the buffer was computed if the down projection wrote something other than
     0 into its first lanes (a row the kernel passes over is left 0; so would a
-    pair of weight 0 be, which the softmax does not give), the rows that
-    should have been are the held pairs' by the routing's counts, and held
+    pair of weight 0 be, which neither routing gives: a softmax is positive,
+    and a sigmoid is until its logit falls under float32's range, about -87
+    where denormals are flushed, while the chosen are a token's largest), the
+    rows that should have been are the held pairs' by the routing's counts, and held
     pairs past the last segment's end count too. 0 as long as the segments
     hold every held pair and the kernel visits every group it is handed."""
     t, k = experts.shape

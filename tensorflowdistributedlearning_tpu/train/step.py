@@ -436,13 +436,18 @@ class SequenceTask:
         """The run header's ``decoder`` block: the share of a layer this chip
         holds and the pattern of the layers kept."""
         cfg = self.decoder
-        return {
-            "decoder": {
-                "share": [cfg.share_index, cfg.share_count],
-                "layer_types": list(cfg.layer_types[: cfg.num_hidden_layers]),
-                "sequence_length": cfg.sequence_length,
-            }
+        layers = range(cfg.num_hidden_layers)
+        block = {
+            "share": [cfg.share_index, cfg.share_count],
+            "layer_types": list(cfg.layer_types[: cfg.num_hidden_layers]),
+            "sequence_length": cfg.sequence_length,
         }
+        # what goes by layer, where the layers differ in it
+        if "dense" in cfg.mlp_layer_types[: cfg.num_hidden_layers]:
+            block["mlp_layer_types"] = [cfg.mlp_type(i) for i in layers]
+        if cfg.num_attention_heads_per_layer:
+            block["attention_heads"] = [cfg.heads(i) for i in layers]
+        return {"decoder": block}
 
     def batches(self, batch_size: int, seed: int, steps=None, start_index: int = 0):
         """The packed synthetic token stream (index-keyed, so a resumed run
@@ -473,11 +478,19 @@ class SequenceTask:
             # and the share of them that held pairs filled
             "moe_buffer_rows": [round(x, 1) for x in vectors["moe/buffer_rows"]],
             "moe_buffer_fill": [round(x, 4) for x in vectors["moe/buffer_fill"]],
+            # row tiles the grouped products visit for the held experts'
+            # groups, every sparse layer together (a step's mean)
+            "moe_tile_visits": round(scalars["moe/tile_visits"], 2),
             "attn_keys_per_query": {
                 kind: round(scalars[f"attn/keys_per_query_{kind.split('_')[0]}"], 2)
                 for kind in sorted(set(self.decoder.layer_types[: self.decoder.num_hidden_layers]))
             },
         }
+        # the head gates' mean by layer type (a gated model's alone)
+        gated = {kind: round(scalars[key], 6) for kind in fields["attn_keys_per_query"]
+                 if (key := f"attn/gate_mean_{kind.split('_')[0]}") in scalars}
+        if gated:
+            fields["attn_gate_mean"] = gated
         if "align_loss" in scalars:
             # the indexer's own loss, and a window's (query, key) pairs its
             # layers scored (the visible ones) and selected
@@ -516,7 +529,9 @@ class SequenceTask:
         routed to each), ``moe/pairs_dropped``. Per step, by layer:
         ``moe/buffer_rows`` (rows of the sorted pair buffer); per buffer row,
         by layer: ``moe/buffer_fill`` (held pairs). Per position:
-        ``attn/keys_per_query_*`` by layer type and, where layers are sparse,
+        ``attn/keys_per_query_*`` by layer type; per step ``moe/tile_visits``
+        (row tiles the held groups overlap); per gate ``attn/gate_mean_*`` by
+        layer type (a gated model's); and, where layers are sparse,
         ``align_loss`` (the indexer's loss; ``loss`` stays the cross-entropy);
         per sequence ``sparse/pairs_scored``, ``sparse/key_reads`` ([sparse
         layers, T]: queries that read each key position),
@@ -542,6 +557,12 @@ class SequenceTask:
             "moe/pairs_dropped": mean(outputs["pairs_dropped"], rows),
             "moe/buffer_rows": mean(buffer_rows, jnp.ones((), jnp.float32)),
             "moe/buffer_fill": mean(jnp.sum(outputs["expert_tokens"], axis=1), buffer_rows),
+            "moe/tile_visits": mean(outputs["tile_visits"], jnp.ones((), jnp.float32)),
+            **{
+                "attn/gate_mean_" + name[len("attn_gate_sum_"):]: mean(
+                    total, outputs["attn_gate_n_" + name[len("attn_gate_sum_"):]])
+                for name, total in outputs.items() if name.startswith("attn_gate_sum_")
+            },
             **{
                 "attn/keys_per_query_" + name[len("attn_keys_"):]: mean(keys, outputs["n_positions"])
                 for name, keys in outputs.items() if name.startswith("attn_keys_")

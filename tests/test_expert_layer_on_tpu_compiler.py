@@ -111,3 +111,54 @@ def test_the_sparse_layer_is_eight_kernels_and_no_product_over_heads(one_chip, a
         loss, policy=jax.checkpoint_policies.save_only_these_names(sparse_lib.SELECT_NAME))
     text = _compiled_text(jax.value_and_grad(kept, argnums=range(6), has_aux=True), one_chip, *shapes)
     assert _kernels(text) == 11 and len(re.findall(r"%sparse_select[.\d]* = ", text)) == 1
+
+
+@pytest.mark.parametrize("heads,window", [(8, 512), (6, None)])
+def test_attention_takes_the_mixed_layers_head_groups(one_chip, as_on_a_tpu, heads, window):
+    """``ops/blocked_attention.py`` at laguna_xs2_33b_a3b_share8's shapes (one
+    16,384-token sequence, heads of 128 on one key-value head): the compiler
+    takes the splash kernels — forward, dq, dkv — at a window layer's group of
+    8 query heads under a window of 512 (a band two 512-blocks wide) and at a
+    full layer's group of 6, and no ``[heads, T, T]`` scores stand in memory."""
+    from tensorflowdistributedlearning_tpu.ops import blocked_attention as attn_lib
+
+    t, hd = 16384, 128
+    assert attn_lib.kernel_serves(t, hd)
+
+    def loss(q, k, v, seg):
+        out = attn_lib.blocked_attention(q, k, v, seg, window=window)
+        return jnp.sum(out.astype(jnp.float32))
+
+    bf16 = jnp.bfloat16
+    text = _compiled_text(
+        jax.value_and_grad(loss, argnums=range(3)), one_chip,
+        ((1, t, heads, hd), bf16), ((1, t, 1, hd), bf16), ((1, t, 1, hd), bf16),
+        ((1, t), jnp.int32))
+    assert _kernels(text) == 3
+    assert f"[{heads},{t},{hd}]" in text and f"{t},{t}]" not in text
+
+
+def test_a_segment_of_small_experts_is_eleven_kernels(one_chip, as_on_a_tpu):
+    """``dropless_experts``' segment at laguna_xs2_33b_a3b_share8's shapes: 32
+    held experts of width 512 of 256, 16,384 tokens, 8 experts a token — a
+    held expert's group is about one 512-row tile. The compiler takes the nine
+    grouped products and the two row sums at those tiles, and moves no tensor
+    of all 131,072 pairs by the hidden or the experts' width."""
+    t, k, d, f, held, total = 16384, 8, 2048, 512, 32, 256
+    rows = expert_lib._segment_rows(t * k, held, total)
+    assert rows == 20480 and expert_lib._gmm_tiling(rows, d, f) == (512, 1024, 512)
+    assert expert_lib._gmm_tiling(rows, f, d) == (512, 512, 1024)
+
+    def loss(x, weights, w_gate, w_up, w_down, order, counts):
+        out, _ = expert_lib._over_segment(
+            rows, k, 0, x, weights, w_gate, w_up, w_down, order, counts)
+        return jnp.sum(out)
+
+    bf16, f32, s32 = jnp.bfloat16, jnp.float32, jnp.int32
+    text = _compiled_text(
+        jax.value_and_grad(loss, argnums=range(5)), one_chip,
+        ((t, d), bf16), ((t, k), f32), ((held, d, f), bf16), ((held, d, f), bf16),
+        ((held, f, d), bf16), ((t * k,), s32), ((held,), s32))
+    assert _kernels(text) == 11
+    for full in (f"[{t * k},{d}]", f"[{t * k},{f}]"):
+        assert full not in text, full

@@ -315,7 +315,7 @@ def test_the_windows_say_how_full_the_pair_buffer_was():
                            ([[1000, 1000], [2000, 1000]], [2560, 4096])]:
         outputs = {k: jnp.asarray(1.0) for k in (
             "loss_sum", "n_targets", "n_correct", "pairs_dropped", "attn_keys_full",
-            "attn_keys_sliding", "n_positions")}
+            "attn_keys_sliding", "n_positions", "tile_visits")}
         outputs.update(n_sequences=jnp.asarray(2.0),
                        expert_tokens=jnp.asarray(tokens_, jnp.float32),
                        buffer_rows=jnp.asarray(rows_, jnp.float32))
@@ -327,6 +327,7 @@ def test_the_windows_say_how_full_the_pair_buffer_was():
     assert fields["moe_buffer_fill"] == [round(4000 / 5120, 4), round(5000 / 6656, 4)]
     assert all(0 < f <= 1 for f in fields["moe_buffer_fill"])
     assert fields["moe_pairs"] == 9000
+    assert fields["moe_tile_visits"] == 1.0 and "attn_gate_mean" not in fields
 
 
 def test_window_means_are_worked_out_on_the_host(monkeypatch):
