@@ -31,7 +31,9 @@ no token dropped (parallel/expert.py:dropless_experts), and where the
 configuration has one a shared expert added unweighted; ``dense`` is one
 SiLU-gated MLP of ``intermediate_size``. The head is untied; with targets the
 model returns the summed next-token cross-entropy, computed in token chunks so
-the ``[tokens, vocabulary]`` logits never stand whole.
+the ``[tokens, vocabulary]`` logits never stand whole; where it is
+differentiated the same pass over a chunk computes the loss's gradients, so
+each chunk's logits are computed once (``chunked_head_loss``).
 
 Matrix products run in ``config.dtype`` with float32 accumulation; the
 residual stream, norms, rotary embedding, router, head gate, softmaxes and
@@ -49,6 +51,7 @@ like the kernels.
 
 from __future__ import annotations
 
+import functools
 import math
 from typing import Any, Dict, Tuple
 
@@ -65,7 +68,8 @@ from tensorflowdistributedlearning_tpu.parallel import expert as expert_lib
 
 # from this many tokens a step on, layers are recomputed in the backward pass
 REMAT_MIN_TOKENS = 4096
-# tokens whose logits stand at once in the head's loss
+# tokens whose logits stand at once in the head's loss (and, where it is
+# differentiated, their gradient: both are computed in one pass over a chunk)
 LOSS_CHUNK_TOKENS = 4096
 # what a trainer initialises the model on (models.sample_input)
 INIT_TOKENS = 8
@@ -316,10 +320,91 @@ class DecoderLayer(nn.Module):
         return h + out, (tuple(counters), extras)
 
 
+def _head_chunks(h: jax.Array, targets: jax.Array):
+    """h [tokens, d] and targets [tokens] as the head's scan takes them."""
+    chunk = math.gcd(h.shape[0], LOSS_CHUNK_TOKENS)
+    return h.reshape(-1, chunk, h.shape[-1]), targets.reshape(-1, chunk)
+
+
+def _chunk_loss(hc, w, tc):
+    """One chunk's summed cross-entropy over the positions that have a target
+    and the arg-max hits among them, and what its gradient is made of: the
+    float32 logits, their log-sum-exp, where each row's target is and which
+    rows have one. The picked logit and the arg-max (the first index that
+    holds the row's maximum) are float32 sums and minima over the row like the
+    sum of exponentials, so XLA reads the logits for all three in one pass, and
+    the maximum fuses into the product."""
+    logits = jnp.dot(hc, w, preferred_element_type=jnp.float32)
+    has = tc >= 0
+    lse = jax.nn.logsumexp(logits, axis=-1)
+    column = lax.broadcasted_iota(jnp.int32, logits.shape, 1)
+    is_target = column == tc[:, None]
+    picked = jnp.sum(jnp.where(is_target, logits, 0.0), axis=-1)
+    top = jnp.max(logits, axis=-1, keepdims=True)
+    best = jnp.min(jnp.where(logits == top, column.astype(jnp.float32), float(logits.shape[1])),
+                   axis=-1)
+    loss = jnp.sum(jnp.where(has, lse - picked, 0.0))
+    hits = jnp.sum(jnp.where(has, best == tc.astype(jnp.float32), False))
+    return loss, hits.astype(jnp.float32), (logits, lse, is_target, has)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
+def chunked_head_loss(dtype, h: jax.Array, kernel: jax.Array, targets: jax.Array):
+    """(summed cross-entropy, arg-max hits) of ``h [tokens, d] @ kernel [d, V]``
+    against ``targets [tokens]`` (-1: none), a chunk of tokens at a time;
+    products in ``dtype`` with float32 accumulation, the rest float32. Called
+    as it stands it computes the loss alone, one product a chunk.
+    Differentiated, one pass over the chunks (``_head_loss_fwd``) computes the
+    loss and both gradients, so no logits are kept or computed twice."""
+    w = kernel.astype(dtype)
+
+    def one(carry, xs):
+        loss, hits, _ = _chunk_loss(xs[0].astype(dtype), w, xs[1])
+        return (carry[0] + loss, carry[1] + hits), None
+
+    zero = jnp.zeros((), jnp.float32)
+    return lax.scan(one, (zero, zero), _head_chunks(h, targets))[0]
+
+
+def _head_loss_fwd(dtype, h, kernel, targets):
+    """The loss pass that also forms, from each chunk's logits and log-sum-exp,
+    ``dlogits = (softmax - onehot(target)) * has_target`` (float32) and the two
+    products it owes: the chunk's ``dlogits @ W^T`` and ``h^T @ dlogits`` summed
+    over the chunks in float32. The gradients at a cotangent of 1 are the
+    residuals; nothing ``[tokens, V]`` outlives its chunk."""
+    w = kernel.astype(dtype)
+
+    def one(carry, xs):
+        hc, tc = xs[0].astype(dtype), xs[1]
+        loss, hits, (logits, lse, is_target, has) = _chunk_loss(hc, w, tc)
+        dlogits = jnp.where(has[:, None], jnp.exp(logits - lse[:, None]) - is_target, 0.0)
+        dh = lax.dot_general(dlogits, w, (((1,), (1,)), ((), ())),
+                             preferred_element_type=jnp.float32)
+        dw = lax.dot_general(hc, dlogits, (((0,), (0,)), ((), ())),
+                             preferred_element_type=jnp.float32)
+        return (carry[0] + loss, carry[1] + hits, carry[2] + dw), dh.astype(h.dtype)
+
+    zero = jnp.zeros((), jnp.float32)
+    (loss_sum, hits, dw), dh = lax.scan(
+        one, (zero, zero, jnp.zeros(kernel.shape, jnp.float32)), _head_chunks(h, targets)
+    )
+    return (loss_sum, hits), (dh.reshape(h.shape), dw.astype(kernel.dtype))
+
+
+def _head_loss_bwd(dtype, residuals, cotangents):
+    dh, dw = residuals
+    g = cotangents[0]  # the hits are piecewise constant; integer targets take none
+    return (g * dh).astype(dh.dtype), (g * dw).astype(dw.dtype), None
+
+
+chunked_head_loss.defvjp(_head_loss_fwd, _head_loss_bwd)
+
+
 class HeadLoss(nn.Module):
     """The untied vocabulary head and the next-token cross-entropy over it,
-    ``LOSS_CHUNK_TOKENS`` tokens at a time; each chunk's logits are recomputed
-    in the backward pass."""
+    ``LOSS_CHUNK_TOKENS`` tokens at a time (``chunked_head_loss``): where the
+    loss is differentiated each chunk's logits are computed once, and the
+    gradients of the hidden states and of the kernel in the same pass."""
 
     vocab_size: int
     dtype: Any
@@ -328,25 +413,9 @@ class HeadLoss(nn.Module):
     def __call__(self, h: jax.Array, targets: jax.Array) -> Dict[str, jax.Array]:
         d = h.shape[-1]
         kernel = self.param("kernel", _INIT, (d, self.vocab_size), jnp.float32)
-        w = kernel.astype(self.dtype)
-        h, targets = h.reshape(-1, d), targets.reshape(-1)
-        chunk = math.gcd(h.shape[0], LOSS_CHUNK_TOKENS)
-
-        @jax.checkpoint
-        def one(carry, xs):
-            hc, tc = xs
-            logits = jnp.dot(hc.astype(self.dtype), w, preferred_element_type=jnp.float32)
-            has = tc >= 0
-            picked = jnp.take_along_axis(logits, jnp.maximum(tc, 0)[:, None], axis=-1)[:, 0]
-            loss = jnp.sum(jnp.where(has, jax.nn.logsumexp(logits, axis=-1) - picked, 0.0))
-            hits = jnp.sum(jnp.where(has, jnp.argmax(logits, axis=-1) == tc, False))
-            return (carry[0] + loss, carry[1] + hits.astype(jnp.float32)), None
-
+        targets = targets.reshape(-1)
         with jax.named_scope("decoder/head_loss"):
-            zero = jnp.zeros((), jnp.float32)
-            (loss_sum, hits), _ = lax.scan(
-                one, (zero, zero), (h.reshape(-1, chunk, d), targets.reshape(-1, chunk))
-            )
+            loss_sum, hits = chunked_head_loss(self.dtype, h.reshape(-1, d), kernel, targets)
         return {
             "loss_sum": loss_sum,
             "n_targets": jnp.sum(targets >= 0).astype(jnp.float32),

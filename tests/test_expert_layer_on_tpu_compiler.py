@@ -162,3 +162,38 @@ def test_a_segment_of_small_experts_is_eleven_kernels(one_chip, as_on_a_tpu):
     assert _kernels(text) == 11
     for full in (f"[{t * k},{d}]", f"[{t * k},{f}]"):
         assert full not in text, full
+
+
+@pytest.mark.parametrize("d,v", [(2304, 24576), (2048, 18992), (2048, 12544)])
+def test_the_head_is_one_loop_of_three_products_and_no_kept_logits(one_chip, as_on_a_tpu, d, v):
+    """``models/decoder.py:chunked_head_loss`` differentiated, at the three
+    decoder cells' ``[16384, d] x [d, V]``: one loop over the chunks whose body
+    holds the three products with the vocabulary, a chunk's logits written
+    once and read by one pass of reductions (the row maximum rides the
+    product), and no ``[tokens, V]`` array outside a chunk."""
+    from tensorflowdistributedlearning_tpu.models import decoder as decoder_lib
+
+    def loss(h, kernel, targets):
+        total, _ = decoder_lib.chunked_head_loss(jnp.bfloat16, h, kernel, targets)
+        return total / jnp.sum(targets >= 0)
+
+    text = _compiled_text(
+        jax.value_and_grad(loss, argnums=(0, 1)), one_chip,
+        ((T, d), jnp.float32), ((d, v), jnp.float32), ((T,), jnp.int32))
+    assert text.count(" while(") == 1
+    body = re.search(r" while\(.*?body=(%[\w.\-]+)", text).group(1)
+    start = text.index("\n" + body + " ")
+    ops = [line for line in text[start:text.index("\n}\n", start)].splitlines() if " fusion(" in line]
+    chunk = decoder_lib.LOSS_CHUNK_TOKENS
+    logits = rf"f32\[{chunk},{v}\]"
+    # each fusion's result shapes: what stands between "=" and "fusion("
+    results = [line.split(" = ", 1)[1].split(" fusion(", 1)[0] for line in ops]
+    # written once: by the product, with the row maximum beside it
+    written = [r for r in results if re.search(logits, r)]
+    assert len(written) == 1 and re.match(rf"\(f32\[{chunk}\]\S*, {logits}", written[0])
+    # the products: logits; dlogits @ W^T into the chunk's rows of dh; h^T @ dlogits into dW
+    outputs = [r for line, r in zip(ops, results) if "kind=kOutput" in line]
+    assert len(outputs) == 3
+    assert any(re.match(rf"f32\[{T // chunk},{chunk},{d}\]", r) for r in outputs)
+    assert any(re.match(rf"f32\[{d},{v}\]", r) for r in outputs)
+    assert f"[{T},{v}]" not in text and f"[{T // chunk},{chunk},{v}]" not in text

@@ -339,7 +339,9 @@ def test_selection_kernel_equals_the_xla_selection(case, monkeypatch):
 
 def test_each_loss_reaches_its_own_leaves_only(full_weights):
     """``L_lm``'s gradient is exactly zero on the indexer's leaves and
-    ``L_I``'s exactly zero on every other leaf; both are non-zero on their own."""
+    ``L_I``'s exactly zero on every other leaf — the head's kernel among them,
+    whose gradient is the residual of the head's own pass times ``L_lm``'s
+    cotangent alone; both are non-zero on their own."""
     cfg = _cfg(4, 2)
     flat = reference.share_of(full_weights, _cfg(1, 0), 4, 2)
     model, params = _program(cfg, flat)
@@ -349,6 +351,7 @@ def test_each_loss_reaches_its_own_leaves_only(full_weights):
     indexer = set(reference.indexer_leaves(cfg))
     assert len(indexer) == 5 * cfg["num_hidden_layers"]
     assert {k.split("/indexer/")[1].split("/")[0] for k in indexer} == {"wq", "wk", "k_norm", "w"}
+    assert "head/kernel" in g_lm and "head/kernel" not in indexer
     for name in g_lm:
         own, other = (g_align, g_lm) if name in indexer else (g_lm, g_align)
         assert float(jnp.max(jnp.abs(other[name]))) == 0.0, name

@@ -1,8 +1,10 @@
 """The decoder family (``backbone="decoder"``) at a tiny size on the CPU:
 program against the plain reference for every share and the uncut model, the
 share test, the attention kernel in interpret mode against the masked
-reference, no token dropped under any imbalance, the rotary constants against
-hand-computed values, the packer, and ``fit`` end to end."""
+reference, no token dropped under any imbalance, the head's one pass over the
+token chunks against a plain cross-entropy and by the products in its jaxpr,
+the rotary constants against hand-computed values, the packer, and ``fit`` end
+to end."""
 
 import json
 import math
@@ -344,6 +346,112 @@ def test_window_means_are_worked_out_on_the_host(monkeypatch):
     assert got == {"loss": 1.5, "empty": 0.0, "moe/expert_tokens": [[1.0, 2.0]]}
     assert step_lib.split_scalars(got) == (
         {"loss": 1.5, "empty": 0.0}, {"moe/expert_tokens": [[1.0, 2.0]]})
+
+
+# the head's cases: (tokens as [B, T], what the targets lack). 4,096 divides
+# neither token count, so the chunk is gcd(tokens, 4096): 32 of 96, 16 of 80
+_HEAD_CASES = {
+    "some_without_target": ((2, 48), lambda t: t.at[::5].set(-1)),
+    "a_chunk_without_any": ((2, 48), lambda t: t.at[32:64].set(-1).at[7].set(-1)),
+    "chunk_by_gcd": ((1, 80), lambda t: t.at[-1].set(-1)),
+}
+_HEAD_D, _HEAD_V = 16, 40
+
+
+def _head_inputs(case: str):
+    shape, lack = _HEAD_CASES[case]
+    tokens = shape[0] * shape[1]
+    h = jax.random.normal(jax.random.key(1), shape + (_HEAD_D,), jnp.float32)
+    kernel = 0.3 * jax.random.normal(jax.random.key(2), (_HEAD_D, _HEAD_V), jnp.float32)
+    targets = lack(jax.random.randint(jax.random.key(3), (tokens,), 0, _HEAD_V)).reshape(shape)
+    return h, kernel, targets
+
+
+def _head(dtype, h, kernel, targets):
+    return decoder_lib.HeadLoss(_HEAD_V, dtype).apply({"params": {"kernel": kernel}}, h, targets)
+
+
+def _one_shot_cross_entropy(dtype, h, kernel, targets):
+    """All logits at once, float32 from operands rounded to ``dtype`` (the
+    rounding passed straight through: a cast's own gradient rounds again)."""
+    rounded = lambda x: x + jax.lax.stop_gradient(x.astype(dtype).astype(jnp.float32) - x)
+    logits = jnp.dot(rounded(h).reshape(-1, _HEAD_D), rounded(kernel), precision="highest")
+    t, has = targets.reshape(-1), targets.reshape(-1) >= 0
+    logp = jax.nn.log_softmax(logits, axis=-1)
+    loss = -jnp.sum(jnp.where(has, logp[jnp.arange(t.size), jnp.maximum(t, 0)], 0.0))
+    return loss, jnp.sum(has & (jnp.argmax(logits, axis=-1) == t))
+
+
+@pytest.mark.parametrize("scale", ["mean", 3.0])
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("case", sorted(_HEAD_CASES))
+def test_the_heads_one_pass_against_a_one_shot_cross_entropy(case, dtype, scale):
+    """Loss sum and hits equal, the gradients of the hidden states and of the
+    kernel to float32 tolerance, under a cotangent that is not 1 — also where
+    a chunk holds no target and where the chunk follows from a gcd."""
+    dtype = jnp.dtype(dtype)
+    h, kernel, targets = _head_inputs(case)
+    chunk = math.gcd(targets.size, decoder_lib.LOSS_CHUNK_TOKENS)
+    assert 1 < targets.size // chunk and chunk < decoder_lib.LOSS_CHUNK_TOKENS
+    if case == "a_chunk_without_any":
+        assert (np.asarray(targets).reshape(-1, chunk) < 0).all(axis=1).tolist() == [False, True, False]
+    factor = (lambda n: 1.0 / n) if scale == "mean" else (lambda n: scale)
+
+    def program(h, kernel):
+        out = _head(dtype, h, kernel, targets)
+        return out["loss_sum"] * factor(out["n_targets"]), out
+
+    def plain(h, kernel):
+        loss, hits = _one_shot_cross_entropy(dtype, h, kernel, targets)
+        return loss * factor(jnp.sum(targets >= 0)), (loss, hits)
+
+    (_, out), (dh, dw) = jax.jit(jax.value_and_grad(program, (0, 1), has_aux=True))(h, kernel)
+    (_, (loss, hits)), (want_dh, want_dw) = jax.jit(
+        jax.value_and_grad(plain, (0, 1), has_aux=True))(h, kernel)
+    assert float(out["loss_sum"]) == pytest.approx(float(loss), rel=2e-6)
+    assert float(out["n_correct"]) == float(hits) and float(hits) > 0
+    assert float(out["n_targets"]) == float(jnp.sum(targets >= 0))
+    assert dh.dtype == h.dtype and dw.dtype == kernel.dtype and dh.shape == h.shape
+    scale_of = lambda x: float(jnp.max(jnp.abs(x)))
+    np.testing.assert_allclose(dh, want_dh, rtol=0, atol=1e-5 * scale_of(want_dh))
+    np.testing.assert_allclose(dw, want_dw, rtol=0, atol=1e-5 * scale_of(want_dw))
+    # a position without a target moves nothing
+    assert float(jnp.max(jnp.abs(dh.reshape(-1, _HEAD_D)[np.asarray(targets).reshape(-1) < 0]))) == 0.0
+    # and the undifferentiated call reads the same sums
+    alone = jax.jit(lambda: _head(dtype, h, kernel, targets))()
+    assert float(alone["loss_sum"]) == pytest.approx(float(out["loss_sum"]), rel=1e-6)
+    assert float(alone["n_correct"]) == float(hits)
+
+
+def _vocabulary_products(jaxpr, vocab: int):
+    """(the ``dot_general``s with ``vocab`` in a shape, the loops that hold
+    one) in a jaxpr and every jaxpr inside it."""
+    products = loops = 0
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "dot_general":
+            products += any(vocab in v.aval.shape for v in list(eqn.invars) + list(eqn.outvars))
+        inner = 0
+        for value in eqn.params.values():
+            for sub in value if isinstance(value, (tuple, list)) else (value,):
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    p, n = _vocabulary_products(sub, vocab)
+                    inner, loops = inner + p, loops + n
+        products += inner
+        loops += bool(inner) and eqn.primitive.name in ("scan", "while")
+    return products, loops
+
+
+@pytest.mark.parametrize("differentiated,want", [(True, (3, 1)), (False, (1, 1))])
+def test_the_head_computes_each_chunks_logits_once(differentiated, want):
+    """What says the one pass engages: the gradient's jaxpr holds three
+    products with the vocabulary a chunk — logits, ``dlogits @ W^T``,
+    ``h^T @ dlogits`` — in one loop over the chunks (a recomputed chunk would
+    give four in two), and the undifferentiated call holds the logits' alone."""
+    h, kernel, targets = _head_inputs("some_without_target")
+    loss = lambda h, kernel: _head(jnp.bfloat16, h, kernel, targets)["loss_sum"]
+    fn = jax.grad(loss, (0, 1)) if differentiated else loss
+    assert _vocabulary_products(jax.make_jaxpr(fn)(h, kernel).jaxpr, _HEAD_V) == want
 
 
 def test_top_k_routing_renormalises_over_the_chosen():
