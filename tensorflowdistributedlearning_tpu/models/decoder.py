@@ -62,6 +62,7 @@ import numpy as np
 from jax import lax
 
 from tensorflowdistributedlearning_tpu.config import DecoderConfig, ModelConfig
+from tensorflowdistributedlearning_tpu.obs import scopes
 from tensorflowdistributedlearning_tpu.ops import sparse_attention as sparse_lib
 from tensorflowdistributedlearning_tpu.ops.blocked_attention import blocked_attention
 from tensorflowdistributedlearning_tpu.parallel import expert as expert_lib
@@ -211,20 +212,24 @@ class DecoderAttention(nn.Module):
         cfg = self.cfg
         b, t, _ = u.shape
         hq, hkv, hd = self.heads or cfg.num_attention_heads, cfg.num_key_value_heads, cfg.head_dim
-        with jax.named_scope(_SCOPES[self.layer_type]):
-            q = Projection(hq * hd, self.dtype, name="wq")(u).reshape(b, t, hq, hd)
-            k = Projection(hkv * hd, self.dtype, name="wk")(u).reshape(b, t, hkv, hd)
-            v = Projection(hkv * hd, self.dtype, name="wv")(u).reshape(b, t, hkv, hd)
-            if cfg.use_qk_norm:
-                q = RMSNorm(cfg.rms_norm_eps, name="q_norm")(q)
-                k = RMSNorm(cfg.rms_norm_eps, name="k_norm")(k)
-            inv_freq, scale = rope_constants(cfg, self.layer_type)
-            q = apply_rope(q, positions, inv_freq, scale).astype(self.dtype)
-            k = apply_rope(k, positions, inv_freq, scale).astype(self.dtype)
+        with scopes.scope(_SCOPES[self.layer_type]):
+            # the projections, q/k normalisation and rotary apart from the
+            # block's kernels
+            with scopes.scope("decoder/attn_proj"):
+                q = Projection(hq * hd, self.dtype, name="wq")(u).reshape(b, t, hq, hd)
+                k = Projection(hkv * hd, self.dtype, name="wk")(u).reshape(b, t, hkv, hd)
+                v = Projection(hkv * hd, self.dtype, name="wv")(u).reshape(b, t, hkv, hd)
+                if cfg.use_qk_norm:
+                    q = RMSNorm(cfg.rms_norm_eps, name="q_norm")(q)
+                    k = RMSNorm(cfg.rms_norm_eps, name="k_norm")(k)
+                inv_freq, scale = rope_constants(cfg, self.layer_type)
+                q = apply_rope(q, positions, inv_freq, scale).astype(self.dtype)
+                k = apply_rope(k, positions, inv_freq, scale).astype(self.dtype)
             extras = {}
             if self.layer_type == "sparse_attention":
-                qi, ki, wi = Indexer(cfg, self.layer_type, self.dtype, name="indexer")(
-                    u, positions)
+                with scopes.scope("decoder/attn_proj"):
+                    qi, ki, wi = Indexer(cfg, self.layer_type, self.dtype, name="indexer")(
+                        u, positions)
                 out, *extra = sparse_lib.sparse_attention(
                     q, k, v.astype(self.dtype), qi, ki, wi, segment_ids,
                     topk=cfg.indexer["topk"],
@@ -236,12 +241,14 @@ class DecoderAttention(nn.Module):
                     window=cfg.sliding_window if self.layer_type == "sliding_attention" else None,
                 )
             if cfg.gating:
-                with jax.named_scope("decoder/attn_gate"):
+                with scopes.scope("decoder/attn_gate"):
                     gate = jax.nn.sigmoid(
                         Projection(hq, jnp.float32, precise=True, name="head_gate")(u))
                     out = out.astype(jnp.float32) * gate[..., None]
                     extras["gate"] = jnp.sum(gate)
-            out = Projection(cfg.hidden_size, self.dtype, name="wo")(out.reshape(b, t, hq * hd))
+            with scopes.scope("decoder/attn_proj"):
+                out = Projection(cfg.hidden_size, self.dtype, name="wo")(
+                    out.reshape(b, t, hq * hd))
             return out, extras
 
 
@@ -273,13 +280,13 @@ class DecoderMoE(nn.Module):
         w_up = self.param("w_up", _INIT, (held, d, f), jnp.float32)
         w_down = self.param("w_down", _INIT, (held, f, d), jnp.float32)
         x = u.reshape(-1, d)
-        with jax.named_scope("decoder/moe/route"):
+        with scopes.scope("decoder/moe/route"):
             logits = jnp.dot(x, router, precision=lax.Precision.HIGHEST)
             weights, experts = expert_lib.top_k_routing(
                 logits, cfg.num_experts_per_tok, cfg.norm_topk_prob,
                 score=cfg.scoring_func, scale=cfg.moe_routed_scaling_factor,
             )
-        with jax.named_scope("decoder/moe/experts"):
+        with scopes.scope("decoder/moe/experts"):
             out, counts, dropped = expert_lib.dropless_experts(
                 x.astype(self.dtype), weights, experts,
                 w_gate.astype(self.dtype), w_up.astype(self.dtype), w_down.astype(self.dtype),
@@ -287,7 +294,7 @@ class DecoderMoE(nn.Module):
             )
         out = out.reshape(u.shape)
         if cfg.shared_expert_intermediate_size:
-            with jax.named_scope("decoder/moe/shared"):
+            with scopes.scope("decoder/moe/shared"):
                 out = out + GatedMLP(cfg.shared_expert_intermediate_size, self.dtype,
                                      name="shared")(u)
         buffer_rows = expert_lib.pair_buffer_rows(counts, experts.size, total)
@@ -305,18 +312,21 @@ class DecoderLayer(nn.Module):
     @nn.compact
     def __call__(self, x, segment_ids, positions):
         cfg, eps = self.cfg, self.cfg.rms_norm_eps
+        with scopes.scope("decoder/norm"):
+            u = RMSNorm(eps, name="attn_norm")(x)
         attended, extras = DecoderAttention(
             cfg, cfg.layer_types[self.index], self.dtype, cfg.heads(self.index), name="attn"
-        )(RMSNorm(eps, name="attn_norm")(x), segment_ids, positions)
+        )(u, segment_ids, positions)
         h = x + attended
         if cfg.mlp_type(self.index) == "dense":
-            with jax.named_scope("decoder/mlp_dense"):
-                out = GatedMLP(cfg.intermediate_size, self.dtype, name="mlp")(
-                    RMSNorm(eps, name="mlp_norm")(h))
+            with scopes.scope("decoder/norm"):
+                u = RMSNorm(eps, name="mlp_norm")(h)
+            with scopes.scope("decoder/mlp_dense"):
+                out = GatedMLP(cfg.intermediate_size, self.dtype, name="mlp")(u)
             return h + out, ((), extras)
-        out, *counters = DecoderMoE(cfg, self.dtype, name="moe")(
-            RMSNorm(eps, name="moe_norm")(h)
-        )
+        with scopes.scope("decoder/norm"):
+            u = RMSNorm(eps, name="moe_norm")(h)
+        out, *counters = DecoderMoE(cfg, self.dtype, name="moe")(u)
         return h + out, (tuple(counters), extras)
 
 
@@ -414,7 +424,7 @@ class HeadLoss(nn.Module):
         d = h.shape[-1]
         kernel = self.param("kernel", _INIT, (d, self.vocab_size), jnp.float32)
         targets = targets.reshape(-1)
-        with jax.named_scope("decoder/head_loss"):
+        with scopes.scope("decoder/head_loss"):
             loss_sum, hits = chunked_head_loss(self.dtype, h.reshape(-1, d), kernel, targets)
         return {
             "loss_sum": loss_sum,
@@ -450,7 +460,9 @@ class MoEDecoder(nn.Module):
             inputs["tokens"], inputs["segment_ids"], inputs["positions"]
         )
         b, t = tokens.shape
-        x = nn.Embed(cfg.vocab_size, cfg.hidden_size, embedding_init=_INIT, name="embed")(tokens)
+        with scopes.scope("decoder/embed"):
+            x = nn.Embed(cfg.vocab_size, cfg.hidden_size, embedding_init=_INIT,
+                         name="embed")(tokens)
         kinds = cfg.layer_types[: cfg.num_hidden_layers]
         layer_cls = DecoderLayer
         if b * t >= REMAT_MIN_TOKENS:
@@ -481,7 +493,8 @@ class MoEDecoder(nn.Module):
                 short = kind.split("_")[0]
                 gate_sums[short] = gate_sums.get(short, 0.0) + extras["gate"]
                 gate_counts[short] = gate_counts.get(short, 0) + b * t * cfg.heads(i)
-        x = RMSNorm(cfg.rms_norm_eps, name="final_norm")(x)
+        with scopes.scope("decoder/norm"):
+            x = RMSNorm(cfg.rms_norm_eps, name="final_norm")(x)
         if "targets" not in inputs:
             return {"hidden": x}
         out = HeadLoss(cfg.vocab_size, dtype, name="head")(x, inputs["targets"])

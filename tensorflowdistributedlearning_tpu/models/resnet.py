@@ -41,6 +41,7 @@ from tensorflowdistributedlearning_tpu.models.layers import (
     subsample,
     upsample,
 )
+from tensorflowdistributedlearning_tpu.obs import scopes
 
 # Reference: core/resnet.py:14 (_DEFAULT_MULTI_GRID = [2, 2, 2]); resnet_model passes
 # (1, 2, 1) for the segmentation net (core/resnet.py:435).
@@ -460,19 +461,21 @@ def deeplab_head(
         bn_axis_name=bn_axis_name,
         dtype=dtype,
     )
-    aspp = ASPP(cfg, bn_axis_name=bn_axis_name, name="aspp")(features, train)
-    aspp_up = upsample(aspp, skip.shape[1:3]).astype(dtype)
-    decoder = ConvBN(cfg.base_depth, 1, name="decoder_conv_1x1", **common)(skip, train)
-    decoder = jnp.concatenate([decoder, aspp_up], axis=-1)
-    decoder = nn.Conv(
-        1,
-        (3, 3),
-        padding="SAME",
-        kernel_init=conv_kernel_init,
-        dtype=dtype,
-        name="decoder_conv_3x3",
-    )(decoder)
-    return upsample(decoder.astype(jnp.float32), cfg.input_shape)
+    with scopes.scope("seg/aspp"):
+        aspp = ASPP(cfg, bn_axis_name=bn_axis_name, name="aspp")(features, train)
+    with scopes.scope("seg/decoder"):
+        aspp_up = upsample(aspp, skip.shape[1:3]).astype(dtype)
+        decoder = ConvBN(cfg.base_depth, 1, name="decoder_conv_1x1", **common)(skip, train)
+        decoder = jnp.concatenate([decoder, aspp_up], axis=-1)
+        decoder = nn.Conv(
+            1,
+            (3, 3),
+            padding="SAME",
+            kernel_init=conv_kernel_init,
+            dtype=dtype,
+            name="decoder_conv_3x3",
+        )(decoder)
+        return upsample(decoder.astype(jnp.float32), cfg.input_shape)
 
 
 class ResNetSegmentation(nn.Module):
@@ -487,11 +490,12 @@ class ResNetSegmentation(nn.Module):
     @nn.compact
     def __call__(self, x: jax.Array, train: bool = False) -> jax.Array:
         cfg = self.config
-        end_points = ResNetBackbone(
-            cfg, multi_grid=SEGMENTATION_MULTI_GRID, bn_axis_name=self.bn_axis_name,
-            spatial_axis_name=self.spatial_axis_name,
-            name="backbone",
-        )(x, train)
+        with scopes.scope("seg/backbone"):
+            end_points = ResNetBackbone(
+                cfg, multi_grid=SEGMENTATION_MULTI_GRID, bn_axis_name=self.bn_axis_name,
+                spatial_axis_name=self.spatial_axis_name,
+                name="backbone",
+            )(x, train)
         features = end_points["features"]
         skip = end_points["block1_unit1_residual"]
         if self.spatial_axis_name is not None:

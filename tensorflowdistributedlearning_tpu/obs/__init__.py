@@ -32,7 +32,12 @@ metrics+tracing as a core subsystem, Abadi et al., arXiv:1605.08695):
 - ``obs.profiler``  — continuous profiling: bounded windowed ``jax.profiler``
   captures on a cadence, on demand, and at alert chokepoints; per-op roofline
   classification and achieved-vs-peak MFU ledgered as ``profile_capture`` /
-  ``op_roofline`` events that feed the planner's measured cost model.
+  ``op_roofline`` events that feed the planner's measured cost model;
+- ``obs.scopes``    — the registry of the ``jax.named_scope``s the program
+  opens, and the ``program_scopes`` record every compiled train-step program
+  writes once: which scope and pass each of its HLO ops came from, so a
+  capture's device time reads by layer (``op_roofline.by_scope``) and not by
+  ``%fusion.N``.
 """
 
 from tensorflowdistributedlearning_tpu.obs.capacity import (

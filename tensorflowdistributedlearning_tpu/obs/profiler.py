@@ -42,6 +42,7 @@ import threading
 import time
 from typing import Dict, List, Optional
 
+from tensorflowdistributedlearning_tpu.obs import scopes as scopes_lib
 from tensorflowdistributedlearning_tpu.obs import trace as trace_lib
 from tensorflowdistributedlearning_tpu.utils import xplane
 
@@ -382,6 +383,15 @@ class ContinuousProfiler:
         for key in ("step", "alert_id"):
             if key in rec:
                 roofline[key] = rec[key]
+        # the same op times by the program's own scopes, where the run's
+        # ledger holds the step programs' maps (obs/scopes.py)
+        records = self.telemetry.program_scopes()
+        if records:
+            roofline["by_scope"] = scopes_lib.by_scope(
+                records,
+                ((scopes_lib.instruction_name(r.name), r.total_ms) for r in rows),
+                steps,
+            )
         self.telemetry.event(OP_ROOFLINE_EVENT, **roofline)
 
     def _plane_filter(self) -> str:

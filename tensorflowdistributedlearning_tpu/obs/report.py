@@ -973,7 +973,7 @@ def build_report(
                     "capture_id", "reason", "phase", "total_ms", "classes",
                     "top_hbm_op", "mfu", "compute_mfu",
                     "achieved_flops_per_sec_per_chip", "peak_flops_per_chip",
-                    "achieved_collective_bytes_per_sec", "alert_id",
+                    "achieved_collective_bytes_per_sec", "alert_id", "by_scope",
                 )
                 if last_rf.get(k) is not None
             }
@@ -1869,6 +1869,8 @@ def render_report(report: Dict) -> str:
                 lines.append(
                     f"  postmortem capture triggered by alert {rf['alert_id']}"
                 )
+            if rf.get("by_scope"):
+                lines.extend(_by_scope_lines(rf["by_scope"]))
     tr = report.get("trace")
     if tr:
         lines.append(f"\ndevice op breakdown ({tr['dir']}):")
@@ -1890,10 +1892,40 @@ def render_report(report: Dict) -> str:
     else:
         lines.append(
             "\nno xplane trace under the workdir (capture one with "
-            "utils.profiling.trace / tools/profile_step.py to get the "
+            "--profile-every-windows or utils.profiling.trace to get the "
             "per-op device breakdown)"
         )
     return "\n".join(lines)
+
+
+def _by_scope_lines(by: Dict) -> List[str]:
+    """The capture's device time by the program's own scopes
+    (``op_roofline.by_scope``, obs/scopes.py): a row a scope, a column a
+    pass."""
+    unit = "ms a step" if by.get("per_step") else "ms"
+    passes = ("forward", "backward", "recompute")
+    lines = [
+        f"  device time by scope ({unit}; {', '.join(by.get('programs') or [])}):",
+        f"    {'scope':<30}" + "".join(f"{p:>11}" for p in passes) + f"{'all':>11}",
+    ]
+    totals = dict.fromkeys(passes, 0.0)
+    for scope, by_pass in by["scopes"].items():
+        for p in passes:
+            totals[p] += by_pass.get(p, 0.0)
+        lines.append(
+            f"    {scope:<30}"
+            + "".join(f"{by_pass.get(p, 0.0):>11.3f}" for p in passes)
+            + f"{sum(by_pass.values()):>11.3f}"
+        )
+    lines.append(
+        f"    {'(named)':<30}" + "".join(f"{totals[p]:>11.3f}" for p in passes)
+        + f"{sum(totals.values()):>11.3f}"
+    )
+    lines.append(
+        f"    unnamed {by['unnamed_ms']:.3f} ({by['unnamed_frac']:.1%} of "
+        f"{by['total_ms']:.3f}); in fusions that mix scopes {by['mixed_ms']:.3f}"
+    )
+    return lines
 
 
 def report_workdir(

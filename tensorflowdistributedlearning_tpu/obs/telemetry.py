@@ -39,6 +39,7 @@ from __future__ import annotations
 
 import collections
 import contextlib
+import json
 import logging
 import os
 import time
@@ -47,6 +48,7 @@ from typing import Deque, Dict, List, NamedTuple, Optional, Tuple
 import numpy as np
 
 from tensorflowdistributedlearning_tpu.obs import capacity as capacity_lib
+from tensorflowdistributedlearning_tpu.obs import scopes as scopes_lib
 from tensorflowdistributedlearning_tpu.obs import trace as trace_lib
 from tensorflowdistributedlearning_tpu.obs.ledger import RunLedger
 from tensorflowdistributedlearning_tpu.obs.metrics import (
@@ -235,6 +237,9 @@ class Telemetry:
             maxlen=_MAX_STEP_MARKS
         )
         self._first_step: Optional[_StartupMark] = None
+        # the program_scopes records this run's ledger holds, by identity (a
+        # step program keeps one record per compile for the process's life)
+        self._program_scopes: Dict[int, Dict] = {}
         # (kind, fields) held back until finish_header(); None = write through
         self._held: Optional[List[Tuple[str, Dict]]] = None
         self._windows = 0
@@ -434,6 +439,43 @@ class Telemetry:
         mark, self._first_step = self._first_step, None
         if mark is not None:
             self._startup_event(mark, t1)
+            # the step program exists now; the phase keeps its meaning (it
+            # ends when step one retires) and the records carry their seconds
+            self.write_program_scopes()
+
+    def write_program_scopes(self) -> None:
+        """One ``program_scopes`` event for every compiled step program that
+        was called since this was last asked and is not in this run's ledger
+        yet (``obs/scopes.py``): right after ``first_step`` and when the run
+        closes, where a later recompile's record lands. The record
+        goes into the event; one that would make a line of more than
+        ``scopes.INLINE_LIMIT_BYTES`` goes to ``program_scopes-<n>.json``
+        beside the ledger, and the event names that file."""
+        if self.ledger is None:
+            return
+        for record in scopes_lib.drain():
+            if id(record) in self._program_scopes:
+                continue
+            event = dict(record)
+            if len(json.dumps(record)) > scopes_lib.INLINE_LIMIT_BYTES:
+                name = f"program_scopes-{len(self._program_scopes)}.json"
+                try:
+                    with open(os.path.join(os.path.dirname(self.ledger.path), name),
+                              "w", encoding="utf-8") as f:
+                        json.dump(record, f)
+                except OSError:
+                    logger.warning("could not write %s", name, exc_info=True)
+                    continue
+                for key in ("scopes", "passes", "chains", "ops", "mixed", "containers"):
+                    del event[key]
+                event["file"] = name
+            self._program_scopes[id(record)] = record
+            self._event(scopes_lib.PROGRAM_SCOPES_EVENT, **event)
+
+    def program_scopes(self) -> List[Dict]:
+        """The records :meth:`write_program_scopes` has written, oldest
+        first."""
+        return list(self._program_scopes.values())
 
     def step_done(self, step: int) -> None:
         """The dispatch tracker retired train step ``step`` (its
@@ -918,6 +960,7 @@ class Telemetry:
         # first step, still tells how far it got
         self.finish_header()
         self._end_first_step(time.perf_counter())
+        self.write_program_scopes()
         if self.profiler is not None:
             # finish any capture in flight BEFORE run_end/close so its
             # events land inside this run's ledger

@@ -48,6 +48,8 @@ import numpy as np
 from jax import lax
 from jax.ad_checkpoint import checkpoint_name
 
+from tensorflowdistributedlearning_tpu.obs import scopes
+
 # the selection's two numbers carry this name: a recomputed layer keeps them
 # (models/decoder.py) and so does not search for the thresholds again
 SELECT_NAME = "sparse_select"
@@ -845,22 +847,22 @@ def _sequence_kernels(q, k, v, qi, ki, wi, segment_ids, topk, interpret):
     t, hq, hd = q.shape
     hkv = k.shape[1]
     group = hq // hkv
-    with jax.named_scope("decoder/attn_sparse/indexer"):
+    with scopes.scope("decoder/attn_sparse/indexer"):
         scores = _scores_kernel_path(qi.transpose(1, 0, 2), ki, wi, segment_ids, interpret)
-    with jax.named_scope("decoder/attn_sparse/select"):
+    with scopes.scope("decoder/attn_sparse/select"):
         frozen = lax.stop_gradient(scores)
         tau, tie_pos, work = _select_pallas(frozen, segment_ids, topk, interpret)
         tau, tie_pos = checkpoint_name(tau, SELECT_NAME), checkpoint_name(tie_pos, SELECT_NAME)
         tau = threshold_value(tau)
     qh = (q * (1.0 / math.sqrt(hd))).astype(q.dtype).reshape(t, hkv, group, hd)
     qh, kh, vh = qh.transpose(1, 2, 0, 3), k.transpose(1, 0, 2), v.transpose(1, 0, 2)
-    with jax.named_scope("decoder/attn_sparse/attend"):
+    with scopes.scope("decoder/attn_sparse/attend"):
         # a key-value head and its group of query heads at a time; every head
         # reads the same selection, so the first's counts are the layer's
         out, lse, lse_i, reads = zip(*(
             _attend_kernel_path(qh[n], kh[n], vh[n], frozen, tau, tie_pos, interpret)
             for n in range(hkv)))
-    with jax.named_scope("decoder/attn_sparse/align"):
+    with scopes.scope("decoder/attn_sparse/align"):
         align = _align_kernel_path(
             *map(lax.stop_gradient, (qh.reshape(hq, t, hd), kh, jnp.concatenate(lse), lse_i[0])),
             scores, tau, tie_pos, interpret)
@@ -869,16 +871,16 @@ def _sequence_kernels(q, k, v, qi, ki, wi, segment_ids, topk, interpret):
 
 
 def _sequence_xla(q, k, v, qi, ki, wi, segment_ids, topk):
-    with jax.named_scope("decoder/attn_sparse/indexer"):
+    with scopes.scope("decoder/attn_sparse/indexer"):
         scores = _scores_xla(qi, ki, wi, segment_ids)
-    with jax.named_scope("decoder/attn_sparse/select"):
+    with scopes.scope("decoder/attn_sparse/select"):
         frozen = lax.stop_gradient(scores)
         tau, tie_pos = select(frozen, topk)
         tau, tie_pos = checkpoint_name(tau, SELECT_NAME), checkpoint_name(tie_pos, SELECT_NAME)
         mask = selection_mask(frozen, tau, tie_pos)
-    with jax.named_scope("decoder/attn_sparse/attend"):
+    with scopes.scope("decoder/attn_sparse/attend"):
         out = _attend_xla(q, k, v, mask)
-    with jax.named_scope("decoder/attn_sparse/align"):
+    with scopes.scope("decoder/attn_sparse/align"):
         align = _align_xla(q, k, scores, mask)
     # every row's searches run over the whole row
     t = q.shape[0]

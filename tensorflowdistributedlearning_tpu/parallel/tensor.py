@@ -40,6 +40,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from tensorflowdistributedlearning_tpu.obs import scopes
 from tensorflowdistributedlearning_tpu.parallel.mesh import (
     BATCH_AXIS,
     MODEL_AXIS,
@@ -186,7 +187,8 @@ def _make_train_step_gspmd_cached(
                 train=True,
                 mutable=["batch_stats", "aux_loss"],
             )
-            loss = task.loss(outputs, batch)
+            with scopes.scope("loss"):
+                loss = task.loss(outputs, batch)
             # model-sown auxiliary losses (MoE load balancing) — empty
             # collection for every non-MoE model
             for aux in jax.tree_util.tree_leaves(mutated.get("aux_loss", {})):
@@ -214,7 +216,7 @@ def _make_train_step_gspmd_cached(
         metrics["loss"] = metrics_lib.Mean.empty().update(loss[None])
         return new_state, metrics
 
-    jitted = jax.jit(step, donate_argnums=(0,) if donate else ())
+    jitted = scopes.Program(jax.jit(step, donate_argnums=(0,) if donate else ()))
 
     def run(state, batch: Dict[str, jax.Array]):
         # bind the step to its mesh: fail fast on batch/axis mismatches instead

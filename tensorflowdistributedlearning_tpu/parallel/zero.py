@@ -47,6 +47,7 @@ import jax.numpy as jnp
 import optax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from tensorflowdistributedlearning_tpu.obs import scopes
 from tensorflowdistributedlearning_tpu.parallel.mesh import (
     BATCH_AXIS,
     MODEL_AXIS,
@@ -200,17 +201,18 @@ def apply_gradients_sharded(
     opt_specs = weight_update_specs(
         state.opt_state, mesh, tensor_parallel=tensor_parallel
     )
-    grads = _constrain(grads, mesh, grad_specs)
-    opt_state = _constrain(state.opt_state, mesh, opt_specs)
-    updates, new_opt_state = state.tx.update(grads, opt_state, state.params)
-    updates = _constrain(updates, mesh, grad_specs)
-    new_opt_state = _constrain(new_opt_state, mesh, opt_specs)
-    new_params = optax.apply_updates(state.params, updates)
-    new_params = _constrain(
-        new_params,
-        mesh,
-        param_placement_specs(state.params, mesh, tensor_parallel=tensor_parallel),
-    )
+    with scopes.scope("optimizer"):
+        grads = _constrain(grads, mesh, grad_specs)
+        opt_state = _constrain(state.opt_state, mesh, opt_specs)
+        updates, new_opt_state = state.tx.update(grads, opt_state, state.params)
+        updates = _constrain(updates, mesh, grad_specs)
+        new_opt_state = _constrain(new_opt_state, mesh, opt_specs)
+        new_params = optax.apply_updates(state.params, updates)
+        new_params = _constrain(
+            new_params,
+            mesh,
+            param_placement_specs(state.params, mesh, tensor_parallel=tensor_parallel),
+        )
     return state.replace(
         step=state.step + 1,
         params=new_params,

@@ -43,6 +43,7 @@ from jax.sharding import Mesh, PartitionSpec as P
 
 from tensorflowdistributedlearning_tpu.config import ModelConfig
 from tensorflowdistributedlearning_tpu.models import vit as vit_lib
+from tensorflowdistributedlearning_tpu.obs import scopes
 from tensorflowdistributedlearning_tpu.ops import metrics as metrics_lib
 from tensorflowdistributedlearning_tpu.parallel.mesh import BATCH_AXIS, MODEL_AXIS
 from tensorflowdistributedlearning_tpu.parallel.pipeline import (
@@ -114,10 +115,9 @@ def _pipelined_forward(
     """Full ViT forward with the block stack routed through the GPipe runner.
     Runs inside shard_map; ``images`` is the local batch shard."""
     k = lax.axis_size(MODEL_AXIS)
-    # named scopes thread the obs span taxonomy into the lowered HLO, so an
-    # xplane capture attributes device time to embed / fill-drain / head the
-    # same way the host-side ledger names its phases (obs/telemetry.py)
-    with jax.named_scope("obs/pipeline_embed"):
+    # registered scopes (obs/scopes.py): the step's ``program_scopes`` record
+    # attributes a capture's device time to embed / fill-drain / head
+    with scopes.scope("pipeline/embed"):
         tokens = vit_lib.embed_tokens(config, params, images)
     b, t, d = tokens.shape
     if b % microbatches:
@@ -132,9 +132,9 @@ def _pipelined_forward(
         ),
         stacked,
     )
-    with jax.named_scope("obs/pipeline_fill_drain"):
+    with scopes.scope("pipeline/fill_drain"):
         out = pipeline_apply(stage_fn, my_stage, x)
-    with jax.named_scope("obs/pipeline_head"):
+    with scopes.scope("pipeline/head"):
         return vit_lib.head_logits(config, params, out.reshape(b, t, d))
 
 
@@ -212,7 +212,7 @@ def _make_train_step_pipeline_cached(
         in_specs=(P(), P(BATCH_AXIS)),
         out_specs=(P(), P()),
     )
-    return jax.jit(sharded, donate_argnums=(0,) if donate else ())
+    return scopes.Program(jax.jit(sharded, donate_argnums=(0,) if donate else ()))
 
 
 def _xception_stage_bundle(params, batch_stats, k):
@@ -274,7 +274,7 @@ def _make_train_step_pipeline_xception_cached(
             backbone_p = params["backbone"]
             stats = state.batch_stats
             backbone_s = stats["backbone"]
-            with jax.named_scope("obs/pipeline_entry"):
+            with scopes.scope("pipeline/entry"):
                 feats, entry_mut = entry.apply(
                     {
                         "params": {
@@ -298,7 +298,7 @@ def _make_train_step_pipeline_xception_cached(
                 (microbatches, b // microbatches) + feats.shape[1:]
             )
             my_p, my_s = _xception_stage_bundle(params, stats, k)
-            with jax.named_scope("obs/pipeline_fill_drain"):
+            with scopes.scope("pipeline/fill_drain"):
                 out, my_new_stats = pipeline_apply_aux(
                     stage_fn, (my_p, my_s), x
                 )
@@ -355,7 +355,7 @@ def _make_train_step_pipeline_xception_cached(
         in_specs=(P(), P(BATCH_AXIS)),
         out_specs=(P(), P()),
     )
-    return jax.jit(sharded, donate_argnums=(0,) if donate else ())
+    return scopes.Program(jax.jit(sharded, donate_argnums=(0,) if donate else ()))
 
 
 @functools.lru_cache(maxsize=None)

@@ -16,6 +16,8 @@ import numpy as np
 import optax
 from flax import core, struct
 
+from tensorflowdistributedlearning_tpu.obs import scopes
+
 
 class TrainState(struct.PyTreeNode):
     step: jax.Array
@@ -28,8 +30,9 @@ class TrainState(struct.PyTreeNode):
     tx: optax.GradientTransformation = struct.field(pytree_node=False)
 
     def apply_gradients(self, grads: Any, new_batch_stats: Any) -> "TrainState":
-        updates, new_opt_state = self.tx.update(grads, self.opt_state, self.params)
-        new_params = optax.apply_updates(self.params, updates)
+        with scopes.scope("optimizer"):
+            updates, new_opt_state = self.tx.update(grads, self.opt_state, self.params)
+            new_params = optax.apply_updates(self.params, updates)
         return self.replace(
             step=self.step + 1,
             params=new_params,

@@ -36,6 +36,7 @@ from tensorflowdistributedlearning_tpu.config import (
     TokenStreamConfig,
     TrainConfig,
 )
+from tensorflowdistributedlearning_tpu.obs import scopes
 from tensorflowdistributedlearning_tpu.ops import losses as losses_lib
 from tensorflowdistributedlearning_tpu.ops import metrics as metrics_lib
 from tensorflowdistributedlearning_tpu.parallel.mesh import BATCH_AXIS, SEQUENCE_AXIS
@@ -866,7 +867,8 @@ def _make_train_step_cached(
                     mutable=["batch_stats", "aux_loss"],
                     rngs={"dropout": jax.random.fold_in(dropout_rng, chunk_idx)},
                 )
-                loss = task.loss(outputs, chunk)
+                with scopes.scope("loss"):
+                    loss = task.loss(outputs, chunk)
                 # auxiliary losses sown by the model (MoE load balancing,
                 # models/vit.py:MoEMlp) join the training objective; the
                 # collection is empty for every non-MoE model
@@ -964,7 +966,7 @@ def _make_train_step_cached(
             out_specs=(P(), P()),
             **_hybrid_kwargs(auto_model, task),
         )
-        return jax.jit(sharded, donate_argnums=(0,) if donate else ())
+        return scopes.Program(jax.jit(sharded, donate_argnums=(0,) if donate else ()))
 
     # ZeRO-1: the manual region ends at (grads, stats, metrics) — all
     # unvarying, so they leave replicated — and the optimizer update runs in
@@ -991,7 +993,7 @@ def _make_train_step_cached(
         )
         return new_state, metrics
 
-    return jax.jit(zero_step, donate_argnums=(0,) if donate else ())
+    return scopes.Program(jax.jit(zero_step, donate_argnums=(0,) if donate else ()))
 
 
 def make_eval_step(

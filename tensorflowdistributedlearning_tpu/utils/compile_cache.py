@@ -52,8 +52,12 @@ every later start on that machine loads it — an exporter that sees other
 devices than the server could not have produced matching entries;
 (3) ``configure`` disables the XLA autotune-cache debug option, whose
 directory (a path inside cache_dir) would otherwise be hashed into every
-key, pinning entries to one absolute cache path. Keys do NOT survive jaxlib
-upgrades or XLA flag changes.
+key, pinning entries to one absolute cache path; (4) ``configure`` keys
+entries on the module's metadata too (scopes, source lines): a loaded
+program carries the metadata it was compiled with, and the step programs'
+scope maps (``obs/scopes.py``) are read from it, so an entry compiled by a
+commit with other scopes or lines must not be found. Keys do NOT survive
+jaxlib upgrades or XLA flag changes.
 """
 
 from __future__ import annotations
@@ -247,6 +251,13 @@ def configure(flag_dir: Optional[str] = None) -> Optional[str]:
     # and is NOT stripped from the cache key — so keys would depend on
     # the cache dir's absolute path. Disable it; it's a GPU-only feature.
     jax.config.update("jax_persistent_cache_enable_xla_caches", "none")
+    # By default the key strips an op's metadata, so a program loaded from
+    # the cache carries the metadata of whichever commit compiled it first —
+    # and a step program's scope map (obs/scopes.py) is read from exactly
+    # that. Keyed on it, a program is loaded only where its scopes and source
+    # lines are this commit's: restarts of one commit hit as before, and a
+    # commit that moves the model's lines compiles once for itself.
+    jax.config.update("jax_compilation_cache_include_metadata_in_key", True)
     if changed:
         # The cache backend latches on first compile — even when the dir
         # was unset, leaving it off permanently — so a configure() that

@@ -43,3 +43,44 @@ def make_salt_dataset(root, n_images=16, n_test=6, shape=(32, 32), seed=0):
         img = rng.uniform(0, 255, shape).astype(np.uint8)
         Image.fromarray(img).save(os.path.join(test, "images", f"t{i}.png"))
     return data, test, ids
+
+
+# the (chain, pass) a made-up ``program_scopes`` record deals out in turn
+SCOPE_RECORD_DEAL = [
+    (("optimizer",), "forward"),
+    (("decoder/attn_full", "decoder/attn_proj"), "recompute"),
+    (("decoder/moe/experts",), "backward"),
+    (("decoder/head_loss",), "forward"),
+    (("loss",), "backward"),
+    (("decoder/attn_sparse", "decoder/attn_sparse/indexer"), "forward"),
+    ((), "forward"),
+]
+
+
+def make_scope_record(trace, needle="jit_step", leave_out=()):
+    """A ``program_scopes`` record (obs/scopes.py) for a recorded trace: the
+    op names inside the ``needle`` programs, sorted, take the entries of
+    ``SCOPE_RECORD_DEAL`` in turn; ``while``s and ``conditional``s are the
+    containers; names in ``leave_out`` get no entry."""
+    from perfbench import xtrace
+    from tensorflowdistributedlearning_tpu.obs import scopes
+
+    texts = {xtrace.short_name(e[0]).lstrip("%"): e[0] for e in xtrace.ops_inside(trace, needle)}
+    chains = [()]
+    groups = {}
+    for i, name in enumerate(sorted(n for n in texts if n not in leave_out)):
+        chain, which = SCOPE_RECORD_DEAL[i % len(SCOPE_RECORD_DEAL)]
+        if chain not in chains:
+            chains.append(chain)
+        groups.setdefault((chains.index(chain), scopes.PASSES.index(which)), []).append(name)
+    return {
+        "event": scopes.PROGRAM_SCOPES_EVENT, "program": needle,
+        "scopes": list(scopes.SCOPES), "passes": list(scopes.PASSES),
+        "chains": [[scopes.SCOPES.index(s) for s in chain] for chain in chains],
+        "ops": [[c, p, names] for (c, p), names in sorted(groups.items())],
+        "mixed": {},
+        "containers": [n for n, text in texts.items()
+                       if " while(" in text or " conditional(" in text],
+        "instructions": sum(len(names) for names in groups.values()),
+        "inherited": 0, "seconds": 0.0,
+    }

@@ -110,6 +110,48 @@ def test_second_interpreter_loads_from_cache(cache_roundtrip):
     assert len(os.listdir(cache_dir)) >= 2
 
 
+_SCOPED_SCRIPT = """
+import json, sys
+sys.path.insert(0, {repo!r})
+from tensorflowdistributedlearning_tpu.utils import compile_cache
+
+assert compile_cache.configure({cache_dir!r})
+import jax, jax.numpy as jnp
+
+@jax.jit
+def f(x):
+    with jax.named_scope({scope!r}):
+        return jnp.tanh(x @ x.T).sum()
+
+x = jnp.ones((8, 8))
+jax.block_until_ready(f(x))
+text = f.lower(x).compile().as_text()
+print(json.dumps(dict(compile_cache.stats(), names=[s for s in ("scope_a", "scope_b") if s in text])))
+"""
+
+
+def test_a_program_under_other_scopes_is_not_loaded(tmp_path):
+    """A loaded program carries the metadata it was compiled with, and the
+    step programs' scope maps (obs/scopes.py) are read from it: the cache is
+    keyed on metadata, so the same computation under another scope compiles
+    for itself, and under the same one still loads."""
+    cache_dir = str(tmp_path / "cache")
+    runs = []
+    for scope in ("scope_a", "scope_a", "scope_b"):
+        script = _SCOPED_SCRIPT.format(repo=REPO, cache_dir=cache_dir, scope=scope)
+        out = subprocess.run(
+            [sys.executable, "-c", script], env=_env(), capture_output=True,
+            text=True, timeout=240,
+        )
+        assert out.returncode == 0, out.stderr[-2000:]
+        runs.append(json.loads(out.stdout.strip().splitlines()[-1]))
+    first, again, other = runs
+    assert first["hits"] == 0 and first["misses"] >= 1
+    assert again["hits"] >= 1 and again["misses"] == 0
+    assert other["misses"] >= 1  # the program itself; what is around it may load
+    assert [r["names"] for r in runs] == [["scope_a"], ["scope_a"], ["scope_b"]]
+
+
 def test_cache_verdicts_reach_the_ledger(cache_roundtrip):
     _, (cold, warm) = cache_roundtrip
     cold_events = obs.read_ledger(cold["workdir"])

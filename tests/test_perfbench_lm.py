@@ -6,7 +6,10 @@ Two of those tests were written when the cell had eight per-layer metrics and
 its windows no ``moe_buffer_rows`` / ``moe_buffer_fill``: the recorded run
 gets the two fields here, as the program writes them since, and the count of
 the cell's metrics is held here as ``BENCHMARK.json`` has it now, until a
-benchmark PR brings that file up to date."""
+benchmark PR brings that file up to date. Since PR 34 the cell also lists six
+``scope_*`` metrics, which read the step program's ``program_scopes`` record:
+the recorded run's ledger gets a made-up one (``tests/test_perfbench_scope.py``
+has the readers' own tests)."""
 
 import json
 import os
@@ -16,6 +19,7 @@ import pytest
 from perfbench import harness
 from perfbench.tests import test_lm_cell
 from perfbench.tests.test_lm_cell import *  # noqa: F401,F403
+from tests.conftest import make_scope_record
 
 _recorded_run = test_lm_cell._recorded_run
 
@@ -24,6 +28,7 @@ def _recorded_run_with_buffer_fields(tmp_path):
     run = _recorded_run(tmp_path)
     for window in run.windows:
         window.update(moe_buffer_rows=[40960.0] * 4, moe_buffer_fill=[0.8, 0.79, 0.81, 0.8])
+    run.ledger = run.ledger + [make_scope_record(run.trace)]
     return run
 
 
