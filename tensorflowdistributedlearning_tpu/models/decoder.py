@@ -45,8 +45,14 @@ their part of each sum. On one chip it runs without the exchange that would
 complete them.
 
 Recomputation: with 4,096 tokens or more in a step each layer is recomputed in
-the backward pass (only the layers' inputs are kept) — chosen from the shapes,
-like the kernels.
+the backward pass — chosen from the shapes, like the kernels. Kept are a
+layer's input and what its attention kernels name (``REMAT_POLICY``): the
+forward kernel's output and log-sum-exp, which the backward kernels read, and
+a sparse layer's selection — so the backward pass runs neither the forward
+attention kernel nor the selection's search a second time. Everything else
+(projections, the indexer's scores, the indexer's loss, experts) is computed
+again; the XLA paths name no attention output (the sparse one its selection
+alone), so there a layer keeps its input and no more than that.
 """
 
 from __future__ import annotations
@@ -69,6 +75,10 @@ from tensorflowdistributedlearning_tpu.parallel import expert as expert_lib
 
 # from this many tokens a step on, layers are recomputed in the backward pass
 REMAT_MIN_TOKENS = 4096
+# what a recomputed layer keeps beside its input: the values its attention's
+# kernels name, whichever its layer type runs
+REMAT_POLICY = jax.checkpoint_policies.save_only_these_names(
+    sparse_lib.SELECT_NAME, sparse_lib.ATTENTION_NAME)
 # tokens whose logits stand at once in the head's loss (and, where it is
 # differentiated, their gradient: both are computed in one pass over a chunk)
 LOSS_CHUNK_TOKENS = 4096
@@ -466,10 +476,7 @@ class MoEDecoder(nn.Module):
         kinds = cfg.layer_types[: cfg.num_hidden_layers]
         layer_cls = DecoderLayer
         if b * t >= REMAT_MIN_TOKENS:
-            # a recomputed sparse layer keeps its selection's thresholds
-            policy = (jax.checkpoint_policies.save_only_these_names(sparse_lib.SELECT_NAME)
-                      if "sparse_attention" in kinds else None)
-            layer_cls = nn.remat(DecoderLayer, policy=policy)
+            layer_cls = nn.remat(DecoderLayer, policy=REMAT_POLICY)
         # the expert layers' counters, a row a sparse layer (a dense one adds none)
         counts, buffer_rows, dropped = [], [], jnp.zeros((), jnp.int32)
         tile_visits = jnp.zeros((), jnp.int32)

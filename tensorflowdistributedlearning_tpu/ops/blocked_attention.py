@@ -12,6 +12,11 @@ shape, and every other backend, takes ``masked_attention_reference``: the same
 mathematics in XLA, blocked over queries so that the scores standing at once
 are ``[heads, block, T]``. The choice is by shape and backend, no flag.
 
+The kernel's forward rule names its output and log-sum-exp, the residuals its
+dq and dkv kernels read (``sparse_attention.ATTENTION_NAME``): a recomputed
+decoder layer keeps them (models/decoder.py:REMAT_POLICY), so its backward
+pass does not run the forward kernel again. The XLA path names nothing.
+
 The library kernel's ``out_shape`` carries no ``vma``, so ``pallas_call``
 refuses it inside a ``shard_map`` that checks varying manual axes: a step that
 runs it is built with ``check_vma=False`` (train/step.py:SequenceTask).
@@ -30,6 +35,8 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 from jax import lax
+
+from tensorflowdistributedlearning_tpu.ops.sparse_attention import ATTENTION_NAME
 
 # queries per block of the XLA path: [heads, 512, T] float32 scores at once
 _REFERENCE_BLOCK_Q = 512
@@ -103,7 +110,8 @@ def _splash_kernel(t: int, group: int, window: Optional[int], interpret: bool):
     # concretely, so one object serves every trace
     with jax.ensure_compile_time_eval():
         return splash.make_splash_mqa_single_device(
-            masks.MultiHeadMask([mask] * group), block_sizes=sizes, interpret=interpret
+            masks.MultiHeadMask([mask] * group), block_sizes=sizes, interpret=interpret,
+            residual_checkpoint_name=ATTENTION_NAME,
         )
 
 

@@ -10,7 +10,7 @@ document that a small *indexer* scores highest — DeepSeek sparse attention
    by counting (32 counts over the row, no sort), and the position up to which
    keys that tie at the threshold are taken, found the same way (the earlier
    key wins). The two numbers say which keys a query reads; they pass no gradient
-   and are what a recomputed layer keeps (``SELECT_NAME``). The kernel path's
+   and a recomputed layer keeps them (``SELECT_NAME``). The kernel path's
    one kernel holds a block of rows in VMEM and runs every count over the
    columns that block can see alone — up to its last row's own position, and
    from the start of its first row's document where documents are packed —
@@ -34,6 +34,14 @@ and mask inside it (flash attention: forward, dq, dkv): what reading only
 the selected keys would take is ROADMAP S8. Any other shape or backend takes
 the same mathematics in XLA, blocked over queries. The choice is by shape and
 backend, no flag.
+
+What a recomputed layer keeps (models/decoder.py:REMAT_POLICY): the selection's
+two numbers a query (``SELECT_NAME``) and, on the kernel path, what the forward
+attention kernel wrote that the backward pass reads — its output and the two
+log-sum-exps (``ATTENTION_NAME``) — so the backward pass runs neither the
+search nor ``sparse_attend`` again. The scores and the loss's ``[T, T]``
+gradient are too large to keep: ``sparse_indexer_scores`` and ``sparse_align``
+still run twice. The XLA path names the selection alone.
 """
 
 from __future__ import annotations
@@ -53,6 +61,11 @@ from tensorflowdistributedlearning_tpu.obs import scopes
 # the selection's two numbers carry this name: a recomputed layer keeps them
 # (models/decoder.py) and so does not search for the thresholds again
 SELECT_NAME = "sparse_select"
+# what a forward attention kernel wrote that its backward kernels read, the
+# output and the log-sum-exp, carries this name here and in
+# ops/blocked_attention.py: a recomputed layer keeps them too, and so does not
+# run the forward kernel a second time to have them back
+ATTENTION_NAME = "attention_residual"
 
 _INT_MIN = np.int32(-(2**31))
 # queries per block of the XLA path
@@ -747,6 +760,10 @@ def _attend_kernel_path(q, k, v, scores, tau, tie, interpret):
 
 def _attend_fwd(q, k, v, scores, tau, tie, interpret):
     out, lse, lse_i, reads = _attend_pallas(q, k, v, scores, tau, tie, interpret)
+    # named here, before they part into the outputs and the residuals: kept,
+    # they serve the backward kernels and the recomputed ``sparse_align`` alike,
+    # and nothing asks for the kernel again (the counts are the first pass's)
+    out, lse, lse_i = (checkpoint_name(x, ATTENTION_NAME) for x in (out, lse, lse_i))
     return (out, lse, lse_i, reads), (q, k, v, scores, tau, tie, lse, out)
 
 

@@ -83,6 +83,7 @@ def test_the_sparse_layer_is_eight_kernels_and_no_product_over_heads(one_chip, a
     (scores, threshold, attend, align; the scores' two and the attention's two
     gradients), nothing of ``[heads, T, T]`` stands in memory, nothing but the
     one kernel searches the selection, and a recomputed layer runs it once."""
+    from tensorflowdistributedlearning_tpu.models import decoder as decoder_lib
     from tensorflowdistributedlearning_tpu.ops import sparse_attention as sparse_lib
 
     t, hq, hd, heads, dim, topk = 16384, 4, 128, 16, 64, 2048
@@ -105,12 +106,15 @@ def test_the_sparse_layer_is_eight_kernels_and_no_product_over_heads(one_chip, a
     # the selection is its kernel alone: no integers or flags a (query, key) pair beside it
     for searched in (f"pred[{t},{t}]", f"s32[{t},{t}]"):
         assert searched not in text, searched
-    # a recomputed layer keeps the selection's two numbers a query, so its
-    # backward pass runs scores, attention and loss again, not the search
-    kept = jax.checkpoint(
-        loss, policy=jax.checkpoint_policies.save_only_these_names(sparse_lib.SELECT_NAME))
+    # a recomputed layer (the decoder's own policy) keeps the selection's two
+    # numbers a query and the attention kernel's output and log-sum-exps, so
+    # its backward pass runs scores and loss again, not the search and not
+    # the forward attention
+    kept = jax.checkpoint(loss, policy=decoder_lib.REMAT_POLICY)
     text = _compiled_text(jax.value_and_grad(kept, argnums=range(6), has_aux=True), one_chip, *shapes)
-    assert _kernels(text) == 11 and len(re.findall(r"%sparse_select[.\d]* = ", text)) == 1
+    assert _kernels(text) == 10
+    for once in ("sparse_select", "sparse_attend"):
+        assert len(re.findall(rf"%{once}[.\d]* = ", text)) == 1, once
 
 
 @pytest.mark.parametrize("heads,window", [(8, 512), (6, None)])
@@ -120,6 +124,7 @@ def test_attention_takes_the_mixed_layers_head_groups(one_chip, as_on_a_tpu, hea
     takes the splash kernels — forward, dq, dkv — at a window layer's group of
     8 query heads under a window of 512 (a band two 512-blocks wide) and at a
     full layer's group of 6, and no ``[heads, T, T]`` scores stand in memory."""
+    from tensorflowdistributedlearning_tpu.models import decoder as decoder_lib
     from tensorflowdistributedlearning_tpu.ops import blocked_attention as attn_lib
 
     t, hd = 16384, 128
@@ -130,12 +135,19 @@ def test_attention_takes_the_mixed_layers_head_groups(one_chip, as_on_a_tpu, hea
         return jnp.sum(out.astype(jnp.float32))
 
     bf16 = jnp.bfloat16
-    text = _compiled_text(
-        jax.value_and_grad(loss, argnums=range(3)), one_chip,
-        ((1, t, heads, hd), bf16), ((1, t, 1, hd), bf16), ((1, t, 1, hd), bf16),
-        ((1, t), jnp.int32))
+    shapes = (((1, t, heads, hd), bf16), ((1, t, 1, hd), bf16), ((1, t, 1, hd), bf16),
+              ((1, t), jnp.int32))
+    text = _compiled_text(jax.value_and_grad(loss, argnums=range(3)), one_chip, *shapes)
     assert _kernels(text) == 3
     assert f"[{heads},{t},{hd}]" in text and f"{t},{t}]" not in text
+    # recomputed under the decoder's own policy the forward kernel's output and
+    # log-sum-exp are kept: still three kernels, where a layer that keeps its
+    # input alone runs the forward kernel a second time
+    for policy, kernels in ((decoder_lib.REMAT_POLICY, 3), (None, 4)):
+        text = _compiled_text(
+            jax.value_and_grad(jax.checkpoint(loss, policy=policy), argnums=range(3)), one_chip,
+            *shapes)
+        assert _kernels(text) == kernels, policy
 
 
 def test_a_segment_of_small_experts_is_eleven_kernels(one_chip, as_on_a_tpu):
