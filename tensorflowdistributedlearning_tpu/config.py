@@ -13,7 +13,15 @@ import dataclasses
 from typing import Optional, Tuple
 
 
-DECODER_LAYER_TYPES = ("sliding_attention", "full_attention", "sparse_attention")
+DECODER_LAYER_TYPES = ("sliding_attention", "full_attention", "sparse_attention",
+                       "linear_attention", "latent_attention")
+# published keys read under this class's names (Kimi-Linear's, DeepSeek-V3's)
+_PUBLISHED_ALIASES = {
+    "num_experts_per_token": "num_experts_per_tok",
+    "moe_router_activation_func": "scoring_func",
+    "moe_renormalize": "norm_topk_prob",
+    "routed_scaling_factor": "moe_routed_scaling_factor",
+}
 
 
 @dataclasses.dataclass(frozen=True)
@@ -32,7 +40,11 @@ class DecoderConfig:
     them. One exception: where the published key-value heads are fewer than
     the shares (4 heads over 8 chips), a key-value head is held by several
     shares, each with its own part of that head's query heads, and
-    ``num_key_value_heads`` held is 1. The router keeps its published width
+    ``num_key_value_heads`` held is 1. A share may also divide the experts
+    alone: where a configuration holds every published head
+    (``num_attention_heads`` as published), attention is whole on every
+    share — data-parallel attention beside expert parallelism, as the
+    Kimi-Linear preset is laid out. The router keeps its published width
     (``num_experts * share_count`` outputs), its ``num_experts_per_tok`` and
     its renormalisation over all chosen experts, held or not; the layer
     computes the part of the attention and expert sums that its own heads and
@@ -53,6 +65,13 @@ class DecoderConfig:
     rotary embedding may cover the first ``partial_rotary_factor`` of a head
     only (a key of a layer type's ``rope_parameters``), and ``gating`` puts a
     sigmoid gate, one scalar a head, on the heads' outputs.
+    ``linear_attention`` is Kimi Delta Attention (ops/kda.py: a chunked,
+    channel-gated delta-rule recurrence with short convolutions,
+    ``linear_attn_config``) and ``latent_attention`` is multi-head latent
+    attention without a rotary part (``kv_lora_rank``, ``qk_nope_head_dim``,
+    ``qk_rope_head_dim``, ``v_head_dim``, ``mla_use_nope``): the Kimi-Linear
+    family's two layers, read from its published ``kda_layers`` /
+    ``full_attn_layers`` (1-indexed) where a config has no ``layer_types``.
 
     **MLPs.** ``mlp_layer_types`` says layer by layer ``dense`` (one SiLU-gated
     MLP of ``intermediate_size``) or ``sparse`` (the routed experts; every
@@ -114,6 +133,17 @@ class DecoderConfig:
     sa_config: Tuple[Tuple[str, int], ...] = ()
     # per-head RMS normalisation of q and k with a learned [head_dim] scale
     use_qk_norm: bool = False
+    # "linear_attention" layers, under the published keys (num_heads,
+    # head_dim, short_conv_kernel_size): sorted (key, value) pairs
+    linear_attn_config: Tuple[Tuple[str, int], ...] = ()
+    # "latent_attention" layers: the compressed key-value width, the query's
+    # low rank (None: a full projection), a head's parts and no rotation
+    kv_lora_rank: int = 0
+    q_lora_rank: Optional[int] = None
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
+    mla_use_nope: bool = False
     share_count: int = 1
     share_index: int = 0
     # tokens of one packed training sequence (data/tokens.py)
@@ -135,6 +165,18 @@ class DecoderConfig:
                 raise ValueError(f"sparse_attention layers need sa_config keys {sorted(missing)}")
             if sa.get("indexer_num_kv_heads", 1) != 1:
                 raise ValueError("the indexer has one key head (indexer_num_kv_heads 1)")
+        if "linear_attention" in self.layer_types:
+            missing = {"num_heads", "head_dim", "short_conv_kernel_size"} - set(self.linear)
+            if missing:
+                raise ValueError(
+                    f"linear_attention layers need linear_attn_config keys {sorted(missing)}")
+        if "latent_attention" in self.layer_types:
+            if not (self.kv_lora_rank and self.qk_nope_head_dim and self.v_head_dim):
+                raise ValueError("latent_attention layers need kv_lora_rank, qk_nope_head_dim "
+                                 "and v_head_dim")
+            if not self.mla_use_nope or self.q_lora_rank is not None:
+                raise ValueError("latent_attention is built without a rotary part and with a full "
+                                 "query projection: mla_use_nope true, q_lora_rank null")
         if self.num_attention_heads % self.num_key_value_heads:
             raise ValueError("num_attention_heads must be a multiple of num_key_value_heads")
         for name in ("num_attention_heads_per_layer", "mlp_layer_types"):
@@ -173,8 +215,32 @@ class DecoderConfig:
         passed over); ``share`` gives share_count, share_index,
         sequence_length."""
         names = {f.name for f in dataclasses.fields(cls)}
+        config = dict(config)
+        for published, name in _PUBLISHED_ALIASES.items():
+            if published in config:
+                config[name] = config[published]
         # a published null (Keye's sliding_window) says nothing: the default stays
         kept = {k: v for k, v in config.items() if k in names and v is not None}
+        linear = config.get("linear_attn_config")
+        if "layer_types" not in config and linear:
+            # Kimi-Linear: the layers by their 1-indexed lists
+            count = max(linear["kda_layers"] + linear["full_attn_layers"])
+            config["layer_types"] = [
+                "linear_attention" if i + 1 in linear["kda_layers"] else "latent_attention"
+                for i in range(count)]
+            kept["linear_attn_config"] = tuple(sorted(
+                (k, v) for k, v in linear.items() if not isinstance(v, list)))
+            kept["q_lora_rank"] = config.get("q_lora_rank")
+        if "first_k_dense_replace" in config and "mlp_layer_types" not in config:
+            # DeepSeek-V3's rule: dense before the first k, then an expert
+            # layer every moe_layer_freq
+            first, every = config["first_k_dense_replace"], config.get("moe_layer_freq", 1)
+            kept["mlp_layer_types"] = tuple(
+                "sparse" if i >= first and i % every == 0 else "dense"
+                for i in range(len(config["layer_types"])))
+        if config.get("num_shared_experts"):
+            kept["shared_expert_intermediate_size"] = (
+                config["num_shared_experts"] * config["moe_intermediate_size"])
         kept["layer_types"] = tuple(config["layer_types"])
         for name in ("num_attention_heads_per_layer", "mlp_layer_types"):
             if name in kept:
@@ -216,6 +282,10 @@ class DecoderConfig:
     @property
     def indexer(self) -> dict:
         return dict(self.sa_config)
+
+    @property
+    def linear(self) -> dict:
+        return dict(self.linear_attn_config)
 
 
 @dataclasses.dataclass(frozen=True)

@@ -421,6 +421,76 @@ PRESETS: Dict[str, Preset] = {
         "16,384-token sequences: chip 0's share of a layer divided over 8 chips, "
         "layers 0-4 of 40 — a partial result by design (config.py:DecoderConfig)",
     ),
+    # Kimi-Linear-48B-A3B (moonshotai, model_type kimi_linear,
+    # https://huggingface.co/moonshotai/Kimi-Linear-48B-A3B-Instruct/blob/main/config.json;
+    # the published keys are in perfbench/configs/kimi_linear_48b_a3b_share32.json):
+    # Kimi Delta Attention (32 heads of 128, short convolutions of 4, a decay
+    # by channel; ops/kda.py) on three layers in four and multi-head latent
+    # attention without a rotary part (32 heads, q.k 192, v 128, a 512-wide
+    # compressed key-value) on the fourth; a leading dense MLP of 9,216, then
+    # 256 routed experts of 1,024, 8 a token by sigmoid scores renormalised and
+    # scaled by 2.446, beside one shared expert. Published widths; chip 0 of
+    # 32 sharing each expert layer by experts (8 of 256), attention, layer 0's
+    # MLP, the shared expert and the router whole on every chip, 20,480 of
+    # 163,840 vocabulary rows (divided over 8), layers 0-4 of 27 — 602.4M
+    # parameters here, 9.64 GB with gradients and Adam's moments. Assumed (the
+    # config has no key): the KDA's scale, convolutions and initialisation, no
+    # router bias and no auxiliary loss, AdamW 3e-7 / 0.1.
+    "kimi_linear_48b_a3b_share32": Preset(
+        model=ModelConfig(
+            backbone="decoder",
+            dtype="bfloat16",
+            decoder=DecoderConfig(
+                hidden_size=2304,
+                head_dim=72,
+                num_attention_heads=32,
+                num_key_value_heads=32,
+                num_hidden_layers=5,
+                layer_types=(("linear_attention",) * 3 + ("latent_attention",)) * 6
+                + ("linear_attention",) * 2 + ("latent_attention",),
+                num_experts=8,
+                num_experts_per_tok=8,
+                moe_intermediate_size=1024,
+                scoring_func="sigmoid",
+                moe_routed_scaling_factor=2.446,
+                mlp_layer_types=("dense",) + ("sparse",) * 26,
+                intermediate_size=9216,
+                shared_expert_intermediate_size=1024,
+                rms_norm_eps=1e-5,
+                vocab_size=20480,
+                rope_parameters=(
+                    ("latent_attention", (("rope_theta", 10000), ("rope_type", "default"))),
+                    ("linear_attention", (("rope_theta", 10000), ("rope_type", "default"))),
+                ),
+                linear_attn_config=(("head_dim", 128), ("num_heads", 32),
+                                    ("short_conv_kernel_size", 4)),
+                kv_lora_rank=512,
+                qk_nope_head_dim=128,
+                qk_rope_head_dim=64,
+                v_head_dim=128,
+                mla_use_nope=True,
+                share_count=32,
+                share_index=0,
+                sequence_length=16384,
+            ),
+        ),
+        # the rate is small for the reason the Mellum-2 preset gives; a window
+        # every 10 steps, as the Keye preset has it
+        train=TrainConfig(
+            optimizer="adam", lr=3e-7, weight_decay=0.1, augmentation="none",
+            train_log_every_steps=10,
+            token_stream=TokenStreamConfig(
+                median_length=8192.0, sigma=1.0, min_length=256, max_length=16384
+            ),
+        ),
+        global_batch=1,
+        description="Kimi-Linear-48B-A3B decoder (Kimi Delta Attention on three layers in "
+        "four, latent attention without rotation on the fourth, a leading dense MLP, a "
+        "shared expert and sigmoid-routed top-8-of-256 experts of 1,024), next-token "
+        "training on packed 16,384-token sequences: chip 0 of 32 sharing each expert "
+        "layer by experts, attention whole, layers 0-4 of 27 — a partial result by design "
+        "(config.py:DecoderConfig)",
+    ),
 }
 
 

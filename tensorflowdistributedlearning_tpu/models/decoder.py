@@ -21,7 +21,14 @@ own, returned beside the cross-entropy. How many query heads a layer has may go
 by layer (``num_attention_heads_per_layer``), and with ``gating`` each head's
 output is multiplied by a sigmoid gate, one scalar a head and position, from
 the layer's normalised input (float32) — both the Laguna family's
-(https://huggingface.co/poolside/Laguna-XS.2, ``model_type: laguna``).
+(https://huggingface.co/poolside/Laguna-XS.2, ``model_type: laguna``). A
+``linear_attention`` layer mixes by Kimi Delta Attention
+(``KimiDeltaAttention``: short convolutions, a decay by channel and a
+delta-rule recurrence, ops/kda.py) and a ``latent_attention`` layer by
+multi-head latent attention without a rotary part (``LatentAttention``) —
+the Kimi-Linear family's
+(https://huggingface.co/moonshotai/Kimi-Linear-48B-A3B-Instruct,
+``model_type: kimi_linear``).
 
 The MLP goes by ``mlp_layer_types``: ``sparse`` (every layer where the list
 is empty) is a float32 router over all experts — a softmax over them or a
@@ -48,8 +55,9 @@ Recomputation: with 4,096 tokens or more in a step each layer is recomputed in
 the backward pass — chosen from the shapes, like the kernels. Kept are a
 layer's input and what its attention kernels name (``REMAT_POLICY``): the
 forward kernel's output and log-sum-exp, which the backward kernels read, and
-a sparse layer's selection — so the backward pass runs neither the forward
-attention kernel nor the selection's search a second time. Everything else
+a sparse layer's selection, a linear layer's KDA output and the states its
+kernel's blocks start from — so the backward pass runs neither the forward
+attention or KDA kernel nor the selection's search a second time. Everything else
 (projections, the indexer's scores, the indexer's loss, experts) is computed
 again; the XLA paths name no attention output (the sparse one its selection
 alone), so there a layer keeps its input and no more than that.
@@ -69,6 +77,7 @@ from jax import lax
 
 from tensorflowdistributedlearning_tpu.config import DecoderConfig, ModelConfig
 from tensorflowdistributedlearning_tpu.obs import scopes
+from tensorflowdistributedlearning_tpu.ops import kda as kda_lib
 from tensorflowdistributedlearning_tpu.ops import sparse_attention as sparse_lib
 from tensorflowdistributedlearning_tpu.ops.blocked_attention import blocked_attention
 from tensorflowdistributedlearning_tpu.parallel import expert as expert_lib
@@ -78,7 +87,7 @@ REMAT_MIN_TOKENS = 4096
 # what a recomputed layer keeps beside its input: the values its attention's
 # kernels name, whichever its layer type runs
 REMAT_POLICY = jax.checkpoint_policies.save_only_these_names(
-    sparse_lib.SELECT_NAME, sparse_lib.ATTENTION_NAME)
+    sparse_lib.SELECT_NAME, sparse_lib.ATTENTION_NAME, kda_lib.KDA_NAME)
 # tokens whose logits stand at once in the head's loss (and, where it is
 # differentiated, their gradient: both are computed in one pass over a chunk)
 LOSS_CHUNK_TOKENS = 4096
@@ -262,6 +271,122 @@ class DecoderAttention(nn.Module):
             return out, extras
 
 
+class ShortConvolution(nn.Module):
+    """A causal depthwise convolution over ``kernel_size`` positions with no
+    bias, inside each document: ``y_t = sum_j w[K - 1 - j] x_{t - j}`` over the
+    ``j <= positions_t``. float32."""
+
+    kernel_size: int
+
+    @nn.compact
+    def __call__(self, x, positions):
+        w = self.param("kernel", _INIT, (self.kernel_size, x.shape[-1]), jnp.float32)
+        out = x * w[-1]
+        for j in range(1, self.kernel_size):
+            earlier = jnp.pad(x[:, :-j], ((0, 0), (j, 0), (0, 0)))
+            out = out + jnp.where((positions >= j)[..., None], earlier, 0.0) * w[-1 - j]
+        return out
+
+
+def _l2_normalize(x, eps: float = 1e-6):
+    return x * lax.rsqrt(jnp.sum(jnp.square(x), axis=-1, keepdims=True) + eps)
+
+
+def _inverse_softplus(y):
+    return y + jnp.log(-jnp.expm1(-y))
+
+
+class KimiDeltaAttention(nn.Module):
+    """``(the layer's part of the residual update, extras)`` of a
+    ``linear_attention`` layer — Kimi Delta Attention as the family's
+    modelling code has it, with ``h`` heads of ``d`` channels
+    (``linear_attn_config``):
+
+    - ``q, k = L2norm(SiLU(conv(u W_q|k)))``, ``v = SiLU(conv(u W_v))``, each
+      convolution causal, depthwise, without bias and inside the document;
+    - the decay by channel ``g = -exp(A_log_h) softplus(u W_fa W_fb +
+      dt_bias)`` and ``beta = sigmoid(u W_b)``, a scalar a head;
+    - the recurrence of ops/kda.py with ``q`` scaled by ``d^-1/2``;
+    - ``W_o concat_h(RMSNorm_d(o_h) * sigmoid(u W_ga W_gb)_h)``.
+
+    The projections to q, k, v and back run in ``dtype``; the decay, beta and
+    the output gate's in float32 at the highest precision, like the router.
+    ``extras["decay"]`` is the sum of ``exp(g)`` over positions, heads and
+    channels."""
+
+    cfg: DecoderConfig
+    dtype: Any
+
+    @nn.compact
+    def __call__(self, u, positions):
+        cfg, lin = self.cfg, self.cfg.linear
+        b, t, _ = u.shape
+        h, d, width = lin["num_heads"], lin["head_dim"], lin["short_conv_kernel_size"]
+        with scopes.scope("decoder/attn_linear"):
+            with scopes.scope("decoder/attn_linear/proj"):
+                q, k, v = (Projection(h * d, self.dtype, name=f"{w}_proj")(u) for w in "qkv")
+                f = Projection(h * d, jnp.float32, precise=True, name="f_b_proj")(
+                    Projection(d, jnp.float32, precise=True, name="f_a_proj")(u))
+                beta = jax.nn.sigmoid(Projection(h, jnp.float32, precise=True, name="b_proj")(u))
+            with scopes.scope("decoder/attn_linear/conv"):
+                q, k, v = (jax.nn.silu(ShortConvolution(width, name=f"{w}_conv1d")(x, positions))
+                           for w, x in zip("qkv", (q, k, v)))
+                a_log = self.param("A_log", lambda key, s: jnp.log(
+                    jax.random.uniform(key, s, jnp.float32, 1.0, 16.0)), (h,))
+                dt_bias = self.param("dt_bias", lambda key, s: _inverse_softplus(jnp.exp(
+                    jax.random.uniform(key, s, jnp.float32, math.log(1e-3), math.log(1e-1)))),
+                    (h * d,))
+                g = -jnp.exp(a_log)[:, None] * jax.nn.softplus(
+                    (f + dt_bias).reshape(b, t, h, d))
+                q = (_l2_normalize(q.reshape(b, t, h, d)) * d**-0.5).astype(self.dtype)
+                k = _l2_normalize(k.reshape(b, t, h, d)).astype(self.dtype)
+                v = v.reshape(b, t, h, d).astype(self.dtype)
+            o = kda_lib.kda(q, k, v, g, beta, positions)  # in its own scope, .../kda
+            with scopes.scope("decoder/attn_linear/out_norm"):
+                gate = Projection(h * d, jnp.float32, precise=True, name="g_b_proj")(
+                    Projection(d, jnp.float32, precise=True, name="g_a_proj")(u))
+                o = RMSNorm(cfg.rms_norm_eps, name="o_norm")(o) * jax.nn.sigmoid(
+                    gate.reshape(b, t, h, d))
+            with scopes.scope("decoder/attn_linear/proj"):
+                out = Projection(cfg.hidden_size, self.dtype, name="o_proj")(o.reshape(b, t, h * d))
+            return out, {"decay": jnp.sum(jnp.exp(g))}
+
+
+class LatentAttention(nn.Module):
+    """``(the layer's part of the residual update, extras)`` of a
+    ``latent_attention`` layer: multi-head latent attention without a rotary
+    part (``mla_use_nope``). ``q_h = u W_q`` is ``[nope, rope]`` wide; ``c =
+    u W_kva`` is ``kv_lora_rank + qk_rope_head_dim`` wide, ``[k_nope_h, v_h] =
+    RMSNorm(c[:rank]) W_kvb`` and ``k_pe = c[rank:]`` is every head's, not
+    rotated. Causal softmax inside the document over ``q_h . [k_nope_h,
+    k_pe]`` scaled by the query head's size (ops/blocked_attention.py: queries
+    and keys wider than the values), then ``W_o concat_h``."""
+
+    cfg: DecoderConfig
+    dtype: Any
+
+    @nn.compact
+    def __call__(self, u, segment_ids):
+        cfg = self.cfg
+        b, t, _ = u.shape
+        h, nope, rope = cfg.num_attention_heads, cfg.qk_nope_head_dim, cfg.qk_rope_head_dim
+        rank, vd = cfg.kv_lora_rank, cfg.v_head_dim
+        with scopes.scope("decoder/attn_latent"):
+            q = Projection(h * (nope + rope), self.dtype, name="q_proj")(u).reshape(
+                b, t, h, nope + rope)
+            c = Projection(rank + rope, self.dtype, name="kv_a_proj_with_mqa")(u)
+            kv = Projection(h * (nope + vd), self.dtype, name="kv_b_proj")(
+                RMSNorm(cfg.rms_norm_eps, name="kv_a_layernorm")(c[..., :rank])
+            ).reshape(b, t, h, nope + vd)
+            k_pe = jnp.broadcast_to(c[:, :, None, rank:], (b, t, h, rope))
+            k = jnp.concatenate([kv[..., :nope], k_pe], axis=-1)
+            with scopes.scope("decoder/attn_latent/attend"):
+                out = blocked_attention(q.astype(self.dtype), k.astype(self.dtype),
+                                        kv[..., nope:].astype(self.dtype), segment_ids)
+            out = Projection(cfg.hidden_size, self.dtype, name="o_proj")(out.reshape(b, t, h * vd))
+            return out, {}
+
+
 class GatedMLP(nn.Module):
     """``(SiLU(u W_gate) * (u W_up)) W_down``: a ``dense`` layer's MLP and the
     shared expert of a ``sparse`` one."""
@@ -324,9 +449,15 @@ class DecoderLayer(nn.Module):
         cfg, eps = self.cfg, self.cfg.rms_norm_eps
         with scopes.scope("decoder/norm"):
             u = RMSNorm(eps, name="attn_norm")(x)
-        attended, extras = DecoderAttention(
-            cfg, cfg.layer_types[self.index], self.dtype, cfg.heads(self.index), name="attn"
-        )(u, segment_ids, positions)
+        kind = cfg.layer_types[self.index]
+        if kind == "linear_attention":
+            attended, extras = KimiDeltaAttention(cfg, self.dtype, name="attn")(u, positions)
+        elif kind == "latent_attention":
+            attended, extras = LatentAttention(cfg, self.dtype, name="attn")(u, segment_ids)
+        else:
+            attended, extras = DecoderAttention(
+                cfg, kind, self.dtype, cfg.heads(self.index), name="attn"
+            )(u, segment_ids, positions)
         h = x + attended
         if cfg.mlp_type(self.index) == "dense":
             with scopes.scope("decoder/norm"):
@@ -482,6 +613,7 @@ class MoEDecoder(nn.Module):
         tile_visits = jnp.zeros((), jnp.int32)
         align, reads, searched = jnp.zeros((), jnp.float32), [], []
         gate_sums, gate_counts = {}, {}  # a gated model's, by layer type
+        decay = jnp.zeros((), jnp.float32)  # exp(g) summed over the linear layers
         for i, kind in enumerate(kinds):
             x, (counters, extras) = layer_cls(cfg, i, dtype, name=f"layers_{i}")(
                 x, segment_ids, positions
@@ -496,6 +628,8 @@ class MoEDecoder(nn.Module):
                 align = align + extras["align"]
                 reads.append(extras["reads"])
                 searched.append(extras["searched"])
+            if "decay" in extras:
+                decay = decay + extras["decay"]
             if "gate" in extras:
                 short = kind.split("_")[0]
                 gate_sums[short] = gate_sums.get(short, 0.0) + extras["gate"]
@@ -514,6 +648,7 @@ class MoEDecoder(nn.Module):
             "full_attention": lambda: jnp.sum(seen),
             "sliding_attention": lambda: jnp.sum(jnp.minimum(seen, float(cfg.sliding_window))),
             "sparse_attention": lambda: jnp.sum(jnp.stack(reads)) / len(reads),
+            "latent_attention": lambda: jnp.sum(seen),
         }
         out.update(
             expert_tokens=jnp.stack(counts).astype(jnp.float32),
@@ -522,7 +657,8 @@ class MoEDecoder(nn.Module):
             # row tiles of the grouped products that the held experts' groups
             # overlap, every sparse layer together
             tile_visits=tile_visits.astype(jnp.float32),
-            **{f"attn_keys_{kind.split('_')[0]}": attn_keys[kind]() for kind in sorted(set(kinds))},
+            **{f"attn_keys_{kind.split('_')[0]}": attn_keys[kind]()
+               for kind in sorted(set(kinds)) if kind in attn_keys},
             # a gated model's gates by layer type: their sum, and how many
             **{f"attn_gate_sum_{kind}": total for kind, total in gate_sums.items()},
             **{f"attn_gate_n_{kind}": jnp.asarray(n, jnp.float32)
@@ -530,6 +666,13 @@ class MoEDecoder(nn.Module):
             n_sequences=jnp.asarray(b, jnp.float32),
             n_positions=jnp.asarray(b * t, jnp.float32),
         )
+        if "linear_attention" in kinds:
+            # the decays exp(g) summed over the linear layers' positions, heads
+            # and channels, and how many there are
+            lin = cfg.linear
+            out.update(kda_decay_sum=decay, kda_decay_n=jnp.asarray(
+                b * t * kinds.count("linear_attention") * lin["num_heads"] * lin["head_dim"],
+                jnp.float32))
         if "sparse_attention" in kinds:
             # the indexer's loss, the pairs its layers scored, how many
             # queries read each key position, by sparse layer [layers, T], and

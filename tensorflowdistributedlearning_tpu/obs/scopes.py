@@ -68,6 +68,17 @@ SCOPES: Tuple[str, ...] = (
     "decoder/attn_sparse/select",
     "decoder/attn_sparse/attend",
     "decoder/attn_sparse/align",
+    # a linear_attention layer (ops/kda.py): its projections, the short
+    # convolutions with the decay and beta, the recurrence's kernels, the
+    # gated output norm
+    "decoder/attn_linear",
+    "decoder/attn_linear/proj",
+    "decoder/attn_linear/conv",
+    "decoder/attn_linear/kda",
+    "decoder/attn_linear/out_norm",
+    # a latent_attention layer: its projections, and its kernels
+    "decoder/attn_latent",
+    "decoder/attn_latent/attend",
     "decoder/attn_proj",
     "decoder/attn_gate",
     "decoder/moe/route",

@@ -7,8 +7,10 @@ On a TPU it is the Pallas kernel ``jax`` ships
 (``jax.experimental.pallas.ops.tpu.splash_attention``): forward, dq and dkv
 kernels that never write a ``[T, T]`` score matrix and that skip the key
 blocks the causal and window masks empty. It is taken when the shapes fit its
-tiles (sequence a multiple of 128, head size a multiple of 128); any other
-shape, and every other backend, takes ``masked_attention_reference``: the same
+tiles (sequence a multiple of 128, head size a multiple of 128 — or, where the
+values are narrower than the queries and keys as in a latent-attention layer,
+queries and keys a multiple of 64 and values of 128); any other shape, and
+every other backend, takes ``masked_attention_reference``: the same
 mathematics in XLA, blocked over queries so that the scores standing at once
 are ``[heads, block, T]``. The choice is by shape and backend, no flag.
 
@@ -53,9 +55,9 @@ def masked_attention_reference(
     *,
     window: Optional[int] = None,
 ) -> jax.Array:
-    """q [B, T, Hq, hd], k/v [B, T, Hkv, hd], segment_ids [B, T] -> [B, T, Hq, hd].
-    Position i sees j where j <= i, both in one segment, and i - j < window.
-    Scores and softmax in float32, operands in the inputs' dtype."""
+    """q/k [B, T, Hq|Hkv, hd], v [B, T, Hkv, vd], segment_ids [B, T] -> [B, T,
+    Hq, vd]. Position i sees j where j <= i, both in one segment, and i - j <
+    window. Scores and softmax in float32, operands in the inputs' dtype."""
     b, t, hq, hd = q.shape
     group = hq // k.shape[2]
     scale = 1.0 / math.sqrt(hd)
@@ -84,7 +86,7 @@ def masked_attention_reference(
                 preferred_element_type=jnp.float32,
             ).astype(q.dtype)
 
-        return lax.map(rows, jnp.arange(0, t, block)).reshape(t, hq, hd)
+        return lax.map(rows, jnp.arange(0, t, block)).reshape(t, hq, v.shape[-1])
 
     return lax.map(one_sequence, (q, k, v, segment_ids))
 
@@ -115,9 +117,11 @@ def _splash_kernel(t: int, group: int, window: Optional[int], interpret: bool):
         )
 
 
-def kernel_serves(t: int, head_dim: int) -> bool:
+def kernel_serves(t: int, head_dim: int, value_dim: Optional[int] = None) -> bool:
     """The shapes the Pallas kernel takes (on a TPU)."""
-    return t % 128 == 0 and head_dim % 128 == 0
+    if value_dim is None or value_dim == head_dim:
+        return t % 128 == 0 and head_dim % 128 == 0
+    return t % 128 == 0 and head_dim % 64 == 0 and value_dim % 128 == 0
 
 
 def splash_attention(q, k, v, segment_ids, *, window=None, interpret=False):
@@ -141,7 +145,7 @@ def splash_attention(q, k, v, segment_ids, *, window=None, interpret=False):
 
     per_head = jax.vmap(one_head, in_axes=(0, 0, 0, None))
     out = jax.vmap(per_head)(qh, kh, vh, segment_ids.astype(jnp.int32))
-    return out.transpose(0, 3, 1, 2, 4).reshape(b, t, hq, hd)
+    return out.transpose(0, 3, 1, 2, 4).reshape(b, t, hq, v.shape[-1])
 
 
 def blocked_attention(q, k, v, segment_ids, *, window: Optional[int] = None):
@@ -149,6 +153,6 @@ def blocked_attention(q, k, v, segment_ids, *, window: Optional[int] = None):
     docstring for which path serves which shapes."""
     from tensorflowdistributedlearning_tpu.ops import pallas_kernels
 
-    if pallas_kernels.pallas_platform_ok() and kernel_serves(q.shape[1], q.shape[3]):
+    if pallas_kernels.pallas_platform_ok() and kernel_serves(q.shape[1], q.shape[3], v.shape[3]):
         return splash_attention(q, k, v, segment_ids, window=window)
     return masked_attention_reference(q, k, v, segment_ids, window=window)

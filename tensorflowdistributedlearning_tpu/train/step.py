@@ -482,11 +482,17 @@ class SequenceTask:
             # row tiles the grouped products visit for the held experts'
             # groups, every sparse layer together (a step's mean)
             "moe_tile_visits": round(scalars["moe/tile_visits"], 2),
+            # by the layer types whose queries read keys (a linear layer's
+            # read its state)
             "attn_keys_per_query": {
-                kind: round(scalars[f"attn/keys_per_query_{kind.split('_')[0]}"], 2)
+                kind: round(scalars[key], 2)
                 for kind in sorted(set(self.decoder.layer_types[: self.decoder.num_hidden_layers]))
+                if (key := f"attn/keys_per_query_{kind.split('_')[0]}") in scalars
             },
         }
+        if "attn/kda_decay_mean" in scalars:
+            # the linear layers' mean decay exp(g) over positions, heads and channels
+            fields["kda_decay_mean"] = round(scalars["attn/kda_decay_mean"], 6)
         # the head gates' mean by layer type (a gated model's alone)
         gated = {kind: round(scalars[key], 6) for kind in fields["attn_keys_per_query"]
                  if (key := f"attn/gate_mean_{kind.split('_')[0]}") in scalars}
@@ -532,7 +538,8 @@ class SequenceTask:
         by layer: ``moe/buffer_fill`` (held pairs). Per position:
         ``attn/keys_per_query_*`` by layer type; per step ``moe/tile_visits``
         (row tiles the held groups overlap); per gate ``attn/gate_mean_*`` by
-        layer type (a gated model's); and, where layers are sparse,
+        layer type (a gated model's); per decay ``attn/kda_decay_mean`` (a
+        model with linear layers'); and, where layers are sparse,
         ``align_loss`` (the indexer's loss; ``loss`` stays the cross-entropy);
         per sequence ``sparse/pairs_scored``, ``sparse/key_reads`` ([sparse
         layers, T]: queries that read each key position),
@@ -569,6 +576,8 @@ class SequenceTask:
                 for name, keys in outputs.items() if name.startswith("attn_keys_")
             },
             **sparse,
+            **({"attn/kda_decay_mean": mean(outputs["kda_decay_sum"], outputs["kda_decay_n"])}
+               if "kda_decay_sum" in outputs else {}),
         }
 
 

@@ -209,3 +209,43 @@ def test_the_head_is_one_loop_of_three_products_and_no_kept_logits(one_chip, as_
     assert any(re.match(rf"f32\[{T // chunk},{chunk},{d}\]", r) for r in outputs)
     assert any(re.match(rf"f32\[{d},{v}\]", r) for r in outputs)
     assert f"[{T},{v}]" not in text and f"[{T // chunk},{chunk},{v}]" not in text
+
+
+def test_the_kda_kernels_compile_at_the_cells_widths(one_chip, as_on_a_tpu):
+    """``ops/kda.py`` differentiated at the Kimi-Linear cell's layer: 16,384
+    tokens, 32 heads of 128 in bfloat16. Mosaic takes the forward and the
+    backward kernel (a refused slice or too much VMEM raises here), and the
+    custom call writes the output and q, k, v's gradients in bfloat16."""
+    from tensorflowdistributedlearning_tpu.ops import kda as kda_lib
+
+    t, h, d = 16384, 32, 128
+
+    def loss(q, k, v, g, beta, positions, w):
+        return jnp.sum(kda_lib.kda(q, k, v, g, beta, positions).astype(jnp.float32) * w)
+
+    bf16, f32 = jnp.bfloat16, jnp.float32
+    text = _compiled_text(
+        jax.grad(loss, argnums=range(5)), one_chip, ((1, t, h, d), bf16), ((1, t, h, d), bf16),
+        ((1, t, h, d), bf16), ((1, t, h, d), f32), ((1, t, h), f32), ((1, t), jnp.int32),
+        ((1, t, h, d), f32))
+    assert _kernels(text) == 2
+    assert text.count("kda_fwd") >= 1 and text.count("kda_bwd") >= 1
+
+
+def test_latent_attention_takes_the_splash_kernel_at_192_and_128(one_chip, as_on_a_tpu):
+    """The Kimi-Linear cell's latent layer: 32 heads, queries and keys of 192,
+    values of 128, over 16,384 packed tokens — the kernel path, no ``[T, T]``
+    scores in HBM."""
+    from tensorflowdistributedlearning_tpu.ops import blocked_attention
+
+    t, h = 16384, 32
+    assert blocked_attention.kernel_serves(t, 192, 128)
+
+    def loss(q, k, v, seg):
+        return jnp.sum(blocked_attention.blocked_attention(q, k, v, seg).astype(jnp.float32))
+
+    bf16 = jnp.bfloat16
+    text = _compiled_text(jax.grad(loss, argnums=(0, 1, 2)), one_chip, ((1, t, h, 192), bf16),
+                          ((1, t, h, 192), bf16), ((1, t, h, 128), bf16), ((1, t), jnp.int32))
+    assert _kernels(text) == 3  # forward, dq, dkv
+    assert f"[{t},{t}]" not in text

@@ -460,7 +460,7 @@ def test_config_refuses_a_sparse_layer_without_its_indexer():
     with pytest.raises(ValueError, match="sa_config"):
         DecoderConfig(layer_types=("sparse_attention",) * 28)
     with pytest.raises(ValueError, match="Unknown layer types"):
-        DecoderConfig(layer_types=("linear_attention",) * 28)
+        DecoderConfig(layer_types=("state_space",) * 28)
 
 
 def test_fit_trains_the_sparse_decoder(tmp_path):

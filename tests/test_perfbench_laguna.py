@@ -77,11 +77,12 @@ def test_benchmark_names_the_cells_files():
                                    else "models and kernels")
         module = importlib.import_module("perfbench.metrics." + metric["name"])
         assert callable(module.read)
-    # the cell's entries stand at the end of their lists, before what later
-    # PRs appended for every cell (the seven ``scope_*`` readers of PR 34)
-    assert bench["configs"][-1] is entry and bench["workloads"][-1] is work
-    later = [m for m in bench["per_layer"] if m["name"].startswith("scope_")]
-    assert bench["per_layer"][-len(mine) - len(later):] == mine + later
+    # the cell's entries, each once, its metrics one after another in the
+    # issue's order (later PRs append after them)
+    assert [c["name"] for c in bench["configs"]].count(tiny_mixed.CONFIG) == 1
+    assert [w["name"] for w in bench["workloads"]].count(tiny_mixed.WORKLOAD) == 1
+    first = bench["per_layer"].index(mine[0])
+    assert bench["per_layer"][first:first + len(mine)] == mine
     # and the tiny cell of these tests compares the same numbers
     assert set(tiny_mixed.TINY_LIMITS) == set(harness.load_cell(tiny_mixed.WORKLOAD).limits)
 

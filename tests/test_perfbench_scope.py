@@ -159,8 +159,11 @@ def test_benchmark_names_the_readers_files():
     with open(os.path.join(tiny_lm.REPO, "BENCHMARK.json"), encoding="utf-8") as f:
         bench = json.load(f)
     mine = [m for m in bench["per_layer"] if m["name"].startswith("scope_")]
-    # the new entries stand at the end of the list, in the issue's order
-    assert [m["name"] for m in bench["per_layer"]][-7:] == SCOPE_METRICS == [m["name"] for m in mine]
+    # the seven entries, in the issue's order, one after another (later PRs
+    # append after them)
+    names = [m["name"] for m in bench["per_layer"]]
+    first = names.index(SCOPE_METRICS[0])
+    assert names[first:first + 7] == SCOPE_METRICS == [m["name"] for m in mine]
     decoders = CELLS[1:]
     for metric in mine:
         module = importlib.import_module("perfbench.metrics." + metric["name"])
