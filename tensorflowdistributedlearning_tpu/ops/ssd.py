@@ -48,23 +48,45 @@ accumulation; ``dt``, ``A``, ``D``, the decays, the states and every
 gradient but those of ``x``, ``B`` and ``C`` (written in the inputs' dtype)
 are float32.
 
-**One path.** The chunks' work runs as batched XLA products, ``GROUP``
-chunks of every head at once in a ``lax.map``, the passes over the chunks as
-scans: each body is traced once whatever the length.
+**Paths.** On a TPU, where a group's heads fill whole lane tiles (``kernels_serve``),
+two Pallas kernels, ``ssd_fwd`` and ``ssd_bwd``: a grid step holds ``BLOCK``
+tokens of one group of B and C and its heads (x and y rows of heads x P
+lanes), forms ``C B^T`` once for the group, and loops over its chunks and,
+inside a chunk, over the group's heads, each head's ``[Q, Q]`` decays and
+masks formed in VMEM and its ``[P, N]`` state carried in VMEM from chunk to
+chunk and from block to block; the forward writes the state each block starts
+from. The backward kernel takes the blocks in reverse, rebuilds its chunks'
+starting states from the block's, carries the state's gradient in reverse,
+and writes dx, dB and dC (summed over the group's heads inside the grid step)
+in the inputs' dtype and, by head and token, dt's gradient, the running sums'
+and ``D``'s parts. The running sums ``c`` are formed in XLA before the kernels
+(the masked product above), so their gradient reaches dt and A by it; every
+rounding point is the XLA form's. Every loop over chunks or heads is a
+``fori_loop``, so a kernel body is traced once whatever the length. Anything
+else — the CPU, a layout the kernels cannot tile — runs the batched XLA form:
+the chunks' work as batched products, ``GROUP`` chunks of every head at once
+in a ``lax.map``, the passes over the chunks as scans.
 """
 
 from __future__ import annotations
+
+import functools
+import math
 
 import jax
 import jax.numpy as jnp
 from jax import lax
 
 from tensorflowdistributedlearning_tpu.obs import scopes
+from tensorflowdistributedlearning_tpu.ops.kda import _NN, _NT, _TN, _dot, _params
 
 # tokens a chunk (the published chunk_size of the family)
 CHUNK = 128
 # chunks whose [Q, Q] work stands at once: a batch axis of the products
 GROUP = 16
+# tokens a grid step of the kernels holds, and how often the forward writes
+# the state
+BLOCK = 512
 SCOPE = "decoder/ssm/ssd"
 
 _F32 = jnp.float32
@@ -260,9 +282,330 @@ def _core_bwd(res, dy):
 _ssd_core.defvjp(_core_fwd, _core_bwd)
 
 
-def _prepare(x, dt, b, c_, positions):
+# ---------------------------------------------------------------------------
+# the kernel path
+# ---------------------------------------------------------------------------
+
+
+def kernels_serve(group_heads: int, head_dim: int, state_dim: int) -> bool:
+    """The layouts the Pallas kernels take (on a TPU): a group's heads fill
+    whole lane tiles and whole sublane tiles (x's rows of ``group_heads x
+    head_dim`` lanes, a head's ``[head_dim, Q]`` block in bfloat16), and B and
+    C fill whole lane tiles. Any length does, padded to whole chunks."""
+    return (group_heads % 8 == 0 and head_dim % 16 == 0
+            and group_heads * head_dim % 128 == 0 and state_dim % 128 == 0)
+
+
+def _iota(shape, axis):
+    return lax.broadcasted_iota(jnp.int32, shape, axis)
+
+
+def _eye(q):
+    return _iota((q, q), 0) == _iota((q, q), 1)
+
+
+def _lane(row, k):
+    """Entry ``k`` of rows [..., 1, Q] as [..., 1, 1]."""
+    return jnp.sum(jnp.where(_iota(row.shape, row.ndim - 1) == k, row, 0.0), axis=-1,
+                   keepdims=True)
+
+
+def _column(row):
+    """Rows [..., 1, Q] -> columns [..., Q, 1] (a masked sum over lanes:
+    Mosaic moves no row of lanes into sublanes)."""
+    return jnp.sum(jnp.where(_eye(row.shape[-1]), row, 0.0), axis=-1, keepdims=True)
+
+
+def _row_of(col):
+    """Columns [..., Q, 1] -> rows [..., 1, Q]."""
+    return jnp.sum(jnp.where(_eye(col.shape[-2]), col, 0.0), axis=-2, keepdims=True)
+
+
+def _chunk_masks(pos_col, pos_row):
+    """A chunk's positions [Q, 1] and [1, Q] -> (same [Q, Q]: j <= i of one
+    document; carry [1, Q] by i: no document starts at or before i inside the
+    chunk; tail [1, Q] by j: j reaches the chunk's last token; through [1, 1]:
+    the starting state reaches it)."""
+    q = pos_row.shape[-1]
+    i, j = _iota((q, q), 0), _iota((q, q), 1)
+    same = (j <= i) & (pos_col >= i - j)
+    carry = pos_row > _iota((1, q), 1)
+    tail = jnp.sum(jnp.where(i == q - 1, same.astype(_F32), 0.0), axis=0, keepdims=True) > 0
+    return same, carry, tail, _lane(carry.astype(_F32), q - 1) > 0
+
+
+def _leaving(c, tail, through):
+    """The heads' decays of what the chunk leaves, from their running sums
+    [R, 1, Q]: (e [R, 1, Q] = exp(c_Q - c_j) where j reaches the last token,
+    the starting state's decay over the chunk [R, 1, 1])."""
+    last = _lane(c, c.shape[-1] - 1)
+    e = jnp.where(tail, jnp.exp(jnp.minimum(last - c, 0.0)), 0.0)
+    return e, jnp.where(through, jnp.exp(jnp.minimum(last, 0.0)), 0.0)
+
+
+def _head_decays(c, same, carry):
+    """The heads' (L [R, Q, Q] = exp(c_i - c_j) on a document's pairs, gin
+    [R, 1, Q] = exp(c_i) where the starting state reaches i)."""
+    el = jnp.exp(jnp.where(same, _column(c) - c, -jnp.inf))
+    return el, jnp.where(carry, jnp.exp(jnp.minimum(c, 0.0)), 0.0)
+
+
+def _rows(n):
+    from jax.experimental import pallas as pl
+
+    return pl.ds(pl.multiple_of(n * CHUNK, CHUNK), CHUNK)
+
+
+def _heads_of(v, heads):
+    """A chunk's rows [Q, R * P] -> [R, P, Q]: each head's block with its
+    channels in sublanes, the heads a batch axis."""
+    return v.T.reshape(heads, -1, v.shape[0])
+
+
+def _flat(v):
+    """[R, P, X] -> [R * P, X]: the group's heads as one product's rows."""
+    return v.reshape(-1, v.shape[-1])
+
+
+def _fwd_kernel(x_ref, b_ref, c_ref, dt_ref, cs_ref, pc_ref, pr_ref, d_ref, y_ref, s_ref, f_ref,
+                *, dtype):
+    from jax.experimental import pallas as pl
+
+    @pl.when(pl.program_id(2) == 0)
+    def _():
+        f_ref[...] = jnp.zeros_like(f_ref)
+
+    # the state this block starts from; f_ref's block is the same every step
+    # of a group: it carries the state [R, P, N]
+    s_ref[...] = f_ref[...]
+    d = d_ref[...]
+    heads = f_ref.shape[0]
+
+    def chunk(n, carry):
+        rows = _rows(n)
+        xt = _heads_of(x_ref[rows, :], heads)
+        bm, cm = b_ref[rows, :], c_ref[rows, :]
+        same, carry_in, tail, through = _chunk_masks(pc_ref[rows, :], pr_ref[n])
+        c, dt = cs_ref[n], dt_ref[n]
+        el, gin = _head_decays(c, same, carry_in)
+        e, decay = _leaving(c, tail, through)
+        h = f_ref[...]
+        xf = xt.astype(_F32)
+        # y^T = x^T m^T + (H C^T) gin + D x^T, m = L o C B^T o dt_j
+        m = el * _dot(cm, bm, _NT, dtype) * dt
+        yt = (_dot(xt, m, _NT, dtype) + _dot(_flat(h), cm, _NT, dtype).reshape(xt.shape) * gin
+              + d * xf)
+        y_ref[rows, :] = _flat(yt).T
+        f_ref[...] = decay * h + _dot(_flat(xf * (e * dt)), bm, _NN, dtype).reshape(h.shape)
+        return carry
+
+    lax.fori_loop(0, cs_ref.shape[0], chunk, 0)
+
+
+def _bwd_kernel(x_ref, b_ref, c_ref, dt_ref, cs_ref, pc_ref, pr_ref, d_ref, s_ref, dy_ref,
+                dx_ref, db_ref, dc_ref, ddt_ref, dcs_ref, dd_ref, dh_s, hs, *, dtype):
+    from jax.experimental import pallas as pl
+
+    heads, chunks = dh_s.shape[0], cs_ref.shape[0]
+    q = CHUNK
+
+    @pl.when(pl.program_id(2) == 0)
+    def _():
+        dh_s[...] = jnp.zeros_like(dh_s)
+
+    d = d_ref[...]
+
+    # the chunks' starting states from the block's
+    hs[0] = s_ref[...]
+
+    def forward(n, carry):
+        rows = _rows(n)
+        _, _, tail, through = _chunk_masks(pc_ref[rows, :], pr_ref[n])
+        e, decay = _leaving(cs_ref[n], tail, through)
+        xw = _heads_of(x_ref[rows, :], heads).astype(_F32) * (e * dt_ref[n])
+        hs[n + 1] = decay * hs[n] + _dot(_flat(xw), b_ref[rows, :], _NN, dtype).reshape(
+            dh_s.shape)
+        return carry
+
+    lax.fori_loop(0, chunks - 1, forward, 0)
+
+    # chunks in reverse, dH (the gradient of the state a chunk leaves) carried
+    def backward(k, carry):
+        n = chunks - 1 - k
+        rows = _rows(n)
+        xt, dyt = _heads_of(x_ref[rows, :], heads), _heads_of(dy_ref[rows, :], heads)
+        bm, cm = b_ref[rows, :], c_ref[rows, :]
+        cb = _dot(cm, bm, _NT, dtype)
+        same, carry_in, tail, through = _chunk_masks(pc_ref[rows, :], pr_ref[n])
+        c, dt = cs_ref[n], dt_ref[n]
+        el, gin = _head_decays(c, same, carry_in)
+        e, decay = _leaving(c, tail, through)
+        xf, h0, dh1 = xt.astype(_F32), hs[n], dh_s[...]
+        # the pairs inside the chunk: y_i = sum_j m_ij x_j, m = L o C B^T o dt_j
+        m = el * cb * dt
+        dm = _dot(dyt, xt, _TN, dtype)  # [R, Q_i, Q_j]
+        dmm = dm * m  # the gradient of each pair's exponent c_i - c_j
+        dml = dm * el
+        dcb = jnp.sum(dml * dt, axis=0)
+        ddt = jnp.sum(dml * cb, axis=1, keepdims=True)
+        dcs = _row_of(jnp.sum(dmm, axis=2, keepdims=True)) - jnp.sum(dmm, axis=1, keepdims=True)
+        # what reads the starting state: exp(c_i) (C_i . h0)
+        inter = _dot(_flat(h0), cm, _NT, dtype).reshape(xt.shape)  # [R, P, Q_i]
+        dcs = dcs + gin * jnp.sum(dyt * inter, axis=1, keepdims=True)
+        dyg = _flat(dyt * gin)
+        dcm = _dot(dyg, _flat(h0), _TN, dtype)
+        # the state the chunk leaves: decay h0 + sum_j w_j x_j B_j^T, w = e dt
+        w = e * dt
+        dsb = _dot(_flat(dh1), bm, _NT, dtype).reshape(xt.shape)  # [R, P, Q_j]
+        dxt = _dot(dyt, m, _NN, dtype) + dsb * w + d * dyt
+        dbm = _dot(_flat(xf * w), _flat(dh1), _TN, dtype)
+        dw = jnp.sum(xf * dsb, axis=1, keepdims=True)
+        ddt = ddt + dw * e
+        dww = dw * w  # the gradient of the exponent c_Q - c_j
+        dlast = jnp.sum(dww, axis=2, keepdims=True) + decay * jnp.sum(
+            jnp.sum(dh1 * h0, axis=2, keepdims=True), axis=1, keepdims=True)
+        dcs = dcs - dww + jnp.where(_iota(dww.shape, 2) == q - 1, dlast, 0.0)
+        dh_s[...] = decay * dh1 + _dot(dyg, cm, _NN, dtype).reshape(dh1.shape)
+        dx_ref[rows, :] = _flat(dxt).T.astype(dx_ref.dtype)
+        dc_ref[rows, :] = (_dot(dcb, bm, _NN, dtype) + dcm).astype(dc_ref.dtype)
+        db_ref[rows, :] = (_dot(dcb, cm, _TN, dtype) + dbm).astype(db_ref.dtype)
+        ddt_ref[n], dcs_ref[n], dd_ref[n] = ddt, dcs, jnp.sum(dyt * xf, axis=1, keepdims=True)
+        return carry
+
+    lax.fori_loop(0, chunks, backward, 0)
+
+
+def _specs(h, g, p, n, block, nb, reverse):
+    """Block specs over a (sequence, group, block) grid: [B, T, H * P] rows of
+    one group's heads, [B, T, G * N] rows of its B or C, [B, K, H, 1, Q]
+    values by chunk, head and token, positions [B, T, 1] and [B, K, 1, Q], D
+    [H, 1, Q], states [B, H, NB, P, N]; the blocks in reverse for the backward
+    kernel."""
+    from jax.experimental import pallas as pl
+
+    r, chunks = h // g, block // CHUNK
+    at = (lambda c: nb - 1 - c) if reverse else (lambda c: c)
+    return dict(
+        x=pl.BlockSpec((None, block, r * p), lambda i, j, c: (i, at(c), j)),
+        bc=pl.BlockSpec((None, block, n), lambda i, j, c: (i, at(c), j)),
+        heads=pl.BlockSpec((None, chunks, r, 1, CHUNK), lambda i, j, c: (i, at(c), j, 0, 0)),
+        pos_col=pl.BlockSpec((None, block, 1), lambda i, j, c: (i, at(c), 0)),
+        pos_row=pl.BlockSpec((None, chunks, 1, CHUNK), lambda i, j, c: (i, at(c), 0, 0)),
+        d=pl.BlockSpec((r, 1, CHUNK), lambda i, j, c: (j, 0, 0)),
+        state=pl.BlockSpec((None, r, None, p, n), lambda i, j, c: (i, j, at(c), 0, 0)),
+        final=pl.BlockSpec((None, r, p, n), lambda i, j, c: (i, j, 0, 0)),
+    )
+
+
+def _kernel_inputs(x, dt, cs, b, c_, d, pos):
+    """The kernels' layouts: x, B, C as rows, dt and the running sums as [B,
+    K, H, 1, Q], the positions as a column and by chunk, D over a chunk's
+    lanes."""
+    bsz, t, h = dt.shape
+    k = t // CHUNK
+    by_chunk = lambda v: v.reshape(bsz, k, CHUNK, h, 1).transpose(0, 1, 3, 4, 2)  # noqa: E731
+    flat = lambda v: v.reshape(bsz, t, -1)  # noqa: E731
+    return (flat(x), flat(b), flat(c_), by_chunk(dt), by_chunk(cs), pos[..., None],
+            pos.reshape(bsz, k, 1, CHUNK), jnp.broadcast_to(d[:, None, None], (h, 1, CHUNK)))
+
+
+def _grid(x, b):
+    _, t, h, p = x.shape
+    g, n = b.shape[2:]
+    block = math.gcd(t, BLOCK)
+    return t, h, g, p, n, block, t // block
+
+
+def _pallas_forward(x, dt, cs, b, c_, d, pos, interpret):
+    """[B, T, ...] inputs -> (y [B, T, H, P] float32, the state each block
+    starts from [B, H, NB, P, N], the state after the last token [B, H, P,
+    N])."""
+    from jax.experimental import pallas as pl
+
+    bsz = x.shape[0]
+    t, h, g, p, n, block, nb = _grid(x, b)
+    spec = _specs(h, g, p, n, block, nb, False)
+    y, states, final = pl.pallas_call(
+        functools.partial(_fwd_kernel, dtype=x.dtype),
+        grid=(bsz, g, nb),
+        in_specs=[spec["x"], spec["bc"], spec["bc"], spec["heads"], spec["heads"],
+                  spec["pos_col"], spec["pos_row"], spec["d"]],
+        out_specs=[spec["x"], spec["state"], spec["final"]],
+        out_shape=[jax.ShapeDtypeStruct((bsz, t, h * p), _F32),
+                   jax.ShapeDtypeStruct((bsz, h, nb, p, n), _F32),
+                   jax.ShapeDtypeStruct((bsz, h, p, n), _F32)],
+        compiler_params=None if interpret else _params("parallel", "parallel", "arbitrary"),
+        interpret=interpret,
+        name="ssd_fwd",
+    )(*_kernel_inputs(x, dt, cs, b, c_, d, pos))
+    return y.reshape(x.shape), states, final
+
+
+def _pallas_backward(x, dt, cs, b, c_, d, pos, states, dy, interpret):
+    """(dx, ddt, the running sums' gradient, dB, dC, dD)."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    bsz = x.shape[0]
+    t, h, g, p, n, block, nb = _grid(x, b)
+    spec = _specs(h, g, p, n, block, nb, True)
+    r, chunks = h // g, block // CHUNK
+    by_head = jax.ShapeDtypeStruct((bsz, t // CHUNK, h, 1, CHUNK), _F32)
+    dx, db, dc, ddt, dcs, dd = pl.pallas_call(
+        functools.partial(_bwd_kernel, dtype=x.dtype),
+        grid=(bsz, g, nb),
+        in_specs=[spec["x"], spec["bc"], spec["bc"], spec["heads"], spec["heads"],
+                  spec["pos_col"], spec["pos_row"], spec["d"], spec["state"], spec["x"]],
+        out_specs=[spec["x"], spec["bc"], spec["bc"], spec["heads"], spec["heads"], spec["heads"]],
+        out_shape=[jax.ShapeDtypeStruct((bsz, t, h * p), x.dtype),
+                   jax.ShapeDtypeStruct((bsz, t, g * n), b.dtype),
+                   jax.ShapeDtypeStruct((bsz, t, g * n), c_.dtype), by_head, by_head, by_head],
+        # dH carried between blocks; the chunks' starting states
+        scratch_shapes=[pltpu.VMEM((r, p, n), _F32), pltpu.VMEM((chunks, r, p, n), _F32)],
+        compiler_params=None if interpret else _params("parallel", "parallel", "arbitrary"),
+        interpret=interpret,
+        name="ssd_bwd",
+    )(*_kernel_inputs(x, dt, cs, b, c_, d, pos), states, dy.reshape(bsz, t, -1))
+    by_token = lambda v: v[:, :, :, 0].transpose(0, 1, 3, 2).reshape(dt.shape)  # noqa: E731
+    return (dx.reshape(x.shape), by_token(ddt), by_token(dcs), db.reshape(b.shape),
+            dc.reshape(c_.shape), jnp.sum(dd, axis=(0, 1, 3, 4)))
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(7,))
+def _kernel_core(x, dt, cs, b, c_, d, pos, interpret):
+    return _pallas_forward(x, dt, cs, b, c_, d, pos, interpret)[0]
+
+
+def _kernel_fwd(x, dt, cs, b, c_, d, pos, interpret):
+    y, states, _ = _pallas_forward(x, dt, cs, b, c_, d, pos, interpret)
+    return y, (x, dt, cs, b, c_, d, pos, states)
+
+
+def _kernel_bwd(interpret, res, dy):
+    with scopes.scope(SCOPE):
+        return _pallas_backward(*res, dy, interpret) + (None,)
+
+
+_kernel_core.defvjp(_kernel_fwd, _kernel_bwd)
+
+
+# ---------------------------------------------------------------------------
+# the entry points
+# ---------------------------------------------------------------------------
+
+
+def _running_sums(a, positions):
+    """a [B, T, H] -> c: ``_running`` chunk by chunk (restarting at a chunk's
+    and a document's start), the kernels' exponents."""
+    run = jax.vmap(jax.vmap(lambda v, p: _running(v, _masks(p)[0])))
+    return run(_chunked(a), _chunked(positions)).reshape(a.shape)
+
+
+def _prepare(x, dt, b, c_, positions, interpret):
     """Pad the sequence to whole chunks with tokens that add nothing to the
-    state and read nothing back."""
+    state and read nothing back, and choose the path."""
+    from tensorflowdistributedlearning_tpu.ops import pallas_kernels
+
     t = x.shape[1]
     pad = -t % CHUNK
     if pad:
@@ -270,22 +613,39 @@ def _prepare(x, dt, b, c_, positions):
         x, dt, b, c_ = map(widen, (x, dt, b, c_))
         positions = jnp.concatenate(
             [positions, positions[:, -1:] + 1 + jnp.arange(pad, dtype=positions.dtype)], axis=1)
-    return x, dt.astype(_F32), b, c_, positions.astype(jnp.int32), t
+    if interpret is None:
+        serve = pallas_kernels.pallas_platform_ok() and kernels_serve(
+            x.shape[2] // b.shape[2], x.shape[3], b.shape[3])
+        path = "pallas" if serve else "xla"
+    else:
+        path = "interpret" if interpret else "pallas"
+    return (x, dt.astype(_F32), b, c_, positions.astype(jnp.int32)), path, t
 
 
-def ssd(x, dt, a, b, c, d, positions):
+def ssd(x, dt, a, b, c, d, positions, *, interpret=None):
     """x [B, T, H, P], b and c [B, T, G, N] in the compute dtype (G divides
     H: head h reads group h // (H / G)), dt [B, T, H] float32 (> 0), a [H]
     float32 (< 0), d [H] float32, positions [B, T] (0 at each document's
-    first token) -> y [B, T, H, P] float32."""
+    first token) -> y [B, T, H, P] float32. ``interpret``: None chooses by
+    backend and shape; True or False forces the kernel path (interpreted on
+    the CPU, for tests)."""
     with scopes.scope(SCOPE):
-        x, dt, b, c, positions, t = _prepare(x, dt, b, c, positions)
-        return _ssd_core(x, dt, a.astype(_F32), b, c, d.astype(_F32), positions)[:, :t]
+        (x, dt, b, c, positions), path, t = _prepare(x, dt, b, c, positions, interpret)
+        a, d = a.astype(_F32), d.astype(_F32)
+        if path == "xla":
+            return _ssd_core(x, dt, a, b, c, d, positions)[:, :t]
+        cs = _running_sums(dt * a, positions)
+        return _kernel_core(x, dt, cs, b, c, d, positions, path == "interpret")[:, :t]
 
 
-def ssd_with_state(x, dt, a, b, c, d, positions):
+def ssd_with_state(x, dt, a, b, c, d, positions, *, interpret=None):
     """``ssd`` and the state after the last token [B, H, P, N], undifferentiated
     (for tests)."""
-    x, dt, b, c, positions, t = _prepare(x, dt, b, c, positions)
-    y, _, final = _forward(x, dt, a.astype(_F32), b, c, d.astype(_F32), positions)
+    (x, dt, b, c, positions), path, t = _prepare(x, dt, b, c, positions, interpret)
+    a, d = a.astype(_F32), d.astype(_F32)
+    if path == "xla":
+        y, _, final = _forward(x, dt, a, b, c, d, positions)
+    else:
+        y, _, final = _pallas_forward(x, dt, _running_sums(dt * a, positions), b, c, d, positions,
+                                      path == "interpret")
     return y[:, :t], final

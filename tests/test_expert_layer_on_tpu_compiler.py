@@ -278,8 +278,9 @@ def test_a_segment_of_relu2_experts_of_1856_takes_the_kernel(one_chip, as_on_a_t
 def test_the_ssd_scan_compiles_at_the_cells_widths(one_chip, as_on_a_tpu):
     """``ops/ssd.py`` differentiated at the NemotronH cell's Mamba layer:
     16,384 tokens, 64 heads of 64, 8 groups of B and C of 128, in bfloat16.
-    The compiler takes the chunks' batched products and the passes over the
-    chunks' states; no ``[T, T]`` matrix of a head appears."""
+    Mosaic takes the forward and the backward kernel (a refused slice, shape
+    cast or too much VMEM raises here), and they are the program's only
+    kernels; no ``[T, T]`` matrix of a head appears."""
     from tensorflowdistributedlearning_tpu.ops import ssd as ssd_lib
 
     t, h, p, g, n = 16384, 64, 64, 8, 128
@@ -293,4 +294,5 @@ def test_the_ssd_scan_compiles_at_the_cells_widths(one_chip, as_on_a_tpu):
         ((h,), f32), ((1, t, g, n), bf16), ((1, t, g, n), bf16), ((h,), f32),
         ((1, t), jnp.int32), ((1, t, h, p), f32))
     assert f"[{t},{t}]" not in text and f",{t},{t}]" not in text
-    assert " while(" in text
+    assert _kernels(text) == 2
+    assert text.count("ssd_fwd") >= 1 and text.count("ssd_bwd") >= 1
